@@ -13,7 +13,6 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
 STEPS = int(os.environ.get("BENCH_TOTAL_STEPS", 4096))
 REPS = int(os.environ.get("BENCH_REPS", 3))
-CACHE = os.environ.get("BENCH_XLA_CACHE", "/tmp/sheeprl_pr13_xla_cache")
 
 results = {"sebulba": [], "coupled": []}
 runs = []
@@ -25,7 +24,6 @@ for rep in range(REPS):
             "BENCH_METRIC": "dreamer_sebulba",
             "BENCH_DREAMER_MODE": mode,
             "BENCH_TOTAL_STEPS": str(STEPS),
-            "BENCH_XLA_CACHE": CACHE,
         }
         out = subprocess.run(
             [sys.executable, "bench.py"], cwd=REPO, env=env, capture_output=True, text=True,

@@ -15,7 +15,6 @@ REPO = os.path.dirname(os.path.dirname(HERE))
 STEPS = int(os.environ.get("BENCH_TOTAL_STEPS", 65536))
 POP = int(os.environ.get("BENCH_SCENARIO_SIZE", 8))
 REPS = int(os.environ.get("BENCH_REPS", 3))
-CACHE = os.environ.get("BENCH_XLA_CACHE", "/tmp/sheeprl_tpu_xla_cache")
 
 
 def run_once(mode: str) -> dict:
@@ -26,7 +25,6 @@ def run_once(mode: str) -> dict:
         "BENCH_SCENARIO_MODE": mode,
         "BENCH_SCENARIO_SIZE": str(POP),
         "BENCH_TOTAL_STEPS": str(STEPS),
-        "BENCH_XLA_CACHE": CACHE,
     }
     out = subprocess.run(
         [sys.executable, "bench.py"], cwd=REPO, env=env, capture_output=True, text=True,

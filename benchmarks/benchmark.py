@@ -9,8 +9,8 @@ first CLI argument and everything after is passed through as overrides::
     python benchmarks/benchmark.py dreamer_v3
 
 Prints the elapsed wall-clock seconds and an env-steps/s JSON line. Uses the
-same persistent XLA compilation cache as ``bench.py`` so repeated runs
-measure the framework, not the compiler.
+repository's one persistent compilation cache (``enable_compile_cache``,
+through the CLI) so repeated runs measure the framework, not the compiler.
 """
 
 from __future__ import annotations
@@ -35,40 +35,21 @@ def main() -> None:
     algo = sys.argv[1]
     overrides = sys.argv[2:]
 
-    try:
-        import jax
-
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.environ.get("BENCH_XLA_CACHE", os.path.join(_REPO_ROOT, ".xla_cache")),
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:
-        pass
-
     from sheeprl_tpu.cli import check_configs, run_algorithm
     from sheeprl_tpu.config import compose
 
     cfg = compose([f"exp={algo}_benchmarks", *overrides])
     total_steps = int(cfg.algo.total_steps)
 
-    # the script dir is sys.path[0] when run as `python benchmarks/<script>.py`
-    from calibration import calibration_verdict, device_calibration_ms, gate_quiet
-
-    # Refuse to measure a loud chip; stamp pre/post readings + verdict so a
-    # number can never be quoted without its measurement conditions.
-    accel = str(cfg.fabric.get("accelerator", "auto"))
-    calib_pre = gate_quiet(accel)
+    # one process: run_algorithm opens the backend (and the compile cache)
     tic = time.perf_counter()
     check_configs(cfg)
     run_algorithm(cfg)
     elapsed = time.perf_counter() - tic
-    calib_post = device_calibration_ms(accel)
     result = {
         "benchmark": algo,
         "elapsed_s": round(elapsed, 2),
         "env_steps_per_sec": round(total_steps / elapsed, 2),
-        **calibration_verdict(calib_pre, calib_post),
     }
     print(json.dumps(result))
 

@@ -10,8 +10,8 @@ v5e ICI roofline bound on data-parallel scaling efficiency:
     t_coll(ring all-reduce of B bytes over n chips) = 2*B*(n-1)/n / ICI_BW
     efficiency_bound = t_compute / (t_compute + t_coll)
 
-with ``t_compute`` taken from the measured quiet-chip step time (the
-BENCH_NOTES numbers) — so the claim is a checkable arithmetic consequence
+with ``t_compute`` taken from a single-chip step time (``MEASURED_STEP_S``
+below) — so the claim is a checkable arithmetic consequence
 of (a) the byte counts printed here, (b) the public v5e ICI bandwidth, and
 (c) a measured single-chip step time, not an extrapolated wall-clock.
 
@@ -36,16 +36,13 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # Public v5e specs (Google Cloud TPU docs / the scaling-book numbers):
 # 197 bf16 TFLOP/s per chip; 1600 Gbps (= 200 GB/s) aggregate ICI per chip.
 V5E_ICI_BYTES_PER_S = 200e9
-# Measured quiet-chip step times from BENCH_NOTES.md (single chip).
-# dreamer_v3: the batch-16 x seq-64 S step measured 35.23 ms
-# (dreamer_train_bench, calibration-passed) — the analysis meshes carry the
-# same batch-16 PER DEVICE (weak scaling), so this is the per-device compute
-# at every dp. The 2.14 ms recorded in round 3 was an artifact of the
-# transport's pre-pull optimistic mode, where block_until_ready returns
-# without a real device sync (BENCH_NOTES "transport latency modes") — it
-# under-read the step ~16x and with it the collective/compute ratio.
-# ppo: 512-batch CPU proxy scaled (measured on the CPU backend, which has
-# no optimistic-mode artifact).
+# Single-chip step times, the one measured input of the bound. Both are the
+# builders' round-3/4 figures (in git history before PR 21), taken on an
+# earlier installation and NOT measured on the current code: dreamer_v3 is
+# the batch-16 x seq-64 S step (35.23 ms, benchmarks/dreamer_train_bench.py)
+# — the analysis meshes carry the same batch-16 PER DEVICE (weak scaling), so
+# it stands for the per-device compute at every dp; ppo is a 512-batch step
+# on the CPU backend, scaled. ROADMAP S7 replaces both with a four-chip run.
 MEASURED_STEP_S = {"dreamer_v3": 35.23e-3, "ppo": 16.0e-3 / 20}
 
 

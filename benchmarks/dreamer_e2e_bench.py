@@ -54,17 +54,6 @@ def main() -> None:
     policy_steps = int(args[0]) if args and args[0].isdigit() else 2000
     overrides = args[1:] if args and args[0].isdigit() else args
 
-    try:
-        import jax
-
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.environ.get("BENCH_XLA_CACHE", os.path.join(_REPO_ROOT, ".xla_cache")),
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:
-        pass
-
     from sheeprl_tpu.cli import check_configs, run_algorithm
     from sheeprl_tpu.config import compose
 
@@ -92,16 +81,11 @@ def main() -> None:
     action_repeat = int(cfg.env.action_repeat)
     total_frames = int(cfg.algo.total_steps) * action_repeat
 
-    # the script dir is sys.path[0] when run as `python benchmarks/<script>.py`
-    from calibration import calibration_verdict, device_calibration_ms, gate_quiet
-
-    accel = str(cfg.fabric.get("accelerator", "auto"))
-    calib_pre = gate_quiet(accel)
+    # one process: run_algorithm opens the backend (and the compile cache)
     tic = time.perf_counter()
     check_configs(cfg)
     run_algorithm(cfg)
     elapsed = time.perf_counter() - tic
-    calib_post = device_calibration_ms(accel)
 
     frames_per_s = total_frames / elapsed
     print(
@@ -114,7 +98,6 @@ def main() -> None:
                 "elapsed_s": round(elapsed, 2),
                 "env_frames_per_sec": round(frames_per_s, 2),
                 "vs_v100_crafter_rate": round(frames_per_s / V100_FRAMES_PER_S, 2),
-                **calibration_verdict(calib_pre, calib_post),
             }
         )
     )

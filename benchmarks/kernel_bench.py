@@ -142,6 +142,10 @@ def _time_case(thunk, reps: int) -> Dict[str, float]:
 def main() -> None:
     import jax
 
+    from sheeprl_tpu.utils.utils import enable_compile_cache
+
+    enable_compile_cache()
+
     which = os.environ.get("BENCH_KERNEL", "all").strip().lower()
     backend_sel = os.environ.get("BENCH_KERNEL_BACKEND", "both").strip().lower()
     reps = int(os.environ.get("BENCH_KERNEL_REPS", 30))

@@ -88,21 +88,6 @@ def main() -> None:
         raise SystemExit(2)
     overrides = sys.argv[4:]
 
-    # Same cache hygiene as bench.py: measure the framework, not the compiler
-    # (keyed by host CPU features so AOT entries never cross machine types).
-    try:
-        import jax
-
-        from sheeprl_tpu.utils.utils import machine_keyed_cache_dir
-
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            machine_keyed_cache_dir(os.environ.get("BENCH_XLA_CACHE", os.path.join(_REPO_ROOT, ".xla_cache"))),
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:
-        pass
-
     start = time.perf_counter()
     returns = capture_returns(overrides)
     elapsed = time.perf_counter() - start

@@ -172,6 +172,10 @@ def _drive_load(
 
 
 def main() -> None:
+    from sheeprl_tpu.utils.utils import enable_compile_cache
+
+    enable_compile_cache()
+
     mode = os.environ.get("BENCH_SERVE_MODE", "aot").strip().lower()
     if mode not in ("aot", "naive"):
         raise SystemExit(f"Unknown BENCH_SERVE_MODE '{mode}' (expected 'aot' or 'naive')")

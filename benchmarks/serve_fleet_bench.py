@@ -195,6 +195,10 @@ def _stand_up(n_replicas: int, buckets: List[int]):
 
 
 def main() -> None:
+    from sheeprl_tpu.utils.utils import enable_compile_cache
+
+    enable_compile_cache()
+
     replicas = int(os.environ.get("BENCH_FLEET_REPLICAS", 3))
     loads = [float(x) for x in os.environ.get("BENCH_FLEET_LOADS", "200").split(",") if x.strip()]
     duration = float(os.environ.get("BENCH_FLEET_DURATION", 6))

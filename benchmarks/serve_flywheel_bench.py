@@ -269,6 +269,10 @@ def _run_phase(
 
 
 def main() -> None:
+    from sheeprl_tpu.utils.utils import enable_compile_cache
+
+    enable_compile_cache()
+
     duration = float(os.environ.get("BENCH_FLYWHEEL_DURATION", "6"))
     n_clients = int(os.environ.get("BENCH_FLYWHEEL_CLIENTS", "4"))
 

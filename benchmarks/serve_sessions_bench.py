@@ -66,6 +66,10 @@ def _build_policy():
 def main() -> None:
     import numpy as np
 
+    from sheeprl_tpu.utils.utils import enable_compile_cache
+
+    enable_compile_cache()
+
     mode = os.environ.get("BENCH_SESSIONS_MODE", "batched").strip().lower()
     if mode not in ("batched", "naive"):
         raise SystemExit(f"Unknown BENCH_SESSIONS_MODE '{mode}' (expected 'batched' or 'naive')")

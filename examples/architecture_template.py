@@ -32,7 +32,7 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
-from sheeprl_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 
 def main() -> None:

@@ -21,6 +21,7 @@ import numpy as np
 import optax
 
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from sheeprl_tpu.algos.a2c.agent import build_agent, forward_with_actions
 from sheeprl_tpu.algos.a2c.utils import prepare_obs, test
@@ -33,7 +34,6 @@ from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric, build_aggregat
 from sheeprl_tpu.utils.registry import register_algorithm
 from sheeprl_tpu.utils.timer import timer
 from sheeprl_tpu.utils.utils import save_configs
-from sheeprl_tpu.parallel.compat import shard_map
 
 __all__ = ["main", "make_train_step"]
 

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from sheeprl_tpu.algos.dreamer_v1.agent import PlayerDV1, WorldModel, actor_sample, build_agent
 from sheeprl_tpu.algos.dreamer_v1.loss import actor_loss, critic_loss, reconstruction_loss
@@ -40,7 +41,6 @@ from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric, build_aggregat
 from sheeprl_tpu.utils.registry import register_algorithm
 from sheeprl_tpu.utils.timer import timer
 from sheeprl_tpu.utils.utils import Ratio, conv_heavy_compile_options, resolve_hybrid_player, save_configs
-from sheeprl_tpu.parallel.compat import shard_map
 
 __all__ = ["main", "make_train_step"]
 

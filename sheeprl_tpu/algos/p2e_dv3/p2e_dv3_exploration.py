@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from sheeprl_tpu.algos.dreamer_v3.agent import PlayerDV3, actor_dists, actor_sample
 from sheeprl_tpu.algos.dreamer_v3.loss import reconstruction_loss
@@ -58,7 +59,6 @@ from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric, build_aggregat
 from sheeprl_tpu.utils.registry import register_algorithm
 from sheeprl_tpu.utils.timer import timer
 from sheeprl_tpu.utils.utils import Ratio, resolve_hybrid_player, save_configs
-from sheeprl_tpu.parallel.compat import shard_map
 
 __all__ = ["main", "make_train_step"]
 

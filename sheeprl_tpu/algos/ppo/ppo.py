@@ -34,6 +34,8 @@ import numpy as np
 import optax
 
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.lax import axis_size
 
 from sheeprl_tpu.algos.ppo.agent import build_agent, forward_with_actions
 from sheeprl_tpu.algos.ppo.loss import entropy_loss, policy_loss, value_loss
@@ -49,7 +51,6 @@ from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric, build_aggregat
 from sheeprl_tpu.utils.registry import register_algorithm
 from sheeprl_tpu.utils.timer import timer
 from sheeprl_tpu.utils.utils import polynomial_decay, save_configs
-from sheeprl_tpu.parallel.compat import axis_size, shard_map
 
 __all__ = ["main", "make_train_step", "make_local_train"]
 

@@ -52,6 +52,7 @@ import numpy as np
 import optax
 
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from sheeprl_tpu.algos.ppo.agent import build_agent, sample_actions
 from sheeprl_tpu.algos.ppo.ppo import make_local_train
@@ -64,7 +65,6 @@ from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric, build_aggregat
 from sheeprl_tpu.utils.registry import register_algorithm
 from sheeprl_tpu.utils.timer import timer
 from sheeprl_tpu.utils.utils import polynomial_decay, save_configs
-from sheeprl_tpu.parallel.compat import shard_map
 
 __all__ = [
     "main",

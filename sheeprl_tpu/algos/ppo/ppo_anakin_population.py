@@ -70,6 +70,7 @@ import numpy as np
 import optax
 
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from sheeprl_tpu.algos.ppo.agent import build_agent
 from sheeprl_tpu.algos.ppo.ppo_anakin import (
@@ -79,7 +80,6 @@ from sheeprl_tpu.algos.ppo.ppo_anakin import (
 )
 from sheeprl_tpu.algos.ppo.utils import test
 from sheeprl_tpu.envs.jax_envs import BatchedJaxEnv, is_jax_env, make_jax_env
-from sheeprl_tpu.parallel.compat import shard_map
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
 from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric, build_aggregator
 from sheeprl_tpu.utils.registry import register_algorithm

@@ -34,6 +34,7 @@ import numpy as np
 import optax
 
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from sheeprl_tpu.algos.ppo_recurrent.agent import build_agent, forward_with_actions
 from sheeprl_tpu.algos.ppo_recurrent.utils import chunk_sequences, prepare_obs, test
@@ -46,7 +47,6 @@ from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric, build_aggregat
 from sheeprl_tpu.utils.registry import register_algorithm
 from sheeprl_tpu.utils.timer import timer
 from sheeprl_tpu.utils.utils import polynomial_decay, save_configs
-from sheeprl_tpu.parallel.compat import shard_map
 
 __all__ = ["main", "make_train_step"]
 

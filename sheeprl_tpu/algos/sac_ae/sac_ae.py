@@ -31,6 +31,7 @@ import numpy as np
 import optax
 
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from sheeprl_tpu.algos.sac.loss import critic_loss, entropy_loss, policy_loss
 from sheeprl_tpu.algos.sac_ae.agent import SACAEAgent, build_agent
@@ -43,7 +44,6 @@ from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric, build_aggregat
 from sheeprl_tpu.utils.registry import register_algorithm
 from sheeprl_tpu.utils.timer import timer
 from sheeprl_tpu.utils.utils import Ratio, save_configs
-from sheeprl_tpu.parallel.compat import shard_map
 
 __all__ = ["main", "make_train_step"]
 

@@ -322,7 +322,7 @@ def _is_trace_wrapper(resolved: Optional[str]) -> bool:
         return False
     if resolved == tail:  # bare name that never came from an import
         return tail in ("shard_map", "jit")  # local defs named e.g. `map` don't count
-    # anything imported from jax/lax/compat shims qualifies
+    # anything imported from jax/lax qualifies
     return True
 
 

@@ -179,27 +179,23 @@ def _load_utils_module(entry: Dict[str, Any]):
     return importlib.import_module(f"{pkg}.utils")
 
 
+def _prepare_process(cfg: DotDict) -> None:
+    """What every verb does before JAX opens a backend: pin the CPU platform
+    for a ``fabric.accelerator=cpu`` run, point the persistent compile cache
+    at the repository's one directory, and wire ``ops.backend=auto|pallas|lax``
+    + per-kernel overrides into the kernel registry (howto/kernels.md)."""
+    from sheeprl_tpu.ops.kernels import configure_from_config
+    from sheeprl_tpu.utils.utils import enable_compile_cache, pin_cpu_platform
+
+    pin_cpu_platform(cfg.get("fabric", {}).get("accelerator", "auto"))
+    enable_compile_cache()
+    configure_from_config(cfg.get("ops"))
+
+
 def run_algorithm(cfg: DotDict) -> None:
     """(reference: ``cli.py:59-198``)"""
-    from sheeprl_tpu.utils.utils import machine_keyed_cache_dir, pin_cpu_platform
-
     os.environ.setdefault("OMP_NUM_THREADS", str(cfg.num_threads))
-    pin_cpu_platform(cfg.get("fabric", {}).get("accelerator", "auto"))
-
-    # Opt-in persistent XLA compile cache for CLI runs. The directory is
-    # keyed by host CPU features: XLA:CPU AOT entries compiled on another
-    # machine type load with cpu_aot_loader mismatch errors and execute
-    # conservative code paths (−16% on the PPO driver bench) — mismatched
-    # hosts must recompile, never reuse.
-    cache_base = os.environ.get("SHEEPRL_TPU_XLA_CACHE")
-    if cache_base:
-        try:
-            import jax
-
-            jax.config.update("jax_compilation_cache_dir", machine_keyed_cache_dir(cache_base))
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        except Exception as e:  # pragma: no cover - cache is best-effort
-            warnings.warn(f"Could not enable the persistent XLA cache: {e}")
+    _prepare_process(cfg)
 
     entry = resolve_algorithm(cfg.algo.name)
     if entry is None:
@@ -235,11 +231,8 @@ def run_algorithm(cfg: DotDict) -> None:
     from sheeprl_tpu.utils.timer import timer
 
     from sheeprl_tpu.distributions import set_validate_args
-    from sheeprl_tpu.ops.kernels import configure_from_config
 
     set_validate_args(bool(cfg.get("distribution", {}).get("validate_args", False)))
-    # ops.backend=auto|pallas|lax + per-kernel overrides (howto/kernels.md)
-    configure_from_config(cfg.get("ops"))
 
     if cfg.get("metric") is not None:
         predefined = getattr(utils, "AGGREGATOR_KEYS", None)
@@ -305,13 +298,8 @@ def eval_algorithm(cfg: DotDict) -> None:
     """(reference: ``cli.py:201-267``)"""
     from sheeprl_tpu.parallel import Fabric
     from sheeprl_tpu.utils.checkpoint import load_state
-    from sheeprl_tpu.utils.utils import pin_cpu_platform
 
-    pin_cpu_platform(cfg.get("fabric", {}).get("accelerator", "auto"))
-
-    from sheeprl_tpu.ops.kernels import configure_from_config
-
-    configure_from_config(cfg.get("ops"))
+    _prepare_process(cfg)
 
     fabric = Fabric(devices=1, accelerator=cfg.fabric.get("accelerator", "auto"), precision=str(cfg.fabric.get("precision", "32-true")))
     fabric.seed_everything(cfg.seed if cfg.get("seed") is not None else 42)
@@ -335,13 +323,8 @@ def serve_algorithm(cfg: DotDict) -> None:
     from sheeprl_tpu.serve.server import serve_policy
     from sheeprl_tpu.utils.checkpoint import load_state
     from sheeprl_tpu.utils.registry import registered_policy_builder_names, resolve_policy_builder
-    from sheeprl_tpu.utils.utils import pin_cpu_platform
 
-    pin_cpu_platform(cfg.get("fabric", {}).get("accelerator", "auto"))
-
-    from sheeprl_tpu.ops.kernels import configure_from_config
-
-    configure_from_config(cfg.get("ops"))
+    _prepare_process(cfg)
     # serve joins the same multi-host bring-up contract as train: a serve
     # replica launched by a pod runtime initializes jax.distributed from the
     # identical fabric.distributed.* / SHEEPRL_* knobs
@@ -376,13 +359,8 @@ def flywheel_algorithm(cfg: DotDict) -> None:
     from sheeprl_tpu.parallel import Fabric
     from sheeprl_tpu.serve.flywheel import run_flywheel_learner
     from sheeprl_tpu.utils.checkpoint import load_state
-    from sheeprl_tpu.utils.utils import pin_cpu_platform
 
-    pin_cpu_platform(cfg.get("fabric", {}).get("accelerator", "auto"))
-
-    from sheeprl_tpu.ops.kernels import configure_from_config
-
-    configure_from_config(cfg.get("ops"))
+    _prepare_process(cfg)
 
     fabric = Fabric(
         devices=1,

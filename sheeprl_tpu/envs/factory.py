@@ -313,4 +313,7 @@ def vectorize_env(
             restart_backoff=float(cfg.env.get("restart_backoff", 0.5) or 0.0),
             step_timeout=cfg.env.get("step_timeout"),
         )
-    return AsyncVectorEnv(thunks, autoreset_mode=AutoresetMode.SAME_STEP)
+    # Workers come from a fork server, never from a fork of this process: by
+    # now it has opened its JAX backend (threads, and on a chip machine
+    # libtpu), and a forked copy of that deadlocks or fights for the chip.
+    return AsyncVectorEnv(thunks, autoreset_mode=AutoresetMode.SAME_STEP, context="forkserver")

@@ -317,8 +317,8 @@ class LayerNormGRUCell(nn.Module):
             from sheeprl_tpu.ops.kernels import gru_gates
 
             # None follows the ops.backend registry (auto = Pallas iff the
-            # process default backend is TPU — the historical rule); an
-            # explicit True forces the Pallas tier regardless of config.
+            # process holds a TPU; a host-CPU lowering there takes the lax
+            # reference); an explicit True forces the Pallas tier.
             h_new = gru_gates(fused, h, backend="pallas" if self.use_pallas else None)
             return h_new, h_new
         reset, cand, update = jnp.split(fused, 3, axis=-1)

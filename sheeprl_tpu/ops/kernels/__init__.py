@@ -8,6 +8,7 @@ package registers every kernel.
 """
 
 from sheeprl_tpu.ops.kernels.registry import (
+    AUTO_LAX_ON_TPU,
     Kernel,
     UnknownKernelError,
     UnknownOpsBackendError,
@@ -21,6 +22,7 @@ from sheeprl_tpu.ops.kernels.registry import (
     overrides,
     register,
     resolve,
+    tier,
     use_backend,
 )
 from sheeprl_tpu.ops.kernels.gru import gru_gates, gru_gates_pallas, gru_gates_reference
@@ -35,6 +37,7 @@ from sheeprl_tpu.ops.kernels.sumtree import sumtree_sample, sumtree_sample_refer
 from sheeprl_tpu.ops.kernels.scatter import ragged_ring_scatter, ragged_ring_scatter_reference
 
 __all__ = [
+    "AUTO_LAX_ON_TPU",
     "Kernel",
     "UnknownKernelError",
     "UnknownOpsBackendError",
@@ -57,6 +60,7 @@ __all__ = [
     "resolve",
     "sumtree_sample",
     "sumtree_sample_reference",
+    "tier",
     "two_hot_symexp_decode",
     "two_hot_symexp_decode_reference",
     "two_hot_symlog_loss",

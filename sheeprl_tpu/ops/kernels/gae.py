@@ -12,7 +12,8 @@ reference (return estimation is where low precision visibly hurts).
 
 The lax reference IS :func:`sheeprl_tpu.ops.core.gae`, so ``ops.backend=lax``
 keeps today's graphs bit-for-bit; the kernel mirrors its op order, so the
-interpret-mode forward agrees to the last ulp on CPU CI.
+interpret-mode forward agrees to the last ulp on CPU CI. Compiled for "TPU
+v5 lite" (chipless AOT) at (128, 1) through (128, 4096) rollouts.
 
 Gradients: ``jax.custom_vjp`` — Pallas forward, reference scan re-derived on
 the backward (the scan's VJP is itself a cheap scan).
@@ -96,6 +97,7 @@ def _build_gae(gamma: float, gae_lambda: float):
     def fused_gae(rewards, values, dones, next_value):
         return registry.platform_dispatch(
             functools.partial(_gae_pallas_forward, gamma=gamma, gae_lambda=gae_lambda),
+            reference,
             rewards,
             values,
             dones,

@@ -14,9 +14,11 @@ XLA's fuser splits the chain.
 Gradients: ``jax.custom_vjp`` with the Pallas kernel on the forward and the
 (cheap, fully-fusable) jnp reference chain re-derived on the backward.
 
-On non-TPU backends the kernel runs in Pallas ``interpret`` mode, so the CPU
-test mesh exercises the same code path numerically. This module is the
-template entry of the kernel tier: every other kernel in
+In a process with no TPU an explicit ``ops.backend=pallas`` runs the kernel
+in Pallas ``interpret`` mode, so the CPU test mesh exercises the same code
+path numerically; in a process that holds a TPU, host-CPU lowerings take the
+reference (:func:`registry.platform_dispatch`). This module is the template
+entry of the kernel tier: every other kernel in
 :mod:`sheeprl_tpu.ops.kernels` follows the same reference/pallas/registry
 triple.
 """
@@ -56,13 +58,44 @@ def _kernel(fused_ref, h_ref, out_ref):
     out_ref[...] = (update * cand + (1 - update) * h).astype(out_ref.dtype)
 
 
+# Scoped VMEM the TPU compiler grants one kernel by default (v5e: 16 MiB,
+# from its own "Scoped allocation with size 40.00M and limit 16.00M" at
+# B=1024, H=4096, f32 with a 256-row block). The block is sized to 3/4 of it.
+_VMEM_BLOCK_BUDGET = 12 * 2**20
+_MAX_BLOCK_ROWS = 256
+
+
+def _block_rows(batch: int, hidden: int, itemsize: int) -> int:
+    """Rows per grid step such that the block fits scoped VMEM at any width.
+
+    Per row the pipeline holds the ``3H`` projection, the ``H`` carry and the
+    ``H`` result twice (double buffering) in the IO dtype, and the kernel body
+    about eight ``H``-wide f32 temporaries (the upcast inputs and the gate
+    chain). Observed by compiling for "TPU v5 lite" (jax 0.9.0, libtpu
+    0.0.34) at B=1024, H=4096: f32 blocks of 80 rows fit and 96 do not, bf16
+    blocks of 128 fit and 160 do not; this rule picks 40 and 48 there, 168
+    and 224 at H=1024, and the 256-row cap at H <= 512.
+    """
+    per_row = 2 * 5 * hidden * itemsize + 8 * hidden * 4
+    rows = min(_VMEM_BLOCK_BUDGET // per_row, _MAX_BLOCK_ROWS)
+    if rows >= batch:
+        return batch
+    sublanes = 8 * max(1, 4 // itemsize)  # (8, 128) f32 tiles, (16, 128) bf16
+    rows = rows // sublanes * sublanes
+    if rows == 0:
+        raise ValueError(
+            f"gru_gates: a {sublanes}-row block of width H={hidden} ({itemsize}-byte elements) "
+            f"does not fit the {_VMEM_BLOCK_BUDGET >> 20} MiB scoped-VMEM block budget"
+        )
+    return rows
+
+
 def _pallas_forward(fused: jax.Array, h: jax.Array, interpret: bool) -> jax.Array:
     from jax.experimental import pallas as pl
 
     B, H = h.shape
-    # Block over the batch; each row keeps its full 3H projection in VMEM
-    # (XL config: 3*4096 floats = 48 KiB/row, far under the ~16 MiB budget).
-    block_b = min(B, 256)
+    # Block over the batch; each row keeps its full 3H projection in VMEM.
+    block_b = _block_rows(B, H, jnp.dtype(h.dtype).itemsize)
     grid = (pl.cdiv(B, block_b),)
     return pl.pallas_call(
         _kernel,
@@ -79,7 +112,7 @@ def _pallas_forward(fused: jax.Array, h: jax.Array, interpret: bool) -> jax.Arra
 
 @functools.partial(jax.named_call, name="pallas_gru_gates")
 def _forward(fused: jax.Array, h: jax.Array) -> jax.Array:
-    return registry.platform_dispatch(_pallas_forward, fused, h)
+    return registry.platform_dispatch(_pallas_forward, gru_gates_reference, fused, h)
 
 
 @jax.custom_vjp

@@ -12,10 +12,17 @@ Every kernel in :mod:`sheeprl_tpu.ops.kernels` ships as a triple:
 Call sites go through :func:`dispatch`, which picks the implementation from
 the process-global backend (``ops.backend=auto|pallas|lax``) with optional
 per-kernel overrides (``ops.kernels.<name>=...``). ``auto`` resolves to the
-Pallas tier iff this process's default JAX backend is a TPU — the same rule
-the LayerNorm-GRU cell used before the registry existed — so CPU/GPU
-processes keep the plain-lax references unless a config or test explicitly
-opts into the interpret-mode kernel path.
+Pallas tier iff this process's default JAX backend is a TPU and the kernel
+is not listed in :data:`AUTO_LAX_ON_TPU` (kernels the TPU compiler refuses,
+routed to their lax reference by name). Processes without a TPU keep the
+plain-lax references unless a config or test explicitly opts into the
+interpret-mode kernel path.
+
+Within the Pallas tier the *lowering platform* picks the code
+(:func:`platform_dispatch`): a TPU lowering compiles the Mosaic kernel; a
+CPU lowering in a process that holds a TPU (the hybrid host player) takes
+the lax reference; the Pallas interpreter runs only in a process with no
+TPU at all. :func:`tier` names which of the three a kernel gets.
 
 Backend resolution happens at *trace* time and the chosen value is constant
 for the life of the process (it is config, not data), so switching backends
@@ -33,6 +40,7 @@ from typing import Any, Callable, Dict, Mapping, Optional, Tuple
 import jax
 
 __all__ = [
+    "AUTO_LAX_ON_TPU",
     "Kernel",
     "UnknownKernelError",
     "UnknownOpsBackendError",
@@ -47,10 +55,24 @@ __all__ = [
     "platform_dispatch",
     "register",
     "resolve",
+    "tier",
     "use_backend",
 ]
 
 VALID_BACKENDS: Tuple[str, ...] = ("auto", "pallas", "lax")
+
+# Kernels whose Pallas variant the TPU compiler refuses, with its message.
+# ``auto`` routes them to the lax reference on TPU, statically and by name;
+# an explicit ``ops.kernels.<name>=pallas`` still reaches the kernel and the
+# compiler's error.
+AUTO_LAX_ON_TPU: Dict[str, str] = {
+    "sumtree_sample": (
+        "jax 0.9.0 Pallas-Mosaic gather rule: 'ValueError: Shape mismatch in input, "
+        "indices and output' for the (1, 2P) tree against (1, B) draws; with equal "
+        "shapes Mosaic stops at 'Not implemented: Multiple source vregs along gather "
+        "dimension' (tpu.dynamic_gather reaches 128 lanes or 8 sublanes, not a tree)"
+    ),
+}
 
 
 class UnknownOpsBackendError(ValueError):
@@ -174,16 +196,30 @@ def configure_from_config(ops_cfg: Any) -> None:
     configure(backend=backend, overrides=dict(kernels or {}))
 
 
+def _process_has_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def resolve(name: str, backend: Optional[str] = None) -> str:
     """The concrete backend (``pallas`` or ``lax``) kernel ``name`` will run
     on: explicit per-call ``backend`` > per-kernel override > global knob,
-    with ``auto`` meaning Pallas iff ``jax.default_backend() == "tpu"``."""
+    with ``auto`` meaning Pallas iff ``jax.default_backend() == "tpu"`` and
+    the kernel is not in :data:`AUTO_LAX_ON_TPU`."""
     get(name)
     chosen = backend if backend is not None else _OVERRIDES.get(name, _BACKEND)
     chosen = _check_backend(str(chosen), kernel=name)
     if chosen == "auto":
-        chosen = "pallas" if jax.default_backend() == "tpu" else "lax"
+        chosen = "pallas" if _process_has_tpu() and name not in AUTO_LAX_ON_TPU else "lax"
     return chosen
+
+
+def tier(name: str) -> str:
+    """What kernel ``name`` lowers to in this process: ``lax``, ``pallas``
+    (Mosaic on TPU lowerings, the lax reference on host-CPU lowerings) or
+    ``pallas-interpret`` (explicit Pallas opt-in without a TPU)."""
+    if resolve(name) == "lax":
+        return "lax"
+    return "pallas" if _process_has_tpu() else "pallas-interpret"
 
 
 def dispatch(name: str, backend: Optional[str] = None) -> Callable[..., Any]:
@@ -208,27 +244,20 @@ def use_backend(backend: Optional[str] = None, *, reset: bool = False, **kernel_
         _OVERRIDES.update(saved_overrides)
 
 
-def _process_has_tpu() -> bool:
-    try:
-        return any(d.platform == "tpu" for d in jax.devices())
-    except RuntimeError:  # pragma: no cover - backend init failure
-        return False
+def platform_dispatch(pallas_forward: Callable[..., Any], reference: Callable[..., Any], *args: Any) -> Any:
+    """Run the Pallas tier of one kernel, the code chosen at LOWERING time.
 
-
-def platform_dispatch(pallas_forward: Callable[..., Any], *args: Any) -> Any:
-    """Run ``pallas_forward(*args, interpret=...)`` with the interpret flag
-    chosen at LOWERING time.
-
-    One process can trace the same op for both the TPU (compiled kernel) and
-    a host CPU player (interpret mode) — a process-global default_backend
-    switch cannot. TPU-less processes skip the dispatch entirely: older jax
-    lowers BOTH ``platform_dependent`` branches under ``lax.scan``, and the
-    non-interpret ``pallas_call`` rejects CPU lowering outright.
+    In a process that holds a TPU, one op can be traced for the chip and for
+    a host-CPU player: the TPU lowering compiles ``pallas_forward(*args,
+    interpret=False)`` and every other platform lowers ``reference(*args)``.
+    A process with no TPU reaches this only through an explicit
+    ``ops.backend=pallas`` and runs the Pallas interpreter (the non-interpret
+    ``pallas_call`` has no CPU lowering).
     """
     if not _process_has_tpu():
         return pallas_forward(*args, interpret=True)
     return jax.lax.platform_dependent(
         *args,
         tpu=functools.partial(pallas_forward, interpret=False),
-        default=functools.partial(pallas_forward, interpret=True),
+        default=reference,
     )

@@ -10,7 +10,13 @@ re-threads the (donated) ring through a scatter op per storage key. The
 Pallas kernel instead streams only the ``S*e`` touched rows: scalar-prefetched
 row/col indices drive the output ``BlockSpec`` directly (the classic
 prefetch-scatter pattern), the ring aliases in-place via
-``input_output_aliases``, and untouched rows are never read or written.
+``input_output_aliases``, and untouched rows are never read or written by
+the kernel itself. XLA is another matter: the custom call wants the ring
+row-major, the device-default layout of a ``u8[C, E, 64, 64, 3]`` ring puts
+``C`` minor-most, and the standalone program compiled for "TPU v5 lite"
+brackets the kernel (and, with two copies, the lax scatter too) in
+whole-ring layout copies — 1.15 GiB of temporaries for a 1.15 GiB ring at
+``C=25000, E=4`` (``memory_analysis`` of the chipless AOT compile).
 
 Dropped slots cannot skip their grid step, so they are parked on the row
 *before* the env's write head (``(pos[e] - 1) % capacity``) and write back
@@ -80,36 +86,41 @@ def _scatter_pallas_forward(storage, staged, row, pos, col_offset, *, interpret)
     safe_row = jnp.where(mask > 0, row, (pos[None, :] - 1) % capacity).astype(jnp.int32)
     cols = (col_offset + jnp.broadcast_to(jnp.arange(e), row.shape)).astype(jnp.int32)
 
-    block = pl.BlockSpec(
-        (1, 1, feat), lambda i, rows, cols, mask: (rows[i], cols[i], 0)
-    )
+    # One ring cell per block, on a (C, E, feat/128, 128) view (or
+    # (C, E, 1, feat) for narrow rows): Mosaic wants the last two block dims
+    # divisible by (8, 128) or equal to the array's, and a (1, 1, feat) block
+    # of a (C, E, feat) array is neither once E > 1.
+    cell = (feat // 128, 128) if feat % 128 == 0 else (1, feat)
+    block = pl.BlockSpec((1, 1) + cell, lambda i, rows, cols, mask: (rows[i], cols[i], 0, 0))
     out = pl.pallas_call(
         _scatter_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(slots * e,),
             in_specs=[
-                pl.BlockSpec((1, 1, feat), lambda i, rows, cols, mask: (i // e, i % e, 0)),
+                pl.BlockSpec((1, 1) + cell, lambda i, rows, cols, mask: (i // e, i % e, 0, 0)),
                 block,
             ],
             out_specs=block,
         ),
-        out_shape=jax.ShapeDtypeStruct((capacity, env_cols, feat), storage.dtype),
+        out_shape=jax.ShapeDtypeStruct((capacity, env_cols) + cell, storage.dtype),
         input_output_aliases={4: 0},  # storage updates in place
         interpret=interpret,
     )(
         safe_row.reshape(slots * e),
         cols.reshape(slots * e),
         mask.reshape(slots * e),
-        staged.reshape(slots, e, feat),
-        storage.reshape(capacity, env_cols, feat),
+        staged.reshape((slots, e) + cell),
+        storage.reshape((capacity, env_cols) + cell),
     )
     return out.reshape(storage.shape)
 
 
 @jax.custom_vjp
 def _scatter_pallas(storage, staged, row, pos, col_offset):
-    return registry.platform_dispatch(_scatter_pallas_forward, storage, staged, row, pos, col_offset)
+    return registry.platform_dispatch(
+        _scatter_pallas_forward, ragged_ring_scatter_reference, storage, staged, row, pos, col_offset
+    )
 
 
 def _fwd(storage, staged, row, pos, col_offset):

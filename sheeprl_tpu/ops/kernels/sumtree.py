@@ -9,8 +9,13 @@ levels plus the importance-weight epilogue in a single pass, so the
 sampling frontier (``mass``/``idx`` per draw) never leaves registers:
 ``(2P,) x (B,) -> (leaf_idx (B,) int32, weights (B,) f32)``.
 
-VMEM bound: the whole tree must fit (f32: ``8 MiB`` at ``P = 2^20`` leaves
-— an order of magnitude above any configured replay ring).
+The TPU compiler refuses this kernel at every shape (the per-level
+``take_along_axis`` over the ``(1, 2P)`` tree is a gather Mosaic does not
+have — message in :data:`registry.AUTO_LAX_ON_TPU`), so ``auto`` runs the lax
+reference on TPU and no VMEM bound has been observed; the kernel runs only
+in interpret mode (``ops.kernels.sumtree_sample=pallas`` without a TPU). By
+arithmetic alone the resident f32 tree is ``8 MiB`` at ``P = 2^20`` leaves,
+half of the 16 MiB of scoped VMEM a v5e kernel gets.
 
 The lax reference is the literal ``sample`` + ``importance_weights``
 composition the SAC PER path ran before this kernel existed, so
@@ -89,7 +94,9 @@ def _sumtree_pallas_forward(tree, u, n_valid, beta, *, interpret):
 
 @jax.custom_vjp
 def _sumtree_pallas(tree, u, n_valid, beta):
-    return registry.platform_dispatch(_sumtree_pallas_forward, tree, u, n_valid, beta)
+    return registry.platform_dispatch(
+        _sumtree_pallas_forward, sumtree_sample_reference, tree, u, n_valid, beta
+    )
 
 
 def _fwd(tree, u, n_valid, beta):

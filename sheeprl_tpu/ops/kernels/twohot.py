@@ -22,8 +22,8 @@ matters for values landing *exactly* on a bin edge — and there the two-hot
 weights are continuous, so the result still agrees to float tolerance.
 
 Gradients: ``jax.custom_vjp`` with the Pallas kernel on the forward and the
-reference chain re-derived on the backward. Interpret mode on non-TPU
-backends, as everywhere in the kernel tier.
+reference chain re-derived on the backward. Interpret mode only in a process
+with no TPU, as everywhere in the kernel tier.
 """
 
 from __future__ import annotations
@@ -183,7 +183,7 @@ def _build_loss(low: float, high: float):
     @jax.custom_vjp
     def loss(logits, value):
         return registry.platform_dispatch(
-            functools.partial(_loss_pallas_forward, low=low, high=high), logits, value
+            functools.partial(_loss_pallas_forward, low=low, high=high), reference, logits, value
         )
 
     def fwd(logits, value):
@@ -204,7 +204,7 @@ def _build_decode(low: float, high: float):
     @jax.custom_vjp
     def decode(logits):
         return registry.platform_dispatch(
-            functools.partial(_decode_pallas_forward, low=low, high=high), logits
+            functools.partial(_decode_pallas_forward, low=low, high=high), reference, logits
         )
 
     def fwd(logits):

@@ -99,10 +99,7 @@ def maybe_init(
     # ships in jaxlib; the flag only shapes CPU client creation, so it is
     # harmless on real accelerators. Must be set BEFORE initialize().
     if not os.environ.get("JAX_CPU_COLLECTIVES_IMPLEMENTATION"):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception as e:  # pragma: no cover - older/newer jaxlib knob drift
-            warnings.warn(f"could not select gloo CPU collectives: {e}")
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     retries = max(0, int(cfg.get("connect_retries", 3) or 0))
     backoff_s = max(0.0, float(cfg.get("connect_backoff_s", 1.0) or 0.0))
     init_kwargs: Dict[str, Any] = {}

@@ -446,4 +446,8 @@ class PodLauncher:
 def run_pod(cfg: Any, argv: List[str]) -> Dict[str, Any]:
     """CLI entrypoint body for ``sheeprl_tpu run --pod N`` — see
     :class:`PodLauncher`."""
+    from sheeprl_tpu.utils.utils import pin_cpu_platform, refuse_children_on_tpu
+
+    pin_cpu_platform((cfg.get("fabric") or {}).get("accelerator", "auto"))
+    refuse_children_on_tpu("run --pod", "N worker processes on this host")
     return PodLauncher(cfg, argv).run()

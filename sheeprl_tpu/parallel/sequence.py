@@ -27,9 +27,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
+from jax.lax import axis_size
 
 from sheeprl_tpu.ops.attention import block_attention, online_softmax_merge, _bh_to_bqh
-from sheeprl_tpu.parallel.compat import axis_size, shard_map
 
 __all__ = [
     "ring_attention",

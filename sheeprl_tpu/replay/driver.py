@@ -31,6 +31,7 @@ from sheeprl_tpu.data.ring import (
 )
 from sheeprl_tpu.replay.device_buffer import DeviceReplayState
 from sheeprl_tpu.utils.burst import init_device_ring
+from sheeprl_tpu.utils.utils import host_cpu_device
 
 __all__ = ["AsyncSequenceRing", "SeqBlobWriter", "SequenceRingDriver"]
 
@@ -99,7 +100,7 @@ class SequenceRingDriver:
         # Packed flushes read the key bytes on the host; a device-resident
         # key would cost one device pull per env step (threefry is platform-
         # deterministic, so the stream is unchanged).
-        self._host_device = jax.local_devices(backend="cpu")[0]
+        self._host_device = host_cpu_device()
         self._key = jax.device_put(jax.random.PRNGKey(seed), self._host_device)
         if isinstance(restore, DeviceReplayState):
             self.load_state_dict(restore)

@@ -801,7 +801,9 @@ def serve_fleet(cfg: Any) -> None:
     checkpoint dir, stand the router front end over them, run until SIGTERM
     / SIGINT (graceful fleet drain, exit 0) or ``serve.max_requests``."""
     from sheeprl_tpu.serve.server import install_drain_handlers
+    from sheeprl_tpu.utils.utils import refuse_children_on_tpu
 
+    refuse_children_on_tpu("serve --fleet", "N replica processes on this host")
     serve_cfg = dict(cfg.get("serve", {}) or {})
     fleet_cfg = dict(serve_cfg.get("fleet", {}) or {})
     n = int(fleet_cfg.get("replicas", 0) or 0)

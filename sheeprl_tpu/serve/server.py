@@ -559,7 +559,11 @@ def serve_policy(fabric, cfg: Dict[str, Any], state: Dict[str, Any], builder) ->
 
     from sheeprl_tpu.envs.factory import make_env
     from sheeprl_tpu.utils.logger import get_log_dir
+    from sheeprl_tpu.utils.utils import refuse_children_on_tpu
 
+    flywheel = (cfg.get("serve") or {}).get("flywheel") or {}
+    if flywheel.get("enabled") and flywheel.get("learner", True):  # before anything is built
+        refuse_children_on_tpu("serve --flywheel", "a learner process beside the server")
     log_dir = get_log_dir(cfg, cfg.root_dir, cfg.run_name) if cfg.get("root_dir") and cfg.get("run_name") else None
     env = make_env(cfg, cfg.seed, 0, log_dir, "serve", vector_env_idx=0)()
     observation_space = env.observation_space

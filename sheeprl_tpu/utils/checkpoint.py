@@ -44,6 +44,8 @@ from typing import Any, Dict, Optional
 import jax
 import numpy as np
 
+from sheeprl_tpu.utils.utils import host_cpu_device
+
 __all__ = ["CheckpointError", "save_state", "load_state", "write_host_checkpoint"]
 
 _FORMAT_KEY = "__sheeprl_tpu_ckpt__"
@@ -67,15 +69,15 @@ def stage_to_host(tree: Any) -> Any:
     """Enqueue device→host pulls for every jax leaf WITHOUT blocking.
 
     The pulls are issued up front (``device_put`` to the host CPU device is
-    asynchronous) so a remote accelerator pays one pipelined batch instead of
-    a full round-trip per leaf; :func:`finalize_host` synchronizes. The async
+    asynchronous) so the copies of all leaves overlap instead of one blocking
+    pull per leaf; :func:`finalize_host` synchronizes. The async
     checkpoint path calls this on the training thread and finalizes on the
     writer thread, overlapping the transfer + serialization with the next
     train block."""
-    # local_devices, not devices: in a multi-process pod the global device
-    # list leads with process 0's devices, and device_put to another
-    # process's CPU is a fatal XLA error on every rank but 0
-    cpu = jax.local_devices(backend="cpu")[0]
+    # this process's CPU device, not devices("cpu")[0]: in a multi-process
+    # pod the global list leads with process 0's devices, and device_put to
+    # another process's CPU is a fatal XLA error on every rank but 0
+    cpu = host_cpu_device()
 
     def pull(x):
         if isinstance(x, jax.Array):

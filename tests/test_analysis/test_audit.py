@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from sheeprl_tpu.analysis.audit import (
     AUDIT_RULES,
@@ -23,7 +24,6 @@ from sheeprl_tpu.analysis.audit import (
 )
 from sheeprl_tpu.analysis.budgets import check_budgets, manifest_from_measurements
 from sheeprl_tpu.analysis.programs import AuditMesh, AuditProgram
-from sheeprl_tpu.parallel.compat import shard_map
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -80,7 +80,7 @@ def test_planted_resharded_feedback_output_fails_aud002(mesh):
 
 
 def test_planted_f64_leak_fails_aud003(mesh):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fn = jax.jit(lambda x: jnp.asarray(x, jnp.float64) * np.float64(2.0))
         prog = AuditProgram(
             name="planted.f64",
@@ -196,14 +196,16 @@ def test_pr8_pinned_fix_shape_passes(mesh):
 
 def test_sharding_fingerprint_normalizes_equivalent_placements(mesh):
     """Two avals-equal programs with distinct cache keys: the NORMALIZED
-    fingerprint maps the NamedSharding and its GSPMD spelling to the same
+    fingerprint maps two spellings of one placement (a one-device
+    NamedSharding and the SingleDeviceSharding of that device) to the same
     identity (so drift checks compare placement, not spelling), while the
     CACHE-KEY fingerprint keeps them distinct (the PR 8 gap)."""
-    named = NamedSharding(mesh, P(None, "dp"))
-    gspmd = jax.sharding.GSPMDSharding(list(mesh.devices.flat), named._to_xla_hlo_sharding(2))
-    assert named.is_equivalent_to(gspmd, 2)
-    assert sharding_fingerprint(named, 2) == sharding_fingerprint(gspmd, 2)
-    assert sharding_cache_fingerprint(named, 2) != sharding_cache_fingerprint(gspmd, 2)
+    device = mesh.devices.flat[0]
+    named = NamedSharding(jax.sharding.Mesh(np.array([device]), ("dp",)), P())
+    single = jax.sharding.SingleDeviceSharding(device)
+    assert named.is_equivalent_to(single, 2)
+    assert sharding_fingerprint(named, 2) == sharding_fingerprint(single, 2)
+    assert sharding_cache_fingerprint(named, 2) != sharding_cache_fingerprint(single, 2)
 
 
 # --------------------------------------------------------------------------- #

@@ -562,7 +562,7 @@ def test_reachability_via_shard_map_edge():
     fs = lint(
         """
         import jax
-        from sheeprl_tpu.parallel.compat import shard_map
+        from jax import shard_map
 
         def make(mesh, spec):
             def local_train(x):

@@ -198,7 +198,7 @@ def test_comm_wire_guard_rides_the_ledger():
         mesh = jax.sharding.Mesh(np.array(jax.devices("cpu")[:2]), ("dp",))
         from jax.sharding import PartitionSpec as P
 
-        from sheeprl_tpu.parallel.compat import shard_map
+        from jax import shard_map
 
         f = shard_map(
             lambda g: pmean_grads(g, "dp"), mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),

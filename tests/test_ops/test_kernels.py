@@ -50,12 +50,13 @@ def _tree(rng, leaves=64, filled=40):
 def _ring_case(rng, C=8, E=5, S=4, e=3, feat=(2,), dtype=np.float32):
     from sheeprl_tpu.data.ring import ring_append_rows
 
-    pos = jnp.asarray([1, C - 1, 3], jnp.int32)  # includes a wrapping head
-    valid = jnp.asarray([1, C - 1, 3], jnp.int32)
-    mask = jnp.asarray([[1, 1, 1], [1, 0, 1], [0, 0, 1], [1, 0, 0]], jnp.int32)
+    heads = [1, C - 1, 3][:e]  # includes a wrapping head
+    pos = jnp.asarray(heads, jnp.int32)
+    valid = jnp.asarray(heads, jnp.int32)
+    mask = jnp.asarray([[1, 1, 1], [1, 0, 1], [0, 0, 1], [1, 0, 0]], jnp.int32)[:S, :e]
     row, _, _ = ring_append_rows(pos, valid, mask, C)
-    storage = jnp.asarray(rng.normal(size=(C, E) + feat).astype(dtype))
-    staged = jnp.asarray(rng.normal(size=(S, e) + feat).astype(dtype))
+    storage = jnp.asarray((rng.normal(size=(C, E) + feat) * 50).astype(dtype))
+    staged = jnp.asarray((rng.normal(size=(S, e) + feat) * 50).astype(dtype))
     return storage, staged, row, pos
 
 
@@ -368,3 +369,117 @@ def test_cache_size_one_per_kernel(backend):
         jax.block_until_ready(jitted(*args))
         jax.block_until_ready(jitted(*args))
         assert jitted._cache_size() == 1, f"{name} retraced under backend={backend}"
+
+
+# ---------------------------------------------------------------------------
+# what a process that holds a TPU gets (chipless: the TPU is pretended, the
+# TPU compiler is libtpu's own, asked through an AOT topology)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture()
+def pretend_tpu(monkeypatch):
+    from sheeprl_tpu.ops.kernels import registry
+
+    monkeypatch.setattr(registry, "_process_has_tpu", lambda: True)
+
+
+def _kernel_cases(rng):
+    """(name, args) at call-site shapes: Dreamer-S scans and heads, the
+    PPO-Anakin rollout, PER, and ring appends with ONE and with SEVERAL env
+    columns."""
+    f32 = np.float32
+    tree = _tree(rng, leaves=64, filled=40)
+    u = jnp.asarray(rng.uniform(size=(8,)).astype(f32))
+    yield "gru_gates", (jnp.ones((16, 3 * 512), f32), jnp.ones((16, 512), f32))
+    yield "two_hot_symlog_loss", (_norm_logits(rng, (64, 16, 255)), jnp.ones((64, 16, 1), f32))
+    yield "two_hot_symexp_decode", (_norm_logits(rng, (16, 64, 255)),)
+    yield "gae", _gae_inputs(rng, T=128, B=4) + (0.99, 0.95)
+    yield "sumtree_sample", (tree, u, jnp.int32(40), jnp.float32(0.4))
+    yield "ragged_ring_scatter", _ring_case(rng, C=8, E=3, S=4, e=3, feat=(18,))
+    yield "ragged_ring_scatter", _ring_case(rng, C=8, E=1, S=4, e=1, feat=(64, 64, 3), dtype=np.uint8)
+    yield "ragged_ring_scatter", _ring_case(rng, C=8, E=3, S=4, e=3, feat=(64, 64, 3), dtype=np.uint8)
+
+
+def _split_statics(args):
+    arrays = tuple(a for a in args if isinstance(a, jax.Array))
+    statics = tuple(a for a in args if not isinstance(a, jax.Array))
+    return arrays, statics
+
+
+def test_auto_on_a_tpu_is_pallas_except_the_kernels_routed_by_name(pretend_tpu):
+    with K.use_backend("auto"):
+        for name in K.names():
+            want = "lax" if name in K.AUTO_LAX_ON_TPU else "pallas"
+            assert K.resolve(name) == want and K.tier(name) == want
+    assert set(K.AUTO_LAX_ON_TPU) == {"sumtree_sample"}
+    with K.use_backend("pallas"):  # an explicit choice still reaches the kernel
+        assert K.resolve("sumtree_sample") == "pallas"
+
+
+def test_interpret_tier_is_named_without_a_tpu():
+    with K.use_backend("pallas"):
+        assert all(K.tier(name) == "pallas-interpret" for name in K.names())
+
+
+def test_cpu_lowering_in_a_tpu_process_takes_the_reference(pretend_tpu):
+    # the hybrid host player's case: Pallas tier selected, lowering for CPU
+    for name, args in dict(_kernel_cases(_rng(7))).items():  # one case per kernel
+        kernel = K.get(name)
+        arrays, statics = _split_statics(args)
+        pallas = jax.jit(lambda *xs, _f=kernel.pallas, _s=statics: _f(*xs, *_s))
+        reference = jax.jit(lambda *xs, _f=kernel.reference, _s=statics: _f(*xs, *_s))
+        assert "custom_call" not in pallas.lower(*arrays).as_text(), name  # neither Mosaic nor the interpreter
+        for got, want in zip(jax.tree.leaves(pallas(*arrays)), jax.tree.leaves(reference(*arrays))):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want), err_msg=name)
+
+
+@pytest.fixture(scope="module")
+def tpu_topology():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu in this installation
+        pytest.skip(f"no TPU compiler to ask: {e}")
+
+
+def _compile_for_tpu(topology, fn, arrays):
+    sharding = jax.sharding.SingleDeviceSharding(topology.devices[0])
+    avals = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding) for a in arrays]
+    return jax.jit(fn).trace(*avals).lower(lowering_platforms=("tpu",)).compile()
+
+
+def test_every_pallas_kernel_compiles_for_tpu(pretend_tpu, tpu_topology):
+    """The kernel AS DISPATCHED (custom_vjp, platform choice and all), at its
+    call-site shapes, through the TPU compiler — no chip needed. Kernels the
+    registry routes to lax by name must still be refused, or the routing is
+    stale."""
+    for name, args in _kernel_cases(_rng(8)):
+        arrays, statics = _split_statics(args)
+        fn = lambda *xs, _f=K.get(name).pallas, _s=statics: _f(*xs, *_s)  # noqa: E731
+        if name in K.AUTO_LAX_ON_TPU:
+            with pytest.raises(Exception, match="Shape mismatch in input, indices and output"):
+                _compile_for_tpu(tpu_topology, fn, arrays)
+            continue
+        compiled = _compile_for_tpu(tpu_topology, fn, arrays)
+        assert "tpu_custom_call" in compiled.as_text(), name
+
+
+@pytest.mark.parametrize(
+    "hidden,dtype",
+    [(512, jnp.float32), (1024, jnp.float32), (2048, jnp.float32), (4096, jnp.float32), (4096, jnp.bfloat16)],
+    ids=["S", "M", "L", "XL", "XL-bf16"],
+)
+def test_gru_gates_fits_scoped_vmem_at_every_configured_width(pretend_tpu, tpu_topology, hidden, dtype):
+    # the imagination scan's rows: batch 16 x sequence 64
+    arrays = (jnp.zeros((1024, 3 * hidden), dtype), jnp.zeros((1024, hidden), dtype))
+    _compile_for_tpu(tpu_topology, K.get("gru_gates").pallas, arrays)
+
+
+def test_gru_gates_block_that_cannot_fit_is_named():
+    from sheeprl_tpu.ops.kernels import gru
+
+    assert gru._block_rows(16, 512, 4) == 16  # a batch under one block is one block
+    with pytest.raises(ValueError, match="H=1048576"):
+        gru._block_rows(1024, 1 << 20, 4)

@@ -9,10 +9,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from sheeprl_tpu.parallel.comm import get_grad_reduce_dtype, pmean_grads, set_grad_reduce_dtype
 from sheeprl_tpu.parallel.fabric import Fabric
-from sheeprl_tpu.parallel.compat import shard_map
 
 
 @pytest.fixture(autouse=True)

@@ -27,7 +27,7 @@ maybe_init()  # env-var driven: SHEEPRL_COORDINATOR/NUM_PROCESSES/PROCESS_ID
 
 import jax.numpy as jnp
 import numpy as np
-from sheeprl_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 assert jax.process_count() == 2, jax.process_count()
@@ -79,7 +79,7 @@ maybe_init()
 
 import jax.numpy as jnp
 import numpy as np
-from sheeprl_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 assert jax.process_count() == 2, jax.process_count()
