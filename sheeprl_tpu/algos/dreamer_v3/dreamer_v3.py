@@ -61,6 +61,7 @@ from sheeprl_tpu.parallel.comm import pmean_grads
 from sheeprl_tpu.envs.factory import vectorize_env
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
 from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric, build_aggregator
+from sheeprl_tpu.utils import profiler as recorder
 from sheeprl_tpu.utils.registry import register_algorithm
 from sheeprl_tpu.utils.timer import timer
 from sheeprl_tpu.utils.utils import Ratio, resolve_hybrid_player, save_configs
@@ -160,134 +161,152 @@ def make_train_step(
         k_dyn, k_img = jax.random.split(key)
 
         # -- target-critic EMA gate (reference: dreamer_v3.py:676-682)
-        tau_eff = jnp.where(cum == 0, 1.0, tau)
-        mix = jnp.where(cum % target_update_freq == 0, tau_eff, 0.0)
-        params = {
-            **params,
-            "target_critic": jax.tree.map(
-                lambda c, t: mix * c + (1.0 - mix) * t, params["critic"], params["target_critic"]
-            ),
-        }
+        with jax.named_scope("target.ema"):
+            tau_eff = jnp.where(cum == 0, 1.0, tau)
+            mix = jnp.where(cum % target_update_freq == 0, tau_eff, 0.0)
+            params = {
+                **params,
+                "target_critic": jax.tree.map(
+                    lambda c, t: mix * c + (1.0 - mix) * t, params["critic"], params["target_critic"]
+                ),
+            }
 
-        batch_obs = {k: batch[k] / 255.0 - 0.5 for k in cnn_enc}
-        batch_obs.update({k: batch[k] for k in mlp_enc})
-        is_first = batch["is_first"].at[0].set(1.0)
-        batch_actions = jnp.concatenate([jnp.zeros_like(batch["actions"][:1]), batch["actions"][:-1]], axis=0)
+        # Region names (utils.profiler.REGIONS): a scope only writes op_name
+        # metadata, which the optimized executable keeps per instruction.
+        with jax.named_scope("wm.encoder"):
+            batch_obs = {k: batch[k] / 255.0 - 0.5 for k in cnn_enc}
+            batch_obs.update({k: batch[k] for k in mlp_enc})
+        with jax.named_scope("wm.dynamics"):
+            is_first = batch["is_first"].at[0].set(1.0)
+            batch_actions = jnp.concatenate(
+                [jnp.zeros_like(batch["actions"][:1]), batch["actions"][:-1]], axis=0
+            )
 
         # -- world-model update (reference train(): dreamer_v3.py:92-196)
         def wm_loss_fn(wmp):
-            embedded = world_model.encoder.apply(wmp["encoder"], batch_obs)
-            recs, posts, post_logits, prior_logits = dynamic_rollout(
-                wmp, embedded, batch_actions, is_first, k_dyn
-            )
-            latents = jnp.concatenate([posts, recs], axis=-1)
-            recon = world_model.decode(wmp, latents)
-            po = {k: MSEDistribution(recon[k], dims=3) for k in cnn_dec}
-            po.update({k: SymlogDistribution(recon[k], dims=1) for k in mlp_dec})
-            pr = TwoHotEncodingDistribution(world_model.reward_model.apply(wmp["reward_model"], latents), dims=1)
-            pc = Independent(
-                BernoulliSafeMode(logits=world_model.continue_model.apply(wmp["continue_model"], latents)), 1
-            )
-            continue_targets = 1 - batch["terminated"]
-            rec_loss, kl, state_loss, reward_loss, observation_loss, continue_loss = reconstruction_loss(
-                po,
-                batch_obs,
-                pr,
-                batch["rewards"],
-                prior_logits.reshape(*prior_logits.shape[:-1], stochastic_size, discrete_size),
-                post_logits.reshape(*post_logits.shape[:-1], stochastic_size, discrete_size),
-                float(wm_cfg.kl_dynamic),
-                float(wm_cfg.kl_representation),
-                float(wm_cfg.kl_free_nats),
-                float(wm_cfg.kl_regularizer),
-                pc,
-                continue_targets,
-                float(wm_cfg.continue_scale_factor),
-            )
+            with jax.named_scope("wm.encoder"):
+                embedded = world_model.encoder.apply(wmp["encoder"], batch_obs)
+            with jax.named_scope("wm.dynamics"):
+                recs, posts, post_logits, prior_logits = dynamic_rollout(
+                    wmp, embedded, batch_actions, is_first, k_dyn
+                )
+                latents = jnp.concatenate([posts, recs], axis=-1)
+            with jax.named_scope("wm.decoder"):
+                recon = world_model.decode(wmp, latents)
+            with jax.named_scope("wm.heads"):
+                po = {k: MSEDistribution(recon[k], dims=3) for k in cnn_dec}
+                po.update({k: SymlogDistribution(recon[k], dims=1) for k in mlp_dec})
+                pr = TwoHotEncodingDistribution(
+                    world_model.reward_model.apply(wmp["reward_model"], latents), dims=1
+                )
+                pc = Independent(
+                    BernoulliSafeMode(logits=world_model.continue_model.apply(wmp["continue_model"], latents)), 1
+                )
+                continue_targets = 1 - batch["terminated"]
+                rec_loss, kl, state_loss, reward_loss, observation_loss, continue_loss = reconstruction_loss(
+                    po,
+                    batch_obs,
+                    pr,
+                    batch["rewards"],
+                    prior_logits.reshape(*prior_logits.shape[:-1], stochastic_size, discrete_size),
+                    post_logits.reshape(*post_logits.shape[:-1], stochastic_size, discrete_size),
+                    float(wm_cfg.kl_dynamic),
+                    float(wm_cfg.kl_representation),
+                    float(wm_cfg.kl_free_nats),
+                    float(wm_cfg.kl_regularizer),
+                    pc,
+                    continue_targets,
+                    float(wm_cfg.continue_scale_factor),
+                )
             aux = (recs, posts, post_logits, prior_logits, kl, state_loss, reward_loss, observation_loss, continue_loss)
             return rec_loss, aux
 
         (rec_loss, wm_aux), wm_grads = jax.value_and_grad(wm_loss_fn, has_aux=True)(params["world_model"])
         recs, posts, post_logits, prior_logits, kl, state_loss, reward_loss, observation_loss, continue_loss = wm_aux
-        wm_grads = pmean_grads(wm_grads, "dp")
-        wupd, opts["world"] = txs["world"].update(wm_grads, opts["world"], params["world_model"])
-        params = {**params, "world_model": optax.apply_updates(params["world_model"], wupd)}
+        with jax.named_scope("wm.optim"):
+            wm_grads = pmean_grads(wm_grads, "dp")
+            wupd, opts["world"] = txs["world"].update(wm_grads, opts["world"], params["world_model"])
+            params = {**params, "world_model": optax.apply_updates(params["world_model"], wupd)}
 
         # -- behaviour learning (reference: dreamer_v3.py:198-301)
         wmp = params["world_model"]
         T, B = batch["actions"].shape[:2]
-        prior0 = jax.lax.stop_gradient(posts).reshape(T * B, stoch_state_size)
-        rec0 = jax.lax.stop_gradient(recs).reshape(T * B, recurrent_state_size)
-        true_continue = (1 - batch["terminated"]).reshape(1, T * B, 1)
+        with jax.named_scope("behaviour.imagination"):
+            prior0 = jax.lax.stop_gradient(posts).reshape(T * B, stoch_state_size)
+            rec0 = jax.lax.stop_gradient(recs).reshape(T * B, recurrent_state_size)
+            true_continue = (1 - batch["terminated"]).reshape(1, T * B, 1)
 
         def actor_loss_fn(ap, mstate):
-            latent0 = jnp.concatenate([prior0, rec0], axis=-1)
-            k0, k_scan = jax.random.split(k_img)
-            a0 = jnp.concatenate(actor_sample(actor, ap, jax.lax.stop_gradient(latent0), k0)[0], axis=-1)
+            with jax.named_scope("behaviour.imagination"):
+                latent0 = jnp.concatenate([prior0, rec0], axis=-1)
+                k0, k_scan = jax.random.split(k_img)
+                a0 = jnp.concatenate(actor_sample(actor, ap, jax.lax.stop_gradient(latent0), k0)[0], axis=-1)
 
-            def img_step(carry, k):
-                prior, rec, act = carry
-                k_prior, k_act = jax.random.split(k)
-                prior, rec = rssm.imagination(wmp, prior, rec, act, k_prior)
-                latent = jnp.concatenate([prior, rec], axis=-1)
-                new_act = jnp.concatenate(
-                    actor_sample(actor, ap, jax.lax.stop_gradient(latent), k_act)[0], axis=-1
+                def img_step(carry, k):
+                    prior, rec, act = carry
+                    k_prior, k_act = jax.random.split(k)
+                    prior, rec = rssm.imagination(wmp, prior, rec, act, k_prior)
+                    latent = jnp.concatenate([prior, rec], axis=-1)
+                    new_act = jnp.concatenate(
+                        actor_sample(actor, ap, jax.lax.stop_gradient(latent), k_act)[0], axis=-1
+                    )
+                    return (prior, rec, new_act), (latent, new_act)
+
+                _, (latents, acts) = jax.lax.scan(
+                    img_step, (prior0, rec0, a0), jax.random.split(k_scan, horizon)
                 )
-                return (prior, rec, new_act), (latent, new_act)
+                traj = jnp.concatenate([latent0[None], latents], axis=0)  # (H+1, TB, L)
+                imagined_actions = jnp.concatenate([a0[None], acts], axis=0)
 
-            _, (latents, acts) = jax.lax.scan(
-                img_step, (prior0, rec0, a0), jax.random.split(k_scan, horizon)
-            )
-            traj = jnp.concatenate([latent0[None], latents], axis=0)  # (H+1, TB, L)
-            imagined_actions = jnp.concatenate([a0[None], acts], axis=0)
+            with jax.named_scope("behaviour.returns"):
+                values = TwoHotEncodingDistribution(critic.apply(params["critic"], traj), dims=1).mean
+                rewards = TwoHotEncodingDistribution(
+                    world_model.reward_model.apply(wmp["reward_model"], traj), dims=1
+                ).mean
+                continues = Independent(
+                    BernoulliSafeMode(logits=world_model.continue_model.apply(wmp["continue_model"], traj)), 1
+                ).mode
+                continues = jnp.concatenate([true_continue, continues[1:]], axis=0)
 
-            values = TwoHotEncodingDistribution(critic.apply(params["critic"], traj), dims=1).mean
-            rewards = TwoHotEncodingDistribution(
-                world_model.reward_model.apply(wmp["reward_model"], traj), dims=1
-            ).mean
-            continues = Independent(
-                BernoulliSafeMode(logits=world_model.continue_model.apply(wmp["continue_model"], traj)), 1
-            ).mode
-            continues = jnp.concatenate([true_continue, continues[1:]], axis=0)
+                lambda_values = compute_lambda_values(rewards[1:], values[1:], continues[1:] * gamma, lmbda)
+                discount = jax.lax.stop_gradient(jnp.cumprod(continues * gamma, axis=0) / gamma)
 
-            lambda_values = compute_lambda_values(rewards[1:], values[1:], continues[1:] * gamma, lmbda)
-            discount = jax.lax.stop_gradient(jnp.cumprod(continues * gamma, axis=0) / gamma)
-
-            new_mstate, offset, invscale = moments_update(
-                mstate,
-                lambda_values,
-                decay=float(moments_cfg.decay),
-                max_=float(moments_cfg.max),
-                percentile_low=float(moments_cfg.percentile.low),
-                percentile_high=float(moments_cfg.percentile.high),
-                axis_name="dp",
-            )
-            normed_lambda = (lambda_values - offset) / invscale
-            normed_baseline = (values[:-1] - offset) / invscale
-            advantage = normed_lambda - normed_baseline
-
-            policies = actor_dists(actor, actor.apply(ap, jax.lax.stop_gradient(traj)))
-            if is_continuous:
-                objective = advantage
-            else:
-                act_parts = (
-                    jnp.split(imagined_actions, split_sizes, axis=-1)
-                    if len(actions_dim) > 1
-                    else [imagined_actions]
+                new_mstate, offset, invscale = moments_update(
+                    mstate,
+                    lambda_values,
+                    decay=float(moments_cfg.decay),
+                    max_=float(moments_cfg.max),
+                    percentile_low=float(moments_cfg.percentile.low),
+                    percentile_high=float(moments_cfg.percentile.high),
+                    axis_name="dp",
                 )
-                logprob = jnp.stack(
-                    [
-                        p.log_prob(jax.lax.stop_gradient(a))[..., None][:-1]
-                        for p, a in zip(policies, act_parts)
-                    ],
-                    axis=-1,
-                ).sum(-1)
-                objective = logprob * jax.lax.stop_gradient(advantage)
-            try:
-                entropy = ent_coef * jnp.stack([p.entropy() for p in policies], axis=-1).sum(-1)
-            except NotImplementedError:  # e.g. TanhNormal (reference: dreamer_v3.py:293-296)
-                entropy = jnp.zeros(traj.shape[:-1], dtype=traj.dtype)
-            policy_loss = -jnp.mean(discount[:-1] * (objective + entropy[..., None][:-1]))
+                normed_lambda = (lambda_values - offset) / invscale
+                normed_baseline = (values[:-1] - offset) / invscale
+                advantage = normed_lambda - normed_baseline
+
+            with jax.named_scope("behaviour.heads"):
+                policies = actor_dists(actor, actor.apply(ap, jax.lax.stop_gradient(traj)))
+                if is_continuous:
+                    objective = advantage
+                else:
+                    act_parts = (
+                        jnp.split(imagined_actions, split_sizes, axis=-1)
+                        if len(actions_dim) > 1
+                        else [imagined_actions]
+                    )
+                    logprob = jnp.stack(
+                        [
+                            p.log_prob(jax.lax.stop_gradient(a))[..., None][:-1]
+                            for p, a in zip(policies, act_parts)
+                        ],
+                        axis=-1,
+                    ).sum(-1)
+                    objective = logprob * jax.lax.stop_gradient(advantage)
+                try:
+                    entropy = ent_coef * jnp.stack([p.entropy() for p in policies], axis=-1).sum(-1)
+                except NotImplementedError:  # e.g. TanhNormal (reference: dreamer_v3.py:293-296)
+                    entropy = jnp.zeros(traj.shape[:-1], dtype=traj.dtype)
+                policy_loss = -jnp.mean(discount[:-1] * (objective + entropy[..., None][:-1]))
             aux = (
                 jax.lax.stop_gradient(traj),
                 jax.lax.stop_gradient(lambda_values),
@@ -299,30 +318,38 @@ def make_train_step(
         (policy_loss, (traj_sg, lambda_sg, discount, moments_state)), actor_grads = jax.value_and_grad(
             actor_loss_fn, has_aux=True
         )(params["actor"], moments_state)
-        actor_grads = pmean_grads(actor_grads, "dp")
-        aupd, opts["actor"] = txs["actor"].update(actor_grads, opts["actor"], params["actor"])
-        params = {**params, "actor": optax.apply_updates(params["actor"], aupd)}
+        with jax.named_scope("behaviour.optim"):
+            actor_grads = pmean_grads(actor_grads, "dp")
+            aupd, opts["actor"] = txs["actor"].update(actor_grads, opts["actor"], params["actor"])
+            params = {**params, "actor": optax.apply_updates(params["actor"], aupd)}
 
         # -- critic update (reference: dreamer_v3.py:303-323)
         def critic_loss_fn(cp):
-            qv = TwoHotEncodingDistribution(critic.apply(cp, traj_sg[:-1]), dims=1)
-            target_values = TwoHotEncodingDistribution(
-                critic.apply(params["target_critic"], traj_sg[:-1]), dims=1
-            ).mean
-            vloss = -qv.log_prob(lambda_sg) - qv.log_prob(jax.lax.stop_gradient(target_values))
-            return jnp.mean(vloss * discount[:-1, ..., 0])
+            with jax.named_scope("behaviour.heads"):
+                qv = TwoHotEncodingDistribution(critic.apply(cp, traj_sg[:-1]), dims=1)
+                target_values = TwoHotEncodingDistribution(
+                    critic.apply(params["target_critic"], traj_sg[:-1]), dims=1
+                ).mean
+                vloss = -qv.log_prob(lambda_sg) - qv.log_prob(jax.lax.stop_gradient(target_values))
+                return jnp.mean(vloss * discount[:-1, ..., 0])
 
         value_loss, critic_grads = jax.value_and_grad(critic_loss_fn)(params["critic"])
-        critic_grads = pmean_grads(critic_grads, "dp")
-        cupd, opts["critic"] = txs["critic"].update(critic_grads, opts["critic"], params["critic"])
-        params = {**params, "critic": optax.apply_updates(params["critic"], cupd)}
+        with jax.named_scope("behaviour.optim"):
+            critic_grads = pmean_grads(critic_grads, "dp")
+            cupd, opts["critic"] = txs["critic"].update(critic_grads, opts["critic"], params["critic"])
+            params = {**params, "critic": optax.apply_updates(params["critic"], cupd)}
 
-        post_ent = Independent(
-            OneHotCategorical(logits=post_logits.reshape(*post_logits.shape[:-1], stochastic_size, discrete_size)), 1
-        ).entropy().mean()
-        prior_ent = Independent(
-            OneHotCategorical(logits=prior_logits.reshape(*prior_logits.shape[:-1], stochastic_size, discrete_size)), 1
-        ).entropy().mean()
+        with jax.named_scope("wm.heads"):
+            post_ent = Independent(
+                OneHotCategorical(
+                    logits=post_logits.reshape(*post_logits.shape[:-1], stochastic_size, discrete_size)
+                ), 1
+            ).entropy().mean()
+            prior_ent = Independent(
+                OneHotCategorical(
+                    logits=prior_logits.reshape(*prior_logits.shape[:-1], stochastic_size, discrete_size)
+                ), 1
+            ).entropy().mean()
         metrics = (
             rec_loss, observation_loss, reward_loss, state_loss, continue_loss,
             kl, post_ent, prior_ent, policy_loss, value_loss,
@@ -693,6 +720,14 @@ def main(fabric, cfg: Dict[str, Any]):
     for iter_num in range(start_iter, total_iters + 1):
         profiler.tick(iter_num)
         policy_step += policy_steps_per_iter
+        # Host spans (utils.profiler.SPANS): one `iter` per iteration, closed
+        # at the loop's foot; its counters are the values it starts from.
+        iter_span = recorder.span(
+            "iter", parent=recorder.ROOT, iter_num=iter_num, policy_step=policy_step,
+            grad_steps=cumulative_per_rank_gradient_steps,
+            grant_backlog=hp.grant_backlog if burst_mode else 0,
+            staged_rows=hp.runner.staged_count if burst_mode else 0,
+        ).start()
 
         if burst_mode:
             hp.poll()
@@ -708,31 +743,34 @@ def main(fabric, cfg: Dict[str, Any]):
                         axis=-1,
                     )
             else:
-                jobs = prepare_obs(fabric, obs, cnn_keys=cnn_keys, num_envs=cfg.env.num_envs)
+                with recorder.span("player.act"):  # policy forward and the pull of its actions
+                    jobs = prepare_obs(fabric, obs, cnn_keys=cnn_keys, num_envs=cfg.env.num_envs)
+                    if burst_mode:
+                        # Host-CPU policy on the snapshot params: numpy obs +
+                        # CPU-committed params keep the whole step off the wire.
+                        action_list = host_player.get_actions(hp.host_params, jobs, hp.host_key())
+                    else:
+                        rng, subkey = jax.random.split(rng)
+                        action_list = player.get_actions(params, jobs, subkey)
+                    actions = np.asarray(jnp.concatenate(action_list, axis=-1))
+                    if is_continuous:
+                        real_actions = actions
+                    else:
+                        real_actions = np.stack([np.asarray(a).argmax(axis=-1) for a in action_list], axis=-1)
+
+            with recorder.span("stage"):
+                step_data["actions"] = actions.reshape(1, cfg.env.num_envs, -1)
+                if host_mirror:
+                    rb.add(step_data, validate_args=cfg.buffer.validate_args)
                 if burst_mode:
-                    # Host-CPU policy on the snapshot params: numpy obs +
-                    # CPU-committed params keep the whole step off the wire.
-                    action_list = host_player.get_actions(hp.host_params, jobs, hp.host_key())
-                else:
-                    rng, subkey = jax.random.split(rng)
-                    action_list = player.get_actions(params, jobs, subkey)
-                actions = np.asarray(jnp.concatenate(action_list, axis=-1))
-                if is_continuous:
-                    real_actions = actions
-                else:
-                    real_actions = np.stack([np.asarray(a).argmax(axis=-1) for a in action_list], axis=-1)
+                    hp.stage_step(step_data)
+                elif resident_mode:
+                    resident_driver.stage_step(step_data)
 
-            step_data["actions"] = actions.reshape(1, cfg.env.num_envs, -1)
-            if host_mirror:
-                rb.add(step_data, validate_args=cfg.buffer.validate_args)
-            if burst_mode:
-                hp.stage_step(step_data)
-            elif resident_mode:
-                resident_driver.stage_step(step_data)
-
-            next_obs, rewards, terminated, truncated, infos = envs.step(
-                real_actions.reshape(envs.action_space.shape)
-            )
+            with recorder.span("env.step"):  # the wait for the env workers
+                next_obs, rewards, terminated, truncated, infos = envs.step(
+                    real_actions.reshape(envs.action_space.shape)
+                )
             dones = np.logical_or(terminated, truncated).astype(np.uint8)
 
         step_data["is_first"] = np.zeros_like(step_data["terminated"])
@@ -800,12 +838,13 @@ def main(fabric, cfg: Dict[str, Any]):
             reset_data["actions"] = np.zeros((1, reset_envs, int(np.sum(actions_dim))), dtype=np.float32)
             reset_data["rewards"] = step_data["rewards"][:, dones_idxes]
             reset_data["is_first"] = np.zeros_like(reset_data["terminated"])
-            if host_mirror:
-                rb.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
-            if burst_mode:
-                hp.stage_reset(reset_data, dones_idxes)
-            elif resident_mode:
-                resident_driver.stage_reset(reset_data, dones_idxes)
+            with recorder.span("stage"):
+                if host_mirror:
+                    rb.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
+                if burst_mode:
+                    hp.stage_reset(reset_data, dones_idxes)
+                elif resident_mode:
+                    resident_driver.stage_reset(reset_data, dones_idxes)
 
             # Reset already-inserted step data (reference: dreamer_v3.py:652-658)
             step_data["rewards"][:, dones_idxes] = np.zeros_like(reset_data["rewards"])
@@ -964,6 +1003,7 @@ def main(fabric, cfg: Dict[str, Any]):
                 state=ckpt_state,
                 replay_buffer=replay_ckpt,
             )
+        iter_span.stop()
 
     if burst_mode:
         # Flush the tail: Ratio already counted the remaining grants; grants

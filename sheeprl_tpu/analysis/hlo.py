@@ -34,6 +34,7 @@ __all__ = [
     "parse_output_pinning",
     "large_constants",
     "find_dtype",
+    "op_scopes",
 ]
 
 #: HLO short dtype -> bytes per element (unknown dtypes default to 4 at the
@@ -225,3 +226,49 @@ def find_dtype(stablehlo_text: str, dtype: str) -> int:
     """Occurrences of ``dtype`` (HLO/StableHLO short name, e.g. ``f64``) in
     tensor types of the lowered text — 0 means the program never touches it."""
     return len(re.findall(rf"tensor<(?:[0-9x]+x)?{re.escape(dtype)}>", stablehlo_text))
+
+
+_INSTRUCTION_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([A-Za-z_][\w.\-]*)\s*=\s")
+_OP_NAME_RE = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_NAME_STACK_SPLIT_RE = re.compile(r"[/()\[\]]")
+
+
+def op_scopes(
+    compiled_hlo_text: str, regions: Tuple[str, ...], kernel_prefix: str = "kernel."
+) -> Dict[str, Dict[str, object]]:
+    """Which ``jax.named_scope`` each instruction of an optimized executable
+    ran under: ``{instruction: {"scope", "outer", "backward"}}``.
+
+    A device trace names an event by its instruction and carries no
+    ``op_name``; the executable's text carries both, so this table is the join
+    from a trace's instruction names to the program's own region names. Read
+    from each instruction's ``metadata={op_name="..."}`` alone (the first of a
+    ``;``-joined list) — nothing is guessed from an instruction's kind or
+    shape. ``outer`` is the outermost of ``regions`` on the name stack
+    (regions are disjoint by it), ``scope`` the innermost known name (a
+    region, or ``<kernel_prefix><name>``), both ``None`` where the path
+    holds neither or the instruction has no ``op_name``; ``backward`` says the
+    path went through ``transpose(...)``, which is how JAX marks the
+    backward pass of ``jvp(<scope>)``.
+    """
+    known = set(regions)
+    out: Dict[str, Dict[str, object]] = {}
+    for line in compiled_hlo_text.splitlines():
+        m = _INSTRUCTION_RE.match(line)
+        if not m:
+            continue
+        outer: Optional[str] = None
+        scope: Optional[str] = None
+        backward = False
+        meta = _OP_NAME_RE.search(line)
+        if meta:
+            op_name = meta.group(1).split(";", 1)[0]
+            for token in _NAME_STACK_SPLIT_RE.split(op_name):
+                if token in known:
+                    outer = outer or token
+                    scope = token
+                elif token.startswith(kernel_prefix) and len(token) > len(kernel_prefix):
+                    scope = token
+            backward = "transpose(" in op_name
+        out[m.group(1)] = {"scope": scope, "outer": outer, "backward": backward}
+    return out

@@ -263,10 +263,11 @@ def _granted_step(
         k, valid_flag = xs
 
         def _run(c):
-            k_env, k_start, k_grad = jax.random.split(k, 3)
-            env_idx = jax.random.randint(k_env, (batch_per_dev,), 0, ring_envs)
-            t_idx = sample_starts(k_start, env_idx)  # (T, B)
-            batch = {kk: storage[kk][t_idx, env_idx[None, :]] for kk in storage}
+            with jax.named_scope("ring.sample"):  # utils.profiler.REGIONS
+                k_env, k_start, k_grad = jax.random.split(k, 3)
+                env_idx = jax.random.randint(k_env, (batch_per_dev,), 0, ring_envs)
+                t_idx = sample_starts(k_start, env_idx)  # (T, B)
+                batch = {kk: storage[kk][t_idx, env_idx[None, :]] for kk in storage}
             nc, m = gradient_step(c, (batch, k_grad))
             # Metrics may be a tuple (Dreamers) or a dict (P2E) — keep the
             # structure, normalize the dtype for the masked mean.
@@ -313,10 +314,11 @@ def build_burst_train_step(
     def local_burst(carry, rb, staged, staged_mask, pos, valid_n, key, valid):
         # -- per-env ring append. Slot i writes env e iff staged_mask[i, e];
         # each env's rows pack densely from its own write head (ragged adds).
-        row, new_pos, new_valid = ring_append_rows(pos, valid_n, staged_mask, capacity)
-        # registry-dispatched ragged scatter (ops.kernels; the lax backend is
-        # the literal .at[row, cols].set(..., mode="drop") this site ran)
-        rb = {k: ragged_ring_scatter(rb[k], staged[k], row, pos) for k in rb}
+        with jax.named_scope("ring.append"):  # utils.profiler.REGIONS
+            row, new_pos, new_valid = ring_append_rows(pos, valid_n, staged_mask, capacity)
+            # registry-dispatched ragged scatter (ops.kernels; the lax backend is
+            # the literal .at[row, cols].set(..., mode="drop") this site ran)
+            rb = {k: ragged_ring_scatter(rb[k], staged[k], row, pos) for k in rb}
         # No env may be shorter than a sample window yet (the host buffer
         # raises in that case); until then every step is a no-op append.
         valid = valid * jnp.all(new_valid >= ring_seq).astype(valid.dtype)
@@ -325,9 +327,10 @@ def build_burst_train_step(
             # Ring contents are fixed after the single append above, so the
             # episode-validity table is computed ONCE per burst; each
             # gradient step then draws starts at O(batch).
-            ep_table, ep_n_valid = episode_window_table(
-                new_pos, new_valid, rb["is_first"], capacity, ring_seq
-            )
+            with jax.named_scope("ring.sample"):
+                ep_table, ep_n_valid = episode_window_table(
+                    new_pos, new_valid, rb["is_first"], capacity, ring_seq
+                )
             sample_starts = lambda k, env_idx: sample_window_starts(
                 k, env_idx, ep_table, ep_n_valid, capacity, ring_seq
             )
@@ -374,7 +377,8 @@ def build_burst_train_step(
 
         def packed_burst(carry, rb, blob):
             layout = by_length[blob.shape[0]]
-            u = unpack_burst_blob(blob, layout)
+            with jax.named_scope("ring.append"):
+                u = unpack_burst_blob(blob, layout)
             return shard_burst(
                 carry,
                 rb,

@@ -85,6 +85,7 @@ def _gae_pallas_forward(rewards, values, dones, next_value, *, gamma, gae_lambda
             jax.ShapeDtypeStruct((horizon, n), jnp.float32),
         ],
         interpret=interpret,
+        name="gae",
     )(r, v, nvs, nd)
     return returns.reshape(ret_aval.shape), advantages.reshape(adv_aval.shape)
 

@@ -25,7 +25,6 @@ triple.
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -107,10 +106,10 @@ def _pallas_forward(fused: jax.Array, h: jax.Array, interpret: bool) -> jax.Arra
         out_specs=pl.BlockSpec((block_b, H), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H), h.dtype),
         interpret=interpret,
+        name="gru_gates",
     )(fused, h)
 
 
-@functools.partial(jax.named_call, name="pallas_gru_gates")
 def _forward(fused: jax.Array, h: jax.Array) -> jax.Array:
     return registry.platform_dispatch(_pallas_forward, gru_gates_reference, fused, h)
 

@@ -39,6 +39,8 @@ from typing import Any, Callable, Dict, Mapping, Optional, Tuple
 
 import jax
 
+from sheeprl_tpu.utils.profiler import KERNEL_PREFIX
+
 __all__ = [
     "AUTO_LAX_ON_TPU",
     "Kernel",
@@ -223,9 +225,21 @@ def tier(name: str) -> str:
 
 
 def dispatch(name: str, backend: Optional[str] = None) -> Callable[..., Any]:
-    """The callable to run for kernel ``name`` under the active backend."""
+    """The callable to run for kernel ``name`` under the active backend: the
+    chosen implementation (``__wrapped__``) under
+    ``jax.named_scope("kernel.<name>")``, so that a compiled program marks the
+    same work by the same name whichever tier ran it
+    (``utils.profiler.KERNEL_PREFIX``; a scope only writes ``op_name``
+    metadata)."""
     kernel = get(name)
-    return kernel.pallas if resolve(name, backend) == "pallas" else kernel.reference
+    impl = kernel.pallas if resolve(name, backend) == "pallas" else kernel.reference
+
+    @functools.wraps(impl)
+    def scoped(*args: Any, **kwargs: Any) -> Any:
+        with jax.named_scope(KERNEL_PREFIX + name):
+            return impl(*args, **kwargs)
+
+    return scoped
 
 
 @contextlib.contextmanager

@@ -106,6 +106,7 @@ def _scatter_pallas_forward(storage, staged, row, pos, col_offset, *, interpret)
         out_shape=jax.ShapeDtypeStruct((capacity, env_cols) + cell, storage.dtype),
         input_output_aliases={4: 0},  # storage updates in place
         interpret=interpret,
+        name="ragged_ring_scatter",
     )(
         safe_row.reshape(slots * e),
         cols.reshape(slots * e),

@@ -83,6 +83,7 @@ def _sumtree_pallas_forward(tree, u, n_valid, beta, *, interpret):
             jax.ShapeDtypeStruct((1, batch), jnp.float32),
         ],
         interpret=interpret,
+        name="sumtree_sample",
     )(
         tree.astype(jnp.float32).reshape(1, 2 * leaves),
         u.astype(jnp.float32).reshape(1, batch),

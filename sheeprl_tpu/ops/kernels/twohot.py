@@ -151,6 +151,7 @@ def _loss_pallas_forward(logits, value, *, low, high, interpret):
         out_specs=pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1), out_aval.dtype),
         interpret=interpret,
+        name="two_hot_symlog_loss",
     )(logits2, value2)
     return out.reshape(out_aval.shape)
 
@@ -172,6 +173,7 @@ def _decode_pallas_forward(logits, *, low, high, interpret):
         out_specs=pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1), out_aval.dtype),
         interpret=interpret,
+        name="two_hot_symexp_decode",
     )(logits2)
     return out.reshape(out_aval.shape)
 

@@ -27,6 +27,7 @@ from jax.flatten_util import ravel_pytree
 
 from sheeprl_tpu.analysis.lockstats import sync_lock
 from sheeprl_tpu.data.ring import BlobLayout, effective_stage_buckets, make_blob_layouts, pack_burst_blob
+from sheeprl_tpu.utils import profiler
 from sheeprl_tpu.utils.utils import host_cpu_device
 
 __all__ = [
@@ -167,7 +168,8 @@ class HostSnapshot:
                 continue
             ctx.beat()
             fault_point("burst.snapshot.refresh")  # chaos: kill-thread mid-pull
-            placed = jax.device_put(packed, self.host_device)
+            with profiler.span("snapshot.refresh"):  # waits for the burst that made `packed`
+                placed = jax.device_put(packed, self.host_device)
             self._slot[0] = placed
             with self._pending_lock:
                 # a crash before this point leaves the pending pull in place,
@@ -207,7 +209,12 @@ class HostSnapshot:
     def poll(self) -> Optional[Any]:
         """Main thread: the latest snapshot unpacked on the host, or None."""
         packed, self._slot[0] = self._slot[0], None
-        return None if packed is None else self._unpack(packed)
+        if packed is None:
+            return None
+        with profiler.span("player.adopt"):
+            # waited for here, where it is named, and not inside the player's
+            # next forward, which needs these weights either way
+            return jax.block_until_ready(self._unpack(packed))
 
 
 class TrainerThread:
@@ -267,6 +274,11 @@ class TrainerThread:
         with self._lock:
             return self._state["metrics"]
 
+    @property
+    def queue_depth(self) -> int:
+        """Jobs submitted and not yet taken by the worker."""
+        return self._q.qsize()
+
     def check(self) -> None:
         """One supervision pass (restart due workers, escalate): raises the
         typed supervision error once the ladder is exhausted."""
@@ -278,14 +290,16 @@ class TrainerThread:
     def submit(self, job: Any) -> None:
         """Enqueue a burst job; back-pressure keeps driving supervision so a
         dead/degraded trainer escalates instead of deadlocking the env loop
-        against a full queue nobody drains."""
-        while True:
-            self.check()
-            try:
-                self._q.put(job, timeout=0.2)
-                return
-            except _queue.Full:
-                continue
+        against a full queue nobody drains. The ``burst.submit`` span is the
+        time the caller spends blocked here."""
+        with profiler.span("burst.submit"):
+            while True:
+                self.check()
+                try:
+                    self._q.put(job, timeout=0.2)
+                    return
+                except _queue.Full:
+                    continue
 
     def _worker(self, ctx) -> None:
         from sheeprl_tpu.fault.inject import fault_point
@@ -335,6 +349,42 @@ class TrainerThread:
         return carry
 
 
+class _BucketPrograms:
+    """``burst_fn`` lowered and compiled explicitly, once per flush bucket —
+    the work its ``jax.jit`` does implicitly at a bucket's first call, no
+    second trace or compile — so that the executable every burst runs is in
+    hand: it is registered with the recorder (``profiler.register_program``),
+    whose ``scope_table`` joins a device trace's instruction names to the
+    program's region names. Called like ``burst_fn``. A callable with no
+    ``lower`` (a test's fake) is called as it is."""
+
+    def __init__(self, burst_fn: Callable) -> None:
+        self._fn = burst_fn
+        self._name = getattr(burst_fn, "__name__", type(burst_fn).__name__)
+        self._compiled: Dict[str, Any] = {}
+        self._lock = threading.Lock()
+
+    def program_name(self, feed: Tuple[Any, ...]) -> str:
+        """``<function>/<leading size of the feed>``: the blob's bytes on the
+        packed path, the staged rows on the unpacked one. One name per flush
+        bucket (``make_blob_layouts`` keeps blob lengths unique)."""
+        return f"{self._name}/{int(np.shape(jax.tree.leaves(feed)[0])[0])}"
+
+    def __call__(self, carry: Any, rb: Any, *feed: Any) -> Any:
+        if not hasattr(self._fn, "lower"):
+            return self._fn(carry, rb, *feed)
+        name = self.program_name(feed)
+        compiled = self._compiled.get(name)
+        if compiled is None:
+            with self._lock:
+                compiled = self._compiled.get(name)
+                if compiled is None:
+                    compiled = self._fn.lower(carry, rb, *feed).compile()
+                    self._compiled[name] = compiled
+                    profiler.register_program(name, compiled)
+        return compiled(carry, rb, *feed)
+
+
 class BurstRunner:
     """Staging + dispatch for a device-ring burst step.
 
@@ -363,7 +413,7 @@ class BurstRunner:
         blob_layouts: Optional[Dict[int, "BlobLayout"]] = None,
         supervisor_cfg: Optional[Dict[str, Any]] = None,
     ) -> None:
-        self._burst_fn = burst_fn
+        self._burst_fn = _BucketPrograms(burst_fn)
         self._layouts = blob_layouts
         self._params_of = params_of
         self._ring_keys = ring_keys
@@ -384,6 +434,7 @@ class BurstRunner:
         self.dev_valid = np.zeros(self._n_envs, np.int64)
         self._staged: list = []  # (data dict, env mask) per ring row
         self._bursts = 0  # trained bursts; worker-thread-only state
+        self._flushes = 0  # burst sequence number; main-thread-only state
         self._thread = TrainerThread(self._step, (carry, rb_dev), supervisor_cfg=supervisor_cfg)
         if snapshot is not None:
             # the refresh pulls ride the trainer's supervisor: a dead pull is
@@ -445,13 +496,17 @@ class BurstRunner:
         self._thread.raise_if_failed()
 
     def _step(self, carry_rb, job):
+        """One job of :meth:`flush`: ``(*feed, (burst, flush span id, bucket),
+        trained)``. The ``burst.dispatch`` span is how long dispatch holds
+        the trainer thread: first the compile, later the runtime's own
+        back-pressure once enough bursts are in flight."""
         carry, rb = carry_rb
-        if self._layouts is not None:
-            blob, trained = job
-            carry, rb, metrics = self._burst_fn(carry, rb, blob)
-        else:
-            staged_j, mask_j, pos_j, valid_j, key_j, validmask_j, trained = job
-            carry, rb, metrics = self._burst_fn(carry, rb, staged_j, mask_j, pos_j, valid_j, key_j, validmask_j)
+        *feed, (burst, flush_span, bucket), trained = job
+        with profiler.span(
+            "burst.dispatch", parent=flush_span, burst=burst, bucket=bucket,
+            program=self._burst_fn.program_name(feed),
+        ):
+            carry, rb, metrics = self._burst_fn(carry, rb, *feed)
         if trained:
             self._bursts += 1
             if self._snapshot is not None and self._bursts % self._snapshot_every == 0:
@@ -468,6 +523,21 @@ class BurstRunner:
         """Package the staged rows + up to ``grad_chunk`` grants into one
         burst job. Returns the number of grants consumed (0 while any env is
         still shorter than a sample window)."""
+        self._flushes += 1
+        with profiler.span("burst.flush", burst=self._flushes) as flush_span:
+            with profiler.span("burst.pack"):
+                job, chunk, env_counts, counters = self._pack(key, grant_backlog, (self._flushes, flush_span.id))
+            # `blob_bytes`: host-to-device bytes of this burst, bucket padding
+            # included; `queue_depth`: jobs the trainer thread has not yet taken
+            flush_span.set(chunk=chunk, queue_depth=self._thread.queue_depth, **counters)
+            self._thread.submit(job)
+            self.dev_pos[:] = (self.dev_pos + env_counts) % self._capacity
+            self.dev_valid[:] = np.minimum(self.dev_valid + env_counts, self._capacity)
+        return chunk
+
+    def _pack(self, key, grant_backlog: int, burst: Tuple[int, int]):
+        """The numpy packing of one flush: ``(job, chunk, rows written per
+        env, counters of the flush span)``."""
         n_rows = len(self._staged)
         size = next(b for b in self._stage_buckets if b >= n_rows)
         arrs = {}
@@ -487,6 +557,7 @@ class BurstRunner:
         chunk = min(self.grad_chunk, grant_backlog) if ready else 0
         validmask = np.zeros((self.grad_chunk,), np.float32)
         validmask[:chunk] = 1.0
+        meta = (*burst, size)
         if self._layouts is not None:
             # One uint8 blob = one host→device transfer per flush instead
             # of eight, each with its own per-transfer latency on the
@@ -501,16 +572,16 @@ class BurstRunner:
             # Fresh blob per flush: the queued job must not alias a buffer a
             # later flush would overwrite while this one is still in flight.
             blob = pack_burst_blob(layout, values)
-            self._thread.submit((blob, chunk > 0))
+            job = (blob, meta, chunk > 0)
+            blob_bytes = blob.nbytes
         else:
-            self._thread.submit((
+            job = (
                 arrs, jnp.asarray(mask), jnp.asarray(self.dev_pos, jnp.int32),
                 jnp.asarray(self.dev_valid, jnp.int32), key, jnp.asarray(validmask),
-                chunk > 0,
-            ))
-        self.dev_pos[:] = (self.dev_pos + env_counts) % self._capacity
-        self.dev_valid[:] = np.minimum(self.dev_valid + env_counts, self._capacity)
-        return chunk
+                meta, chunk > 0,
+            )
+            blob_bytes = sum(a.nbytes for a in arrs.values()) + mask.nbytes + validmask.nbytes
+        return job, chunk, env_counts, {"rows": n_rows, "bucket": size, "blob_bytes": blob_bytes}
 
     def close(self) -> Any:
         """Stop the trainer thread and return the final carry."""

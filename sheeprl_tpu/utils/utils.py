@@ -8,6 +8,7 @@ pieces: step-accounting (:class:`Ratio`), schedules, config printing/saving.
 from __future__ import annotations
 
 import os
+import re
 import warnings
 from typing import Any, Dict, Mapping, Optional, Sequence
 
@@ -148,6 +149,16 @@ def enable_compile_cache() -> None:
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", _REPO_CACHE_DIR)
+    # An executable's ``op_name`` metadata is read here (the scope table of
+    # ``utils.profiler``, an operator's trace): one compiled before a
+    # ``jax.named_scope`` moved must not be served for the program after it,
+    # which the default key, blind to metadata, would do.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    # ... with source paths relative to the checkout, so that two checkouts of
+    # one tree still share what they compile
+    jax.config.update(
+        "jax_hlo_source_file_canonicalization_regex", re.escape(os.path.dirname(_REPO_CACHE_DIR) + os.sep)
+    )
     compile_stats.register()
 
 

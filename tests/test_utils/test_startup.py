@@ -20,17 +20,30 @@ def config_updates(monkeypatch):
     return calls
 
 
+def _key_rules():
+    """The cache key covers op_name metadata (utils.profiler's scope tables read it
+    from cached executables), with source paths relative to the checkout."""
+    import re
+
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(sheeprl_tpu.__file__)))
+    return [
+        ("jax_compilation_cache_include_metadata_in_key", True),
+        ("jax_hlo_source_file_canonicalization_regex", re.escape(checkout + os.sep)),
+    ]
+
+
 def test_cache_dir_from_outside_is_left_alone(monkeypatch, config_updates):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
     utils.enable_compile_cache()
-    assert config_updates == []  # JAX read the variable itself; nothing is set in code
+    # JAX read the variable itself; no directory is set in code (only the key's rules)
+    assert config_updates == _key_rules()
 
 
 def test_cache_dir_defaults_to_the_checkout(monkeypatch, config_updates):
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     utils.enable_compile_cache()
     checkout = os.path.dirname(os.path.dirname(os.path.abspath(sheeprl_tpu.__file__)))
-    assert config_updates == [("jax_compilation_cache_dir", os.path.join(checkout, ".xla_cache"))]
+    assert config_updates == [("jax_compilation_cache_dir", os.path.join(checkout, ".xla_cache")), *_key_rules()]
 
 
 def test_accelerator_tpu_without_a_tpu_raises_by_name():
