@@ -378,7 +378,7 @@ def check_kernels(devices: int) -> dict:
         tier = K.tier(name)
         check(tier != "pallas-interpret", f"kernel {name} resolves to interpret mode on this machine")
         if name in K.AUTO_LAX_ON_TPU:
-            check(tier == "lax" and K.dispatch(name) is K.get(name).reference,
+            check(tier == "lax" and K.dispatch(name).__wrapped__ is K.get(name).reference,  # under its scope
                   f"kernel {name} is listed in AUTO_LAX_ON_TPU but resolves to {tier}")
             for args, label in cases[name]:  # what the call site gets still has to run here
                 out = jax.jit(K.dispatch(name))(*args)

@@ -38,6 +38,7 @@ from sheeprl_tpu.utils.utils import player_reset_fn as _player_reset_fn
 from sheeprl_tpu.utils.utils import player_zeros as _player_zeros
 from sheeprl_tpu.models import MLP, LayerNormGRUCell
 from sheeprl_tpu.models.blocks import _ConvTranspose
+from sheeprl_tpu.models.scan_grads import scan_dense_grads_after
 from sheeprl_tpu.ops import symlog
 
 __all__ = [
@@ -339,47 +340,88 @@ class RSSM:
         logits = _unimix(logits, self.discrete, self.unimix)
         return logits, sample_stochastic(logits, self.discrete, key, sample=sample_state)
 
-    def dynamic(
-        self, wmp, posterior, recurrent_state, action, embedded_obs, is_first, key
-    ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-        """One dynamic-learning step (reference: ``agent.py:396-436``).
-        All tensors are batch-shaped ``(B, ...)``; ``posterior`` flat."""
-        k_post = key
+    def _recurrent_step(self, wmp, posterior, recurrent_state, action, is_first, initial_states):
+        """What both dynamic steps share: reset the rows that start an episode
+        to the initial states, advance the recurrent state, read the prior.
+        ``initial_states`` is ``get_initial_states``'s pair; a scan evaluates
+        it once before the loop and passes it in (it does not depend on the
+        step), a lone call may leave it ``None``."""
         # keep every mixed term in the carried state's dtype: under bf16
         # policies the float32 is_first mask / initial-state param would
         # otherwise promote the scan carry and break its type invariant
         dtype = recurrent_state.dtype
         is_first = is_first.astype(dtype)
         action = (1 - is_first) * action.astype(dtype)
-        init_rec, init_post = self.get_initial_states(wmp, recurrent_state.shape[:-1])
+        if initial_states is None:
+            initial_states = self.get_initial_states(wmp, recurrent_state.shape[:-1])
+        init_rec, init_post = initial_states
         recurrent_state = (1 - is_first) * recurrent_state + is_first * init_rec.astype(dtype)
         posterior = (1 - is_first) * posterior + is_first * init_post.astype(posterior.dtype)
         recurrent_state = self.recurrent_model.apply(
             wmp["recurrent_model"], jnp.concatenate([posterior, action], axis=-1), recurrent_state
         )
         prior_logits = self.transition_model.apply(wmp["transition_model"], recurrent_state)
-        prior_logits = _unimix(prior_logits, self.discrete, self.unimix)
-        posterior_logits, posterior = self._representation(wmp, recurrent_state, embedded_obs, k_post)
+        return recurrent_state, _unimix(prior_logits, self.discrete, self.unimix)
+
+    def dynamic(
+        self, wmp, posterior, recurrent_state, action, embedded_obs, is_first, key, initial_states=None
+    ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+        """One dynamic-learning step (reference: ``agent.py:396-436``).
+        All tensors are batch-shaped ``(B, ...)``; ``posterior`` flat."""
+        recurrent_state, prior_logits = self._recurrent_step(
+            wmp, posterior, recurrent_state, action, is_first, initial_states
+        )
+        posterior_logits, posterior = self._representation(wmp, recurrent_state, embedded_obs, key)
         return recurrent_state, posterior, posterior_logits, prior_logits
 
     def dynamic_decoupled(
-        self, wmp, posterior, recurrent_state, action, is_first
+        self, wmp, posterior, recurrent_state, action, is_first, initial_states=None
     ) -> Tuple[jax.Array, jax.Array]:
         """Decoupled dynamic step: the posterior is precomputed from the
         observations alone; only the recurrent state and the prior advance
         (reference DecoupledRSSM.dynamic, ``agent.py:542-581``)."""
-        dtype = recurrent_state.dtype
-        is_first = is_first.astype(dtype)
-        action = (1 - is_first) * action.astype(dtype)
-        init_rec, init_post = self.get_initial_states(wmp, recurrent_state.shape[:-1])
-        recurrent_state = (1 - is_first) * recurrent_state + is_first * init_rec.astype(dtype)
-        posterior = (1 - is_first) * posterior + is_first * init_post.astype(posterior.dtype)
-        recurrent_state = self.recurrent_model.apply(
-            wmp["recurrent_model"], jnp.concatenate([posterior, action], axis=-1), recurrent_state
-        )
-        prior_logits = self.transition_model.apply(wmp["transition_model"], recurrent_state)
-        prior_logits = _unimix(prior_logits, self.discrete, self.unimix)
-        return recurrent_state, prior_logits
+        return self._recurrent_step(wmp, posterior, recurrent_state, action, is_first, initial_states)
+
+    def dynamic_rollout(self, wmp, embedded, actions, is_first, key):
+        """The ``T``-step dynamic-learning rollout over ``(T, B, ...)`` inputs
+        as one scan: recurrent states, posteriors, posterior and prior logits.
+        The initial states are evaluated once, and the weight gradients of the
+        step's ``Dense`` layers are formed after the backward loop
+        (:func:`sheeprl_tpu.models.scan_grads.scan_dense_grads_after`)."""
+        T, B = actions.shape[:2]
+        dtype = embedded.dtype
+        rec0 = jnp.zeros((B, self.recurrent_model.recurrent_state_size), dtype=dtype)
+        # what the step reads: only the models it applies, and the initial states as values
+        inside = ("recurrent_model", "transition_model") + (() if self.decoupled else ("representation_model",))
+        params = {"wmp": {k: wmp[k] for k in inside}, "initial": self.get_initial_states(wmp, (B,))}
+
+        if self.decoupled:
+            # posteriors come from the observations alone, computed in one
+            # vectorized pass (reference: dreamer_v3.py:116-131)
+            k_repr, key = jax.random.split(key)
+            post_logits, posts = self._representation(wmp, None, embedded, k_repr)
+            posts_prev = jnp.concatenate([jnp.zeros_like(posts[:1]), posts[:-1]], axis=0)
+
+            def step_dec(p, rec, xs):
+                post_prev, act_t, first_t = xs
+                rec, prior_logits = self.dynamic_decoupled(p["wmp"], post_prev, rec, act_t, first_t, p["initial"])
+                return rec, (rec, prior_logits)
+
+            _, (recs, prior_logits) = scan_dense_grads_after(step_dec, params, rec0, (posts_prev, actions, is_first))
+            return recs, posts, post_logits, prior_logits
+
+        post0 = jnp.zeros((B, self.transition_model.stoch_state_size), dtype=dtype)
+
+        def step(p, carry, xs):
+            rec, post = carry
+            emb_t, act_t, first_t, k = xs
+            rec, post, post_logits, prior_logits = self.dynamic(
+                p["wmp"], post, rec, act_t, emb_t, first_t, k, p["initial"]
+            )
+            return (rec, post), (rec, post, post_logits, prior_logits)
+
+        xs = (embedded, actions, is_first, jax.random.split(key, T))
+        return scan_dense_grads_after(step, params, (rec0, post0), xs)[1]
 
     def imagination(self, wmp, prior, recurrent_state, actions, key) -> Tuple[jax.Array, jax.Array]:
         """One latent imagination step (reference: ``agent.py:482-500``)."""

@@ -117,40 +117,6 @@ def make_train_step(
     moments_cfg = cfg.algo.actor.moments
     split_sizes = np.cumsum(np.asarray(actions_dim[:-1], dtype=np.int64)).tolist()
 
-    def dynamic_rollout(wmp, embedded, actions, is_first, key):
-        """T-step representation rollout as one scan (SEQUENTIAL HOT LOOP)."""
-        T, B = actions.shape[:2]
-        rec0 = jnp.zeros((B, recurrent_state_size), dtype=embedded.dtype)
-
-        if rssm.decoupled:
-            # posteriors come from the observations alone, computed in one
-            # vectorized pass (reference: dreamer_v3.py:116-131)
-            k_repr, key = jax.random.split(key)
-            post_logits, posts = rssm._representation(wmp, None, embedded, k_repr)
-            posts_prev = jnp.concatenate([jnp.zeros_like(posts[:1]), posts[:-1]], axis=0)
-
-            def step_dec(rec, xs):
-                post_prev, act_t, first_t = xs
-                rec, prior_logits = rssm.dynamic_decoupled(wmp, post_prev, rec, act_t, first_t)
-                return rec, (rec, prior_logits)
-
-            _, (recs, prior_logits) = jax.lax.scan(step_dec, rec0, (posts_prev, actions, is_first))
-            return recs, posts, post_logits, prior_logits
-
-        post0 = jnp.zeros((B, stoch_state_size), dtype=embedded.dtype)
-
-        def step(carry, xs):
-            rec, post = carry
-            emb_t, act_t, first_t, k = xs
-            rec, post, post_logits, prior_logits = rssm.dynamic(wmp, post, rec, act_t, emb_t, first_t, k)
-            return (rec, post), (rec, post, post_logits, prior_logits)
-
-        keys = jax.random.split(key, T)
-        _, (recs, posts, post_logits, prior_logits) = jax.lax.scan(
-            step, (rec0, post0), (embedded, actions, is_first, keys)
-        )
-        return recs, posts, post_logits, prior_logits
-
     def gradient_step(carry, xs):
         params, opts, moments_state, cum = carry
         # snapshot BEFORE the target-critic EMA below so a guarded skip
@@ -187,7 +153,7 @@ def make_train_step(
             with jax.named_scope("wm.encoder"):
                 embedded = world_model.encoder.apply(wmp["encoder"], batch_obs)
             with jax.named_scope("wm.dynamics"):
-                recs, posts, post_logits, prior_logits = dynamic_rollout(
+                recs, posts, post_logits, prior_logits = rssm.dynamic_rollout(
                     wmp, embedded, batch_actions, is_first, k_dyn
                 )
                 latents = jnp.concatenate([posts, recs], axis=-1)

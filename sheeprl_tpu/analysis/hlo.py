@@ -35,6 +35,7 @@ __all__ = [
     "large_constants",
     "find_dtype",
     "op_scopes",
+    "while_carried_shapes",
 ]
 
 #: HLO short dtype -> bytes per element (unknown dtypes default to 4 at the
@@ -272,3 +273,22 @@ def op_scopes(
             backward = "transpose(" in op_name
         out[m.group(1)] = {"scope": scope, "outer": outer, "backward": backward}
     return out
+
+
+_WHILE_RE = re.compile(r"=\s*(\(.*\))\s+while\(")
+
+
+def while_carried_shapes(compiled_hlo_text: str) -> List[List[Tuple[str, Tuple[int, ...]]]]:
+    """For every ``while`` of an optimized executable, the ``(dtype, dims)`` of
+    each array its loop carries (the instruction's result tuple, in order).
+    A loop-invariant array rides the carry too, so a weight a scan applies
+    appears once; a second array of its shape is an accumulator (the
+    transposed scan's gradient of a closed-over weight)."""
+    loops: List[List[Tuple[str, Tuple[int, ...]]]] = []
+    for line in compiled_hlo_text.splitlines():
+        m = _WHILE_RE.search(line)
+        if m:
+            loops.append(
+                [(t, tuple(int(d) for d in dims.split(",") if d)) for t, dims in _TUPLE_ELEM_RE.findall(m.group(1))]
+            )
+    return loops

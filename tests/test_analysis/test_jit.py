@@ -1008,8 +1008,9 @@ def test_repo_tree_corpus_is_nontrivial():
 
 def test_injected_bug_is_caught_in_real_tree():
     """End-to-end: a key reuse planted inside a real nested traced function
-    (dreamer_v3's rollout) is found — the corpus reaches it through the
-    factory nesting, not just top-level decorated functions."""
+    (dreamer_v3's gradient step, nested in ``make_train_step``) is found — the
+    corpus reaches it through the factory nesting, not just top-level
+    decorated functions."""
     import os
 
     from sheeprl_tpu.analysis.lint import iter_python_files
@@ -1020,12 +1021,12 @@ def test_injected_bug_is_caught_in_real_tree():
             sources.append((fh.read(), os.path.relpath(path, REPO_ROOT)))
     idx = next(i for i, (_, p) in enumerate(sources) if p.endswith("dreamer_v3/dreamer_v3.py"))
     text, p = sources[idx]
-    target = "k_repr, key = jax.random.split(key)"
+    target = "k_dyn, k_img = jax.random.split(key)"
     assert target in text
     sources[idx] = (
         text.replace(
             target,
-            target + "\n            _a = jax.random.normal(k_repr, (2,)); _b = jax.random.normal(k_repr, (2,))",
+            target + "\n        _a = jax.random.normal(k_dyn, (2,)); _b = jax.random.normal(k_dyn, (2,))",
             1,
         ),
         p,
