@@ -80,8 +80,9 @@ def _cases() -> Dict[str, List[Tuple[str, Any]]]:
         )
 
     def scatter(capacity, envs, feat, slots):
-        storage = jnp.zeros((capacity, envs, feat), jnp.float32)
-        staged = jax.random.normal(key, (slots, envs, feat), jnp.float32)
+        # a feat-wide vector key as the ring stores it: (capacity, envs) + data.ring.ring_cell((feat,))
+        storage = jnp.zeros((capacity, envs, 1, feat), jnp.float32)
+        staged = jax.random.normal(key, (slots, envs, 1, feat), jnp.float32)
         pos = jnp.arange(envs, dtype=jnp.int32) % capacity
         row = (pos[None, :] + jnp.arange(slots, dtype=jnp.int32)[:, None]) % capacity
         return lambda backend: (

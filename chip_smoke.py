@@ -338,7 +338,8 @@ def check_kernels(devices: int) -> dict:
         args = (normal(shape), normal(shape), dones, normal(shape[1:]), 0.99, 0.95)
         cases["gae"].append((args, f"{shape}"))
     # ragged_ring_scatter: the flagship ring's keys at capacity 100000 x 1 env and the first flush
-    # bucket (19 rows); a 4-env pixel ring; the audit's (64, 8, 32)
+    # bucket (19 rows); a 4-env pixel ring; the audit's (64, 8, 32); each as the ring stores it,
+    # (capacity, envs) + data.ring.ring_cell(feat)
     def pixels(shape, salt):
         # hashed iota, made on the chip in one fused pass: the flagship ring is 1.2 GB and a random
         # draw of that size needs several times as much in 32-bit temporaries
@@ -348,17 +349,18 @@ def check_kernels(devices: int) -> dict:
         return make()
 
     def ring_case(capacity, envs, feat, dtype, slots):
-        from sheeprl_tpu.data.ring import ring_append_rows
+        from sheeprl_tpu.data.ring import ring_append_rows, ring_cell
 
+        cell = ring_cell(feat)
         if dtype == jnp.uint8:
-            storage, staged = pixels((capacity, envs) + feat, 1), pixels((slots, envs) + feat, 2)
+            storage, staged = pixels((capacity, envs) + cell, 1), pixels((slots, envs) + cell, 2)
         else:
-            storage, staged = normal((capacity, envs) + feat), normal((slots, envs) + feat)
+            storage, staged = normal((capacity, envs) + cell), normal((slots, envs) + cell)
         pos = jnp.asarray(rng.integers(0, capacity, size=(envs,)), jnp.int32)
         pos = pos.at[0].set(capacity - 3)  # a wrapping head
         mask = jnp.asarray(rng.uniform(size=(slots, envs)) < 0.8, jnp.int32)  # ragged: some slots dropped
         row, _, _ = ring_append_rows(pos, jnp.full((envs,), capacity // 2, jnp.int32), mask, capacity)
-        return ((storage, staged, row, pos), f"{(capacity, envs) + feat} {jnp.dtype(dtype).name} <- {slots} rows")
+        return ((storage, staged, row, pos), f"{(capacity, envs) + cell} {jnp.dtype(dtype).name} <- {slots} rows")
 
     for capacity, envs, feat, dtype, slots in (
         (100000, 1, (64, 64, 3), jnp.uint8, 19), (100000, 1, (18,), jnp.float32, 19),

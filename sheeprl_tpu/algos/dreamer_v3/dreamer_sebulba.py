@@ -363,6 +363,7 @@ def main(fabric, cfg: Dict[str, Any]):
             "grad_chunk": grad_max,
             "seq_len": seq_len,
             "batch_size": batch_size,
+            "ring_keys": ring_keys,
             "decoupled": True,
         },
         guard=guard,
@@ -804,7 +805,7 @@ from sheeprl_tpu.analysis.programs import AuditMesh, AuditProgram, register_audi
 def _audit_programs(spec: AuditMesh):
     from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import audit_dreamer_setup
     from sheeprl_tpu.algos.ppo.ppo import _abstract_like
-    from sheeprl_tpu.data.ring import build_seq_append_step
+    from sheeprl_tpu.data.ring import build_seq_append_step, ring_cell
 
     s = audit_dreamer_setup(spec)
     local_envs, num_actors = s["n_envs"], 2
@@ -813,7 +814,7 @@ def _audit_programs(spec: AuditMesh):
     rep = s["rep"]
     state_abs = {
         "storage": {
-            k: jax.ShapeDtypeStruct((s["capacity"], ring_envs) + shape, dtype, sharding=rep)
+            k: jax.ShapeDtypeStruct((s["capacity"], ring_envs) + ring_cell(shape), dtype, sharding=rep)
             for k, (shape, dtype) in s["ring_keys"].items()
         },
         "pos": jax.ShapeDtypeStruct((ring_envs,), jnp.int32, sharding=rep),
@@ -828,7 +829,8 @@ def _audit_programs(spec: AuditMesh):
         s["txs"],
         ring={
             "capacity": s["capacity"], "n_envs": ring_envs, "grad_chunk": s["grad_chunk"],
-            "seq_len": s["seq_len"], "batch_size": s["batch"], "decoupled": True,
+            "seq_len": s["seq_len"], "batch_size": s["batch"], "ring_keys": s["ring_keys"],
+            "decoupled": True,
         },
     )
     ctl_blob = jax.ShapeDtypeStruct((ctl_layout.nbytes,), jnp.uint8, sharding=rep)

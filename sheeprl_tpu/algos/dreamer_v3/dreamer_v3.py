@@ -1097,9 +1097,12 @@ from sheeprl_tpu.analysis.programs import AuditMesh, AuditProgram, register_audi
 
 @register_audit_programs("dreamer_v3.burst_step")
 def _audit_programs(spec: AuditMesh):
-    from sheeprl_tpu.data.ring import effective_stage_buckets, make_blob_layouts
+    from sheeprl_tpu.data.ring import effective_stage_buckets, make_blob_layouts, ring_cell
 
-    s = audit_dreamer_setup(spec)
+    # capacity 128: the ring (3.2 MB) outweighs the XS step's temporaries, so one
+    # more copy of it (a lost donation, a relayout) passes the `peak_hbm_bytes`
+    # budget's tolerance and fails AUD005
+    s = audit_dreamer_setup(spec, capacity=128)
     buckets = effective_stage_buckets((1, 2), 2)  # the SequenceRingDriver flush set
     ring_spec = {
         "capacity": s["capacity"],
@@ -1119,8 +1122,8 @@ def _audit_programs(spec: AuditMesh):
     )
     layouts = make_blob_layouts(s["ring_keys"], s["n_envs"], s["grad_chunk"], buckets)
     blob = jax.ShapeDtypeStruct((layouts[max(buckets)].nbytes,), jnp.uint8, sharding=s["rep"])
-    rb = {
-        k: jax.ShapeDtypeStruct((s["capacity"], s["n_envs"]) + shape, dtype, sharding=s["rep"])
+    rb = {  # the ring as stored (utils/burst.py:init_device_ring)
+        k: jax.ShapeDtypeStruct((s["capacity"], s["n_envs"]) + ring_cell(shape), dtype, sharding=s["rep"])
         for k, (shape, dtype) in s["ring_keys"].items()
     }
     yield AuditProgram(

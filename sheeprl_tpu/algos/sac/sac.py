@@ -891,11 +891,14 @@ def main(fabric, cfg: Dict[str, Any]):
             capacity=buffer_size, n_envs=int(cfg.env.num_envs), stage_max=stage_max, grad_chunk=grad_chunk,
             dims=dims,
         )
-        from sheeprl_tpu.utils.burst import init_device_ring
-
-        rb_dev, _, _ = init_device_ring(
-            fabric, {k: ((d,), jnp.float32) for k, d in dims.items()}, buffer_size, int(cfg.env.num_envs)
-        )
+        # The flat transition ring, zeroed ON the device (a host zeros +
+        # device_put would build it in host memory first). Its own `.at[].set`
+        # append and gather address `(capacity, n_envs, d)` rows: it does not
+        # share the sequence ring's stored view (`data/ring.py:ring_cell`).
+        rb_dev = jax.jit(
+            lambda: {k: jnp.zeros((buffer_size, int(cfg.env.num_envs), d), jnp.float32) for k, d in dims.items()},
+            out_shardings={k: fabric.replicated for k in dims},
+        )()
         dev_pos, dev_total = 0, 0
         if state is not None and cfg.buffer.checkpoint and not rb.empty:
             # Mirror the restored host buffer onto the device ring.

@@ -36,6 +36,7 @@ __all__ = [
     "find_dtype",
     "op_scopes",
     "while_carried_shapes",
+    "whole_array_relayouts",
 ]
 
 #: HLO short dtype -> bytes per element (unknown dtypes default to 4 at the
@@ -292,3 +293,37 @@ def while_carried_shapes(compiled_hlo_text: str) -> List[List[Tuple[str, Tuple[i
                 [(t, tuple(int(d) for d in dims.split(",") if d)) for t, dims in _TUPLE_ELEM_RE.findall(m.group(1))]
             )
     return loops
+
+
+_COMPUTATION_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
+_RELAYOUT_RE = re.compile(r"=\s*([a-z][a-z0-9]*)\[([0-9,]*)\](\{[^}]*\})?\s+(copy|reshape|transpose)\(")
+
+
+def whole_array_relayouts(compiled_hlo_text: str, leading_dim: int) -> List[Dict[str, object]]:
+    """Every ``copy``, ``reshape`` or ``transpose`` of an optimized executable
+    whose result's leading dimension is ``leading_dim`` — with the replay
+    ring's capacity, the instructions that rewrite a whole ring key (a layout
+    change XLA answers a reshape or a custom call's operand layout with).
+    ``[{"name", "op", "dtype", "dims", "layout", "bytes", "computation"}]`` in
+    program order; ``bytes`` is the logical size, tile padding not counted."""
+    found: List[Dict[str, object]] = []
+    computation = None
+    for line in compiled_hlo_text.splitlines():
+        head = _COMPUTATION_RE.match(line)
+        if head:
+            computation = head.group(2)
+            continue
+        m = _RELAYOUT_RE.search(line)
+        name = _INSTRUCTION_RE.match(line)
+        if not (m and name):
+            continue
+        dtype, dims, layout, op = m.groups()
+        if dims.split(",", 1)[0] != str(leading_dim):
+            continue
+        found.append(
+            {
+                "name": name.group(1), "op": op, "dtype": dtype, "dims": tuple(int(d) for d in dims.split(",")),
+                "layout": layout or "", "bytes": shape_bytes(dtype, dims), "computation": computation,
+            }
+        )
+    return found

@@ -25,6 +25,9 @@ from jax import shard_map
 from sheeprl_tpu.ops.kernels import ragged_ring_scatter
 
 __all__ = [
+    "ring_cell",
+    "ring_view",
+    "env_view",
     "ring_append_rows",
     "ring_sample_windows",
     "ring_sample_windows_episode",
@@ -40,6 +43,36 @@ __all__ = [
     "pack_burst_blob",
     "unpack_burst_blob",
 ]
+
+
+def ring_cell(shape) -> Tuple[int, int]:
+    """The two trailing dims one ring row of an env-shaped key is STORED
+    under: ``(feat // 128, 128)`` where the row's element count is a multiple
+    of 128 (pixels: 64x64x3 -> 96x128), ``(1, feat)`` otherwise (vectors,
+    scalars). Every ring key lives on the device as ``(capacity, n_envs) +
+    ring_cell(shape)``: the rows the ragged scatter's blocks and the
+    sampler's gather address, so neither rewrites the ring. (Mosaic wants a
+    block's last two dims divisible by (8, 128) or equal to the array's; a
+    ``(1, 1, feat)`` block of ``(C, E, feat)`` is neither once ``E > 1``. Kept
+    env-shaped, a ``u8[C, E, 64, 64, 3]`` ring gets a device layout with ``C``
+    minor-most and XLA rewrites all of it around every 17-row write.) This is
+    the one place that knows the convention; the host side (staged rows, blob
+    segments, host buffers, checkpoints read back through
+    :func:`env_view`) keeps the env's shapes."""
+    feat = int(np.prod(shape, dtype=np.int64))
+    return (feat // 128, 128) if feat % 128 == 0 else (1, feat)
+
+
+def ring_view(x, shape):
+    """``(...lead) + shape -> (...lead) + ring_cell(shape)``: env-shaped rows
+    into the stored view (numpy or jax; a view of contiguous bytes)."""
+    return x.reshape(x.shape[: x.ndim - len(shape)] + ring_cell(shape))
+
+
+def env_view(x, shape):
+    """``(...lead) + ring_cell(shape) -> (...lead) + shape``: stored rows back
+    into the env's shape (the sampled batch, a checkpoint's host copy)."""
+    return x.reshape(x.shape[:-2] + tuple(shape))
 
 
 def ring_append_rows(pos, valid_n, staged_mask, capacity: int):
@@ -88,9 +121,10 @@ def episode_window_table(pos, valid_n, is_first, capacity: int, seq_len: int):
 
     Everything here depends only on the ring state after the burst's single
     append, so callers compute it ONCE per burst and draw per-step starts
-    with :func:`sample_window_starts` at O(batch) cost.
+    with :func:`sample_window_starts` at O(batch) cost. ``is_first`` is the
+    ring key as stored (:func:`ring_cell`).
     """
-    F = (is_first.reshape(capacity, -1) > 0).astype(jnp.int32)  # (C, E)
+    F = (env_view(is_first, (1,))[..., 0] > 0).astype(jnp.int32)  # (C, E)
     # interior[p, e] = any is_first in rows p+1 .. p+seq_len-1 (circular):
     # windowed sum via a doubled cumsum.
     G = jnp.concatenate([F, F[: seq_len]], axis=0)
@@ -224,12 +258,17 @@ def pack_burst_blob(layout: BlobLayout, values: Dict[str, np.ndarray]) -> np.nda
     return blob
 
 
-def unpack_burst_blob(blob: jax.Array, layout: BlobLayout) -> Dict[str, jax.Array]:
-    """Device side (traced): slice + bitcast each segment back out."""
+def unpack_burst_blob(blob: jax.Array, layout: BlobLayout, ring_keys=None) -> Dict[str, jax.Array]:
+    """Device side (traced): slice + bitcast each segment back out. A segment
+    named in ``ring_keys`` holds staged ring rows and comes out in the ring's
+    stored view, ``(S, E) + ring_cell(shape)`` (a segment is flat bytes, so
+    either view costs the same)."""
+    cells = {k: ring_cell(shape) for k, (shape, _dtype) in dict(ring_keys or ()).items()}
     out = {}
     for name, off, shape, dtype in layout.segments:
         itemsize = np.dtype(dtype).itemsize
         n = int(np.prod(shape))
+        shape = shape[:2] + cells.get(name, shape[2:])
         seg = jax.lax.slice_in_dim(blob, off, off + n * itemsize, axis=0)
         if itemsize == 1:
             arr = seg.reshape(shape)
@@ -244,6 +283,7 @@ def unpack_burst_blob(blob: jax.Array, layout: BlobLayout) -> Dict[str, jax.Arra
 def _granted_step(
     gradient_step: Callable[[Any, Any], Any],
     storage: Dict[str, Any],
+    ring_keys: Dict[str, Tuple[tuple, Any]],
     sample_starts: Callable[[Any, Any], Any],
     batch_per_dev: int,
     ring_envs: int,
@@ -257,7 +297,9 @@ def _granted_step(
     branch (``lax.cond`` executes one branch; operands computed outside it
     would still run unconditionally) — and the zero metrics are derived from
     the true branch's structure, so the two cond branches can never drift
-    apart."""
+    apart. ``storage`` is the ring as stored; only the gathered ``(T, B)``
+    windows are reshaped to the env's shapes (``ring_keys``), so
+    ``gradient_step`` sees the batch a host-sampled path would give it."""
 
     def sampled_step(c, xs):
         k, valid_flag = xs
@@ -267,7 +309,9 @@ def _granted_step(
                 k_env, k_start, k_grad = jax.random.split(k, 3)
                 env_idx = jax.random.randint(k_env, (batch_per_dev,), 0, ring_envs)
                 t_idx = sample_starts(k_start, env_idx)  # (T, B)
-                batch = {kk: storage[kk][t_idx, env_idx[None, :]] for kk in storage}
+                batch = {
+                    kk: env_view(storage[kk][t_idx, env_idx[None, :]], ring_keys[kk][0]) for kk in storage
+                }
             nc, m = gradient_step(c, (batch, k_grad))
             # Metrics may be a tuple (Dreamers) or a dict (P2E) — keep the
             # structure, normalize the dtype for the masked mean.
@@ -294,14 +338,15 @@ def build_burst_train_step(
     cumulative-step counter and V3 the Moments state). The returned jitted
     function has signature::
 
-        burst_fn(carry, rb, staged, staged_mask, pos, valid_n, key, valid)
-            -> (carry, rb, metrics)
+        burst_fn(carry, rb, blob) -> (carry, rb, metrics)
 
-    with ``rb`` the device ring dict (donated), ``staged`` the
-    ``(S, E, ...)`` host rows, ``staged_mask`` ``(S, E)`` env write masks,
-    ``pos``/``valid_n`` the per-env heads, and ``valid`` a
-    ``(grad_chunk,)`` 0/1 mask of granted steps (padding steps skip all
-    work via ``lax.cond``).
+    with ``rb`` the device ring dict as stored (``(capacity, n_envs) +
+    ring_cell(shape)`` per key; donated) and ``blob`` ONE uint8 upload per
+    flush (:func:`make_blob_layouts`: the ``(S, E, ...)`` host rows, the
+    ``(S, E)`` env write masks, the per-env heads, the train key and a
+    ``(grad_chunk,)`` 0/1 mask of granted steps — padding steps skip all
+    work via ``lax.cond``). Each bucket's blob length selects its layout, so
+    every flush bucket is its own trace.
     """
     capacity = int(ring["capacity"])
     ring_envs = int(ring["n_envs"])
@@ -309,6 +354,7 @@ def build_burst_train_step(
     ring_seq = int(ring["seq_len"])
     ring_batch = int(ring["batch_size"])
     episode_rule = bool(ring.get("episode_rule", False))  # Dreamer-V2 buffer.type=episode
+    ring_keys = ring["ring_keys"]
     n_dev = mesh.devices.size
 
     def local_burst(carry, rb, staged, staged_mask, pos, valid_n, key, valid):
@@ -339,7 +385,7 @@ def build_burst_train_step(
                 k, env_idx, new_pos, new_valid, capacity, ring_seq
             )
         sampled_step = _granted_step(
-            gradient_step, rb, sample_starts, ring_batch // n_dev, ring_envs
+            gradient_step, rb, ring_keys, sample_starts, ring_batch // n_dev, ring_envs
         )
 
         key = jax.random.fold_in(key, jax.lax.axis_index("dp"))
@@ -358,62 +404,48 @@ def build_burst_train_step(
         check_vma=False,
     )
 
-    ring_keys = ring.get("ring_keys")
-    if ring_keys is not None:
-        # Packed single-upload variant: the host ships ONE uint8 blob per
-        # flush (see make_blob_layouts); each bucket's blob length selects
-        # its layout, so every bucket gets its own trace exactly as the
-        # unpacked path did.
-        raw_buckets = tuple(int(b) for b in ring["stage_buckets"])
-        layouts = make_blob_layouts(
-            ring_keys,
-            ring_envs,
-            grad_chunk,
-            # Same normalization BurstRunner applies to its flush buckets, so
-            # every bucket the runner can select has a layout here.
-            effective_stage_buckets(raw_buckets, int(ring.get("stage_max", max(raw_buckets)))),
+    raw_buckets = tuple(int(b) for b in ring["stage_buckets"])
+    layouts = make_blob_layouts(
+        ring_keys,
+        ring_envs,
+        grad_chunk,
+        # Same normalization BurstRunner applies to its flush buckets, so
+        # every bucket the runner can select has a layout here.
+        effective_stage_buckets(raw_buckets, int(ring.get("stage_max", max(raw_buckets)))),
+    )
+    by_length = {layout.nbytes: layout for layout in layouts.values()}
+
+    def packed_burst(carry, rb, blob):
+        layout = by_length[blob.shape[0]]
+        with jax.named_scope("ring.append"):
+            u = unpack_burst_blob(blob, layout, ring_keys)
+        return shard_burst(
+            carry,
+            rb,
+            {k: u[k] for k in ring_keys},
+            u["__mask__"],
+            u["__pos__"],
+            u["__valid_n__"],
+            u["__key__"],
+            u["__validmask__"],
         )
-        by_length = {layout.nbytes: layout for layout in layouts.values()}
-
-        def packed_burst(carry, rb, blob):
-            layout = by_length[blob.shape[0]]
-            with jax.named_scope("ring.append"):
-                u = unpack_burst_blob(blob, layout)
-            return shard_burst(
-                carry,
-                rb,
-                {k: u[k] for k in ring_keys},
-                u["__mask__"],
-                u["__pos__"],
-                u["__valid_n__"],
-                u["__key__"],
-                u["__validmask__"],
-            )
-
-        # Pin the fed-back outputs' placements (carry and ring are both fed
-        # back every burst): left to inference, jit may canonicalize them to
-        # an equivalent placement with a different C++ jit-cache key and
-        # silently recompile on the next dispatch (the PR 8 class; checked by
-        # graft-audit AUD002 on `dreamer_v3.burst_step`).
-        from jax.sharding import NamedSharding
-
-        rep = NamedSharding(mesh, P())
-        fn = jax.jit(
-            packed_burst,
-            donate_argnums=(1,),
-            out_shardings=(rep, rep, rep),
-            compiler_options=compiler_options,
-        )
-        return fn
 
     # Only the ring is donated: the carry handles (params/opts/...) are read
     # by the main thread (checkpoints) while a burst may be in flight —
-    # donation would hand it deleted buffers.
+    # donation would hand it deleted buffers. The fed-back outputs'
+    # placements are pinned (carry and ring are both fed back every burst):
+    # left to inference, jit may canonicalize them to an equivalent placement
+    # with a different C++ jit-cache key and silently recompile on the next
+    # dispatch (the PR 8 class; checked by graft-audit AUD002 on
+    # `dreamer_v3.burst_step`).
     from jax.sharding import NamedSharding
 
     rep = NamedSharding(mesh, P())
     return jax.jit(
-        shard_burst, donate_argnums=(1,), out_shardings=(rep, rep, rep), compiler_options=compiler_options
+        packed_burst,
+        donate_argnums=(1,),
+        out_shardings=(rep, rep, rep),
+        compiler_options=compiler_options,
     )
 
 
@@ -460,8 +492,9 @@ def build_seq_append_step(
 ):
     """The donated ragged multi-head scatter: ``fn(state, blob) -> state``.
 
-    ``state`` is the async sequence-ring pytree (``storage`` dict + per-env
-    ``pos``/``valid`` heads + the device train-key) and ``blob`` one actor's
+    ``state`` is the async sequence-ring pytree (``storage`` dict as stored,
+    :func:`ring_cell`, + per-env ``pos``/``valid`` heads + the device
+    train-key) and ``blob`` one actor's
     :func:`make_seq_append_layout` upload, already staged on the mesh. Each
     env column in the actor's slice advances its OWN write head by its masked
     row count (``ring_append_rows`` — reset rows advance only the done envs),
@@ -491,7 +524,7 @@ def build_seq_append_step(
     )
 
     def packed_append(state, blob):
-        u = unpack_burst_blob(blob, layout)
+        u = unpack_burst_blob(blob, layout, ring_keys)
         storage, pos, valid = shard_append(
             state["storage"], state["pos"], state["valid"],
             {k: u[k] for k in ring_keys}, u["__mask__"], u["__offset__"],
@@ -538,6 +571,7 @@ def build_seq_train_step(
     grad_chunk = int(ring["grad_chunk"])
     ring_seq = int(ring["seq_len"])
     ring_batch = int(ring["batch_size"])
+    ring_keys = ring["ring_keys"]
     n_dev = mesh.devices.size
     ctl_layout = make_seq_ctl_layout(grad_chunk)
 
@@ -553,7 +587,7 @@ def build_seq_train_step(
             k, env_idx, pos, valid_n, capacity, ring_seq
         )
         sampled_step = _granted_step(
-            gradient_step, storage, sample_starts, ring_batch // n_dev, ring_envs
+            gradient_step, storage, ring_keys, sample_starts, ring_batch // n_dev, ring_envs
         )
         carry, metrics = jax.lax.scan(sampled_step, carry, (keys, validmask))
         denom = jnp.maximum(validmask.sum(), 1.0)

@@ -60,12 +60,13 @@ def _audit_programs(spec: AuditMesh):
             jax.jit(k["sumtree_sample"]),
             (aval((8192,)), aval((256,)), aval((), jnp.int32), aval(())),
         ),
-        # Sebulba burst append: (capacity, envs, feat) ring, 4-slot burst.
+        # Sebulba burst append: a 32-wide vector key as the ring stores it,
+        # (capacity, envs) + data.ring.ring_cell((32,)); 4-slot burst.
         "ragged_ring_scatter": (
             jax.jit(k["ragged_ring_scatter"]),
             (
-                aval((64, 8, 32)),
-                aval((4, 8, 32)),
+                aval((64, 8, 1, 32)),
+                aval((4, 8, 1, 32)),
                 aval((4, 8), jnp.int32),
                 aval((8,), jnp.int32),
             ),

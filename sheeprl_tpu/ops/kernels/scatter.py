@@ -1,7 +1,7 @@
 """Ragged multi-head ring scatter (``data.ring``'s per-env-head append).
 
 The device ring commits one staged blob per dispatch: slot ``(s, e)`` of a
-``(S, e, ...)`` staged block lands at ``storage[row[s, e], col_offset + e]``,
+``(S, e) + cell`` staged block lands at ``storage[row[s, e], col_offset + e]``,
 where ``row`` carries the per-env ragged pack from
 :func:`sheeprl_tpu.data.ring.ring_append_rows` and dropped/padded slots are
 marked ``row == capacity``. The lax path is a fancy-indexed
@@ -10,13 +10,29 @@ re-threads the (donated) ring through a scatter op per storage key. The
 Pallas kernel instead streams only the ``S*e`` touched rows: scalar-prefetched
 row/col indices drive the output ``BlockSpec`` directly (the classic
 prefetch-scatter pattern), the ring aliases in-place via
-``input_output_aliases``, and untouched rows are never read or written by
-the kernel itself. XLA is another matter: the custom call wants the ring
-row-major, the device-default layout of a ``u8[C, E, 64, 64, 3]`` ring puts
-``C`` minor-most, and the standalone program compiled for "TPU v5 lite"
-brackets the kernel (and, with two copies, the lax scatter too) in
-whole-ring layout copies — 1.15 GiB of temporaries for a 1.15 GiB ring at
-``C=25000, E=4`` (``memory_analysis`` of the chipless AOT compile).
+``input_output_aliases``, and untouched rows are never read or written.
+
+Both tiers take and return the ring AS STORED: ``(C, E) + cell`` with
+``cell = data.ring.ring_cell(shape)``, i.e. ``(feat // 128, 128)`` or
+``(1, feat)`` — one ring cell per block (Mosaic wants a block's last two dims
+divisible by (8, 128) or equal to the array's). Nothing here reshapes
+storage, staged rows or the result. It did until PR 28: the ring was kept
+env-shaped (``u8[C, E, 64, 64, 3]``, whose device layout puts ``C``
+minor-most), the kernel built the cell view with ``reshape`` on every call,
+neither reshape was a bitcast under the TPU's tiled layouts, and XLA
+bracketed the kernel (34 us per three bursts on the 1.15 GiB pixel ring of
+the DreamerV3 cells) in seven whole-ring rewrites, 8.6 GB a burst
+(PERF.md, finding 28: `kernel_ragged_ring_scatter_ms` 2.11 -> 0.0009 and
+`train_step_ms` 22.70 -> 19.96 at S, on the chip). A caller that reshapes a
+whole ring key around this call brings them back;
+``tests/test_ops/test_kernels.py`` (the chipless TPU compile of a pixel
+ring's append, both ways), ``tests/test_utils/test_burst.py`` (no such
+equation in the traced burst program) and the ``dreamer_v3.burst_step`` audit
+budget guard against it. A key with a narrow cell (``f32[C, E, 1, 18]``) still
+arrives with ``C`` minor-most and is copied into the call's row-major layout
+and back, 0.4–7 MB a key: pinning the row-major layout on the program's ring
+argument instead pads those keys to 128 lanes at rest (+196 MB) and leaves
+the gather's copies, so it was left alone.
 
 Dropped slots cannot skip their grid step, so they are parked on the row
 *before* the env's write head (``(pos[e] - 1) % capacity``) and write back
@@ -27,8 +43,8 @@ dispatch (a full-capacity wrap with a dropped slot is impossible —
 order or pipelining.
 
 Preconditions (both call sites satisfy them): ``staged.dtype ==
-storage.dtype``, ``capacity == storage.shape[0]``, and every
-``col_offset + e`` in bounds.
+storage.dtype``, ``capacity == storage.shape[0]``, ``staged.shape[2:] ==
+storage.shape[2:]`` (two dims), and every ``col_offset + e`` in bounds.
 
 Gradients: ``jax.custom_vjp`` — Pallas forward, scatter/gather VJP of the
 lax reference on the backward (float dtypes only; the ring's uint8 image
@@ -37,7 +53,6 @@ keys are never differentiated).
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -75,9 +90,9 @@ def _scatter_pallas_forward(storage, staged, row, pos, col_offset, *, interpret)
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    capacity, env_cols = storage.shape[0], storage.shape[1]
+    capacity, env_cols = storage.shape[:2]
+    cell = storage.shape[2:]  # data.ring.ring_cell: one ring row, two dims
     slots, e = row.shape
-    feat = int(np.prod(storage.shape[2:])) if storage.ndim > 2 else 1
 
     mask = (row < capacity).astype(jnp.int32)
     # Park dropped slots on the row before this env's write head: never
@@ -86,13 +101,8 @@ def _scatter_pallas_forward(storage, staged, row, pos, col_offset, *, interpret)
     safe_row = jnp.where(mask > 0, row, (pos[None, :] - 1) % capacity).astype(jnp.int32)
     cols = (col_offset + jnp.broadcast_to(jnp.arange(e), row.shape)).astype(jnp.int32)
 
-    # One ring cell per block, on a (C, E, feat/128, 128) view (or
-    # (C, E, 1, feat) for narrow rows): Mosaic wants the last two block dims
-    # divisible by (8, 128) or equal to the array's, and a (1, 1, feat) block
-    # of a (C, E, feat) array is neither once E > 1.
-    cell = (feat // 128, 128) if feat % 128 == 0 else (1, feat)
     block = pl.BlockSpec((1, 1) + cell, lambda i, rows, cols, mask: (rows[i], cols[i], 0, 0))
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _scatter_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -103,18 +113,11 @@ def _scatter_pallas_forward(storage, staged, row, pos, col_offset, *, interpret)
             ],
             out_specs=block,
         ),
-        out_shape=jax.ShapeDtypeStruct((capacity, env_cols) + cell, storage.dtype),
+        out_shape=jax.ShapeDtypeStruct(storage.shape, storage.dtype),
         input_output_aliases={4: 0},  # storage updates in place
         interpret=interpret,
         name="ragged_ring_scatter",
-    )(
-        safe_row.reshape(slots * e),
-        cols.reshape(slots * e),
-        mask.reshape(slots * e),
-        staged.reshape((slots, e) + cell),
-        storage.reshape((capacity, env_cols) + cell),
-    )
-    return out.reshape(storage.shape)
+    )(safe_row.reshape(slots * e), cols.reshape(slots * e), mask.reshape(slots * e), staged, storage)
 
 
 @jax.custom_vjp
@@ -170,6 +173,7 @@ def ragged_ring_scatter(
     col_offset=0,
     backend: Optional[str] = None,
 ) -> jax.Array:
-    """Registry-dispatched ragged ring append: ``(C, E, ...) x (S, e, ...)
-    x (S, e) rows -> (C, E, ...)`` (``row == capacity`` slots are dropped)."""
+    """Registry-dispatched ragged ring append on the stored view: ``(C, E) +
+    cell x (S, e) + cell x (S, e) rows -> (C, E) + cell`` (``row == capacity``
+    slots are dropped)."""
     return registry.dispatch("ragged_ring_scatter", backend)(storage, staged, row, pos, col_offset)

@@ -26,8 +26,10 @@ import numpy as np
 
 from sheeprl_tpu.data.ring import (
     build_seq_append_step,
+    env_view,
     make_blob_layouts,
     pack_burst_blob,
+    ring_view,
 )
 from sheeprl_tpu.replay.device_buffer import DeviceReplayState
 from sheeprl_tpu.utils.burst import init_device_ring
@@ -37,6 +39,23 @@ __all__ = ["AsyncSequenceRing", "SeqBlobWriter", "SequenceRingDriver"]
 
 # One env step stages at most one all-envs row plus one ragged reset row.
 _STAGE_MAX = 2
+
+
+def _storage_to_host(storage, ring_keys) -> Dict[str, np.ndarray]:
+    """The ring's keys as a checkpoint holds them: on the host, in the env's
+    shapes (``(capacity, n_envs) + shape``, the format from before the ring
+    was stored in its cell view, so snapshots of either age load and
+    ``restore_host_env_buffer`` reads them as they are)."""
+    return {f"storage/{k}": env_view(v, ring_keys[k][0]) for k, v in jax.device_get(storage).items()}
+
+
+def _storage_from_host(fabric, snap: DeviceReplayState, ring_keys) -> Dict[str, Any]:
+    """A snapshot's keys back onto the device, reshaped on the host to the
+    stored view (``data.ring.ring_cell``)."""
+    return {
+        k: fabric.put_replicated(ring_view(np.asarray(snap.arrays[f"storage/{k}"]), shape))
+        for k, (shape, _dtype) in ring_keys.items()
+    }
 
 
 class SequenceRingDriver:
@@ -212,7 +231,7 @@ class SequenceRingDriver:
     def state_dict(self) -> DeviceReplayState:
         if self._staged:
             raise RuntimeError("checkpointing with staged-but-unflushed rows would drop them")
-        arrays = {f"storage/{k}": np.asarray(v) for k, v in jax.device_get(self.rb_dev).items()}
+        arrays = _storage_to_host(self.rb_dev, self.ring_keys)
         arrays["pos"] = self.dev_pos.copy()
         arrays["valid"] = self.dev_valid.copy()
         arrays["key"] = np.asarray(self._key)
@@ -227,9 +246,7 @@ class SequenceRingDriver:
                 f"replay snapshot shape mismatch: checkpoint ({snap.meta['capacity']}, "
                 f"{snap.meta['n_envs']}) vs configured ({self.capacity}, {self.n_envs})"
             )
-        self.rb_dev = {
-            k: self.fabric.put_replicated(snap.arrays[f"storage/{k}"]) for k in self.ring_keys
-        }
+        self.rb_dev = _storage_from_host(self.fabric, snap, self.ring_keys)
         self.dev_pos = np.asarray(snap.arrays["pos"], np.int64).copy()
         self.dev_valid = np.asarray(snap.arrays["valid"], np.int64).copy()
         self._key = jax.device_put(snap.arrays["key"], self._host_device)
@@ -375,7 +392,7 @@ class AsyncSequenceRing:
     # -- checkpoint ----------------------------------------------------------
     def state_dict(self) -> DeviceReplayState:
         host = jax.device_get(self.state)
-        arrays = {f"storage/{k}": np.asarray(v) for k, v in host["storage"].items()}
+        arrays = _storage_to_host(host["storage"], self.ring_keys)
         arrays["pos"] = np.asarray(host["pos"])
         arrays["valid"] = np.asarray(host["valid"])
         arrays["key"] = np.asarray(host["key"])
@@ -392,9 +409,7 @@ class AsyncSequenceRing:
             )
         rep = self.fabric.replicated
         self.state = {
-            "storage": {
-                k: self.fabric.put_replicated(snap.arrays[f"storage/{k}"]) for k in self.ring_keys
-            },
+            "storage": _storage_from_host(self.fabric, snap, self.ring_keys),
             "pos": jax.device_put(jax.numpy.asarray(snap.arrays["pos"], jax.numpy.int32), rep),
             "valid": jax.device_put(jax.numpy.asarray(snap.arrays["valid"], jax.numpy.int32), rep),
             "key": jax.device_put(jax.numpy.asarray(snap.arrays["key"]), rep),

@@ -26,7 +26,14 @@ import numpy as np
 from jax.flatten_util import ravel_pytree
 
 from sheeprl_tpu.analysis.lockstats import sync_lock
-from sheeprl_tpu.data.ring import BlobLayout, effective_stage_buckets, make_blob_layouts, pack_burst_blob
+from sheeprl_tpu.data.ring import (
+    BlobLayout,
+    effective_stage_buckets,
+    make_blob_layouts,
+    pack_burst_blob,
+    ring_cell,
+    ring_view,
+)
 from sheeprl_tpu.utils import profiler
 from sheeprl_tpu.utils.utils import host_cpu_device
 
@@ -78,10 +85,12 @@ def dreamer_ring_keys(observation_space, cnn_keys, mlp_keys, actions_dim, with_i
 
 
 def init_device_ring(fabric, ring_keys, capacity: int, n_envs: int, rb=None):
-    """Allocate the device ring, optionally mirroring restored per-env host
-    buffers (checkpoint resume). The mirror assembles each key host-side and
-    ships it in ONE transfer — per-env ``.at[:, e].set`` updates would copy
-    the full ring once per env per key. Returns ``(rb_dev, pos, valid)``."""
+    """Allocate the device ring in its stored view, ``(capacity, n_envs) +
+    ring_cell(shape)`` per key (``data/ring.py``), optionally mirroring
+    restored per-env host buffers (checkpoint resume). The mirror assembles
+    each key host-side, env-shaped, reshapes it there and ships it in ONE
+    transfer — per-env ``.at[:, e].set`` updates would copy the full ring
+    once per env per key. Returns ``(rb_dev, pos, valid)``."""
     dev_pos = np.zeros(n_envs, np.int64)
     dev_valid = np.zeros(n_envs, np.int64)
     rb_dev = {}
@@ -91,7 +100,7 @@ def init_device_ring(fabric, ring_keys, capacity: int, n_envs: int, rb=None):
         # first and copy all of it host→device.
         alloc = jax.jit(
             lambda: {
-                k: jnp.zeros((capacity, n_envs) + shape, dtype)
+                k: jnp.zeros((capacity, n_envs) + ring_cell(shape), dtype)
                 for k, (shape, dtype) in ring_keys.items()
             },
             out_shardings={k: fabric.replicated for k in ring_keys},
@@ -102,7 +111,7 @@ def init_device_ring(fabric, ring_keys, capacity: int, n_envs: int, rb=None):
             host = np.zeros((capacity, n_envs) + shape, np.dtype(dtype))
             for e, sub in enumerate(rb.buffer):
                 host[:, e] = np.asarray(sub.buffer[k][:, 0], dtype=host.dtype)
-            rb_dev[k] = fabric.put_replicated(jnp.asarray(host))
+            rb_dev[k] = fabric.put_replicated(jnp.asarray(ring_view(host, shape)))
         for e, sub in enumerate(rb.buffer):
             dev_pos[e] = sub._pos
             dev_valid[e] = capacity if sub.full else sub._pos
@@ -388,8 +397,11 @@ class _BucketPrograms:
 class BurstRunner:
     """Staging + dispatch for a device-ring burst step.
 
-    ``burst_fn(carry, rb, staged, staged_mask, pos, valid_n, key, valid)``
-    is the jitted function from :func:`data.ring.build_burst_train_step`;
+    ``burst_fn(carry, rb, blob)`` is the jitted function from
+    :func:`data.ring.build_burst_train_step` (``rb`` the ring as
+    :func:`init_device_ring` stores it, ``blob`` one packed flush; without
+    ``blob_layouts`` the feed is the unpacked ``staged, staged_mask, pos,
+    valid_n, key, valid``, which only a test's fake ``burst_fn`` takes);
     ``carry`` holds the training handles (params/opts/...) and is readable
     at any time via :attr:`carry` (at most one burst stale — checkpoints
     accept that the same way the reference's decoupled SAC does).

@@ -80,8 +80,9 @@ def test_windows_stay_in_valid_prefix_when_not_full():
 
 
 def _episode_ring(first_rows, cap, n_envs=1):
-    """A (cap, n_envs, 1) is_first channel with 1s at the given rows."""
-    f = np.zeros((cap, n_envs, 1), np.float32)
+    """An is_first channel as the ring stores it, (cap, n_envs, 1, 1), with
+    1s at the given rows."""
+    f = np.zeros((cap, n_envs, 1, 1), np.float32)
     for r in first_rows:
         f[r, :] = 1.0
     return jnp.asarray(f)

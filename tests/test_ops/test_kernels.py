@@ -47,16 +47,21 @@ def _tree(rng, leaves=64, filled=40):
     return st.update(tree, jnp.arange(filled), pri)
 
 
-def _ring_case(rng, C=8, E=5, S=4, e=3, feat=(2,), dtype=np.float32):
-    from sheeprl_tpu.data.ring import ring_append_rows
+def _ring_case(rng, C=8, E=5, S=4, e=3, feat=(2,), dtype=np.float32, stored=True):
+    """A ragged append: heads that wrap, slots that are dropped. ``stored``:
+    ring and staged rows in the ring's stored view (``data.ring.ring_cell``),
+    as the kernel takes them; else in the env's shape ``feat``."""
+    from sheeprl_tpu.data.ring import ring_append_rows, ring_view
 
-    heads = [1, C - 1, 3][:e]  # includes a wrapping head
+    heads = [1, C - 1, 3, 0][:e]  # includes a wrapping head
     pos = jnp.asarray(heads, jnp.int32)
     valid = jnp.asarray(heads, jnp.int32)
-    mask = jnp.asarray([[1, 1, 1], [1, 0, 1], [0, 0, 1], [1, 0, 0]], jnp.int32)[:S, :e]
+    mask = jnp.asarray([[1, 1, 1, 0], [1, 0, 1, 1], [0, 0, 1, 0], [1, 0, 0, 1]], jnp.int32)[:S, :e]
     row, _, _ = ring_append_rows(pos, valid, mask, C)
     storage = jnp.asarray((rng.normal(size=(C, E) + feat) * 50).astype(dtype))
     staged = jnp.asarray((rng.normal(size=(S, e) + feat) * 50).astype(dtype))
+    if stored:
+        storage, staged = ring_view(storage, feat), ring_view(staged, feat)
     return storage, staged, row, pos
 
 
@@ -278,20 +283,33 @@ def test_sumtree_sample_grad_parity():
 @pytest.mark.parametrize(
     "dtype", [np.float32, jnp.bfloat16, np.uint8], ids=["f32", "bf16", "u8"]
 )
-@pytest.mark.parametrize("feat", [(2,), ()], ids=["feature", "scalar"])
-def test_ragged_ring_scatter_parity(dtype, feat):
-    rng = _rng(11)
-    storage, staged, row, pos = _ring_case(rng, feat=feat)
-    if dtype == np.uint8:
-        storage = (jnp.abs(storage) * 20).astype(jnp.uint8)
-        staged = (jnp.abs(staged) * 20).astype(jnp.uint8)
-    else:
-        storage, staged = storage.astype(dtype), staged.astype(dtype)
-    off = jnp.asarray(1, jnp.int32)
-    got = K.ragged_ring_scatter(storage, staged, row, pos, off, backend="pallas")
-    want = K.ragged_ring_scatter(storage, staged, row, pos, off, backend="lax")
+@pytest.mark.parametrize(
+    "feat,E,e,off",
+    [((2,), 5, 3, 1), ((), 5, 3, 1), ((8, 16, 3), 5, 3, 1), ((18,), 4, 2, 2), ((4, 32), 4, 4, 0)],
+    ids=["feature", "scalar", "pixel", "sebulba-vector", "one-lane-row"],
+)
+def test_ragged_ring_scatter_parity(dtype, feat, E, e, off):
+    """Both tiers on the ring's stored view against the literal env-shaped
+    scatter: a ``(1, feat)`` cell (vectors, scalars), a ``(feat // 128, 128)``
+    cell (pixels), heads that wrap, dropped slots, and a Sebulba append of 2
+    env columns at offset 2 of 4."""
+    from sheeprl_tpu.data.ring import env_view
+
+    def cast(x):
+        return (jnp.abs(x) * 20).astype(jnp.uint8) if dtype == np.uint8 else x.astype(dtype)
+
+    storage, staged, row, pos = _ring_case(_rng(11), E=E, e=e, feat=feat)
+    storage, staged = cast(storage), cast(staged)
+    env_storage, env_staged, _, _ = _ring_case(_rng(11), E=E, e=e, feat=feat, stored=False)
+    off = jnp.asarray(off, jnp.int32)
+    cols = off + jnp.broadcast_to(jnp.arange(e)[None, :], row.shape)
+    want = cast(env_storage).at[row, cols].set(cast(env_staged), mode="drop")
+    assert storage.ndim == 4 and storage.shape[:2] == want.shape[:2]
     # a scatter copies values: parity is exact for every dtype
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for backend in ("pallas", "lax"):
+        got = K.ragged_ring_scatter(storage, staged, row, pos, off, backend=backend)
+        assert got.shape == storage.shape
+        np.testing.assert_array_equal(np.asarray(env_view(got, feat)), np.asarray(want), err_msg=backend)
 
 
 def test_ragged_ring_scatter_all_dropped_column():
@@ -305,8 +323,8 @@ def test_ragged_ring_scatter_all_dropped_column():
     valid = jnp.asarray([0, 4], jnp.int32)
     mask = jnp.asarray([[0, 1], [0, 1], [0, 0]], jnp.int32)
     row, _, _ = ring_append_rows(pos, valid, mask, C)
-    storage = jnp.asarray(rng.normal(size=(C, e, 3)).astype(np.float32))
-    staged = jnp.asarray(rng.normal(size=(S, e, 3)).astype(np.float32))
+    storage = jnp.asarray(rng.normal(size=(C, e, 1, 3)).astype(np.float32))
+    staged = jnp.asarray(rng.normal(size=(S, e, 1, 3)).astype(np.float32))
     got = K.ragged_ring_scatter(storage, staged, row, pos, 0, backend="pallas")
     want = K.ragged_ring_scatter(storage, staged, row, pos, 0, backend="lax")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -464,6 +482,35 @@ def test_every_pallas_kernel_compiles_for_tpu(pretend_tpu, tpu_topology):
             continue
         compiled = _compile_for_tpu(tpu_topology, fn, arrays)
         assert "tpu_custom_call" in compiled.as_text(), name
+
+
+@pytest.mark.parametrize("stored", [True, False], ids=["stored-view", "env-shaped-ring"])
+def test_pixel_ring_append_for_tpu_rewrites_no_whole_ring(pretend_tpu, tpu_topology, stored):
+    """What the CPU cannot see: under the TPU's tiled layouts the donated
+    append of a pixel ring kept in its stored view compiles to the Mosaic call
+    alone, and the same ring kept env-shaped (``u8[C, 1, 64, 64, 3]``,
+    reshaped around the call, as until PR 28) is copied whole, several times."""
+    from sheeprl_tpu.analysis.hlo import whole_array_relayouts
+    from sheeprl_tpu.data.ring import env_view, ring_cell, ring_view
+
+    C, S, shape = 4096, 19, (64, 64, 3)
+    sharding = jax.sharding.SingleDeviceSharding(tpu_topology.devices[0])
+
+    def aval(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    def append(ring, staged, row, pos):
+        if stored:
+            return K.get("ragged_ring_scatter").pallas(ring, staged, row, pos)
+        out = K.get("ragged_ring_scatter").pallas(ring_view(ring, shape), ring_view(staged, shape), row, pos)
+        return env_view(out, shape)
+
+    tail = ring_cell(shape) if stored else shape
+    avals = (aval((C, 1) + tail, jnp.uint8), aval((S, 1) + tail, jnp.uint8), aval((S, 1), jnp.int32), aval((1,), jnp.int32))
+    text = jax.jit(append, donate_argnums=(0,)).trace(*avals).lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in text
+    rewrites = whole_array_relayouts(text, C)
+    assert (rewrites == []) if stored else (len(rewrites) >= 2), rewrites
 
 
 @pytest.mark.parametrize(

@@ -21,7 +21,7 @@ def _burst_text():
     ``make_train_step(ring=...)`` the trainers dispatch)."""
     from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import audit_dreamer_setup, make_train_step
     from sheeprl_tpu.analysis.programs import AuditMesh
-    from sheeprl_tpu.data.ring import effective_stage_buckets, make_blob_layouts
+    from sheeprl_tpu.data.ring import effective_stage_buckets, make_blob_layouts, ring_cell
 
     s = audit_dreamer_setup(AuditMesh(devices=1))
     buckets = effective_stage_buckets((2,), 2)
@@ -35,7 +35,7 @@ def _burst_text():
     layouts = make_blob_layouts(s["ring_keys"], s["n_envs"], s["grad_chunk"], buckets)
     blob = jax.ShapeDtypeStruct((layouts[max(buckets)].nbytes,), jnp.uint8, sharding=s["rep"])
     rb = {
-        k: jax.ShapeDtypeStruct((s["capacity"], s["n_envs"]) + shape, dtype, sharding=s["rep"])
+        k: jax.ShapeDtypeStruct((s["capacity"], s["n_envs"]) + ring_cell(shape), dtype, sharding=s["rep"])
         for k, (shape, dtype) in s["ring_keys"].items()
     }
     return burst_fn.lower(s["carry"], rb, blob).compile().as_text()

@@ -208,13 +208,21 @@ def test_restore_host_buffer_memmap_backing(fabric1, tmp_path):
     assert rb._pos == 1
 
 
-def test_restore_host_env_buffer_sequence_crossover(fabric1):
+@pytest.mark.parametrize("shape,dtype", [((3,), np.float32), ((8, 16, 3), np.uint8)], ids=["vector", "pixel"])
+@pytest.mark.parametrize("via", ["snapshot", "ring"])
+def test_restore_host_env_buffer_sequence_crossover(fabric1, via, shape, dtype):
     """A Dreamer resident (sequence-ring) checkpoint resumed onto the host
-    tier fills the per-env buffers with per-env heads intact."""
+    tier fills the per-env buffers with per-env heads intact. ``snapshot``: a
+    checkpoint as written before the ring was stored in its cell view (the
+    env's shapes); ``ring``: the same loaded into a device ring and written
+    out again — host -> ring -> host -> ring, bit for bit."""
     from sheeprl_tpu.data.buffers import EnvIndependentReplayBuffer, SequentialReplayBuffer
-    from sheeprl_tpu.replay import restore_host_env_buffer
+    from sheeprl_tpu.data.ring import env_view, ring_cell
+    from sheeprl_tpu.replay import AsyncSequenceRing, restore_host_env_buffer
+    from sheeprl_tpu.utils.burst import init_device_ring
 
-    storage = np.arange(CAP * N_ENVS * 3, dtype=np.float32).reshape(CAP, N_ENVS, 3)
+    n = CAP * N_ENVS * int(np.prod(shape))
+    storage = (np.arange(n) % 251).astype(dtype).reshape((CAP, N_ENVS) + shape)
     snap = DeviceReplayState(
         "sequence",
         {
@@ -225,6 +233,13 @@ def test_restore_host_env_buffer_sequence_crossover(fabric1):
         },
         {"capacity": CAP, "n_envs": N_ENVS, "seq_len": 2},
     )
+    keys = {"observations": (shape, dtype)}
+    if via == "ring":
+        ring = AsyncSequenceRing(fabric1, keys, capacity=CAP, n_envs=N_ENVS, local_envs=N_ENVS, seq_len=2, stage_rows=2)
+        ring.load_state_dict(snap)
+        assert ring.state["storage"]["observations"].shape == (CAP, N_ENVS) + ring_cell(shape)
+        snap = ring.state_dict()
+        assert snap.arrays["storage/observations"].shape == storage.shape  # a checkpoint keeps the env's shapes
     rb = EnvIndependentReplayBuffer(
         CAP, n_envs=N_ENVS, obs_keys=("observations",), buffer_cls=SequentialReplayBuffer
     )
@@ -238,6 +253,10 @@ def test_restore_host_env_buffer_sequence_crossover(fabric1):
     rb.seed(0)
     out = rb.sample(batch_size=4, sequence_length=2)
     assert out["observations"].shape[1] == 2  # (n_samples, T, B, ...)
+    # and the host buffers mirror back onto a device ring unchanged
+    rb_dev, pos, valid = init_device_ring(fabric1, keys, CAP, N_ENVS, rb=rb)
+    np.testing.assert_array_equal(env_view(np.asarray(rb_dev["observations"]), shape), storage)
+    assert pos.tolist() == [3, 0] and valid.tolist() == [3, CAP]
     # wrong-kind snapshots are rejected loudly
     with pytest.raises(ValueError, match="sequence"):
         restore_host_buffer(snap, ReplayBuffer(CAP, N_ENVS))

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sheeprl_tpu.data.ring import build_seq_train_step, pack_burst_blob, make_seq_ctl_layout
+from sheeprl_tpu.data.ring import build_seq_train_step, env_view, pack_burst_blob, make_seq_ctl_layout
 from sheeprl_tpu.parallel.fabric import Fabric
 from sheeprl_tpu.replay import AsyncSequenceRing, estimate_ring_bytes, resolve_device_resident
 
@@ -69,8 +69,8 @@ def _assert_matches(ring, oracle):
     state = jax.device_get(ring.state)
     np.testing.assert_array_equal(np.asarray(state["pos"]), oracle.pos)
     np.testing.assert_array_equal(np.asarray(state["valid"]), oracle.valid)
-    for k in KEYS:
-        np.testing.assert_allclose(np.asarray(state["storage"][k]), oracle.storage[k])
+    for k, (shape, _d) in KEYS.items():  # the ring as stored, read back in the env's shapes
+        np.testing.assert_allclose(env_view(np.asarray(state["storage"][k]), shape), oracle.storage[k])
     np.testing.assert_array_equal(ring.host_pos, oracle.pos)
     np.testing.assert_array_equal(ring.host_valid, oracle.valid)
 
@@ -149,7 +149,7 @@ def test_train_step_key_advances_and_heads_pass_through():
 
     train_fn, ctl_layout = build_seq_train_step(
         gradient_step, fabric.mesh,
-        {"capacity": CAP, "n_envs": RING_ENVS, "grad_chunk": 2, "seq_len": 2, "batch_size": 4},
+        {"capacity": CAP, "n_envs": RING_ENVS, "grad_chunk": 2, "seq_len": 2, "batch_size": 4, "ring_keys": KEYS},
     )
     validmask = np.zeros(2, np.float32)
     validmask[:1] = 1.0
@@ -183,7 +183,7 @@ def test_train_step_holds_until_every_env_has_a_window():
 
     train_fn, ctl_layout = build_seq_train_step(
         gradient_step, fabric.mesh,
-        {"capacity": CAP, "n_envs": RING_ENVS, "grad_chunk": 2, "seq_len": 2, "batch_size": 4},
+        {"capacity": CAP, "n_envs": RING_ENVS, "grad_chunk": 2, "seq_len": 2, "batch_size": 4, "ring_keys": KEYS},
     )
     ctl = fabric.put_replicated(
         pack_burst_blob(ctl_layout, {"__validmask__": np.ones(2, np.float32)})

@@ -13,7 +13,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import sheeprl_tpu.ops.kernels as K
 from sheeprl_tpu.data.buffers import EnvIndependentReplayBuffer, SequentialReplayBuffer
+from sheeprl_tpu.data.ring import (
+    build_burst_train_step,
+    build_seq_append_step,
+    build_seq_train_step,
+    env_view,
+    make_blob_layouts,
+    pack_burst_blob,
+    ring_append_rows,
+    ring_cell,
+    ring_sample_windows,
+    ring_view,
+)
 from sheeprl_tpu.utils.burst import BurstRunner, HostSnapshot, dreamer_ring_keys, init_device_ring
 
 
@@ -44,26 +57,211 @@ class TestHostSnapshot:
 
 
 class TestInitDeviceRing:
-    KEYS = {"obs": ((2,), jnp.float32), "rewards": ((1,), jnp.float32)}
+    KEYS = {"obs": ((2,), jnp.float32), "rewards": ((1,), jnp.float32), "rgb": ((8, 16, 3), jnp.uint8)}
 
     def test_fresh_ring_is_zeroed(self):
         rb_dev, pos, valid = init_device_ring(_FakeFabric(), self.KEYS, capacity=5, n_envs=3)
-        assert rb_dev["obs"].shape == (5, 3, 2)
+        # stored view: (capacity, n_envs) + ring_cell(shape) -- (1, feat), or (feat // 128, 128) for pixels
+        assert rb_dev["obs"].shape == (5, 3, 1, 2) and rb_dev["rewards"].shape == (5, 3, 1, 1)
+        assert rb_dev["rgb"].shape == (5, 3, 3, 128) and rb_dev["rgb"].dtype == jnp.uint8
         assert float(rb_dev["obs"].sum()) == 0.0
         assert pos.tolist() == [0, 0, 0] and valid.tolist() == [0, 0, 0]
 
     def test_mirror_restores_contents_and_heads(self):
-        rb = EnvIndependentReplayBuffer(4, n_envs=2, obs_keys=("obs",), buffer_cls=SequentialReplayBuffer)
+        rb = EnvIndependentReplayBuffer(4, n_envs=2, obs_keys=("obs", "rgb"), buffer_cls=SequentialReplayBuffer)
         data = {
             "obs": np.arange(12, dtype=np.float32).reshape(3, 2, 2),
             "rewards": np.ones((3, 2, 1), np.float32),
+            "rgb": np.random.default_rng(0).integers(0, 255, (3, 2, 8, 16, 3)).astype(np.uint8),
         }
         rb.add(data)
         rb_dev, pos, valid = init_device_ring(_FakeFabric(), self.KEYS, capacity=4, n_envs=2, rb=rb)
-        np.testing.assert_array_equal(np.asarray(rb_dev["obs"])[:3, 0], data["obs"][:, 0])
-        np.testing.assert_array_equal(np.asarray(rb_dev["obs"])[:3, 1], data["obs"][:, 1])
+        for k, (shape, _dtype) in self.KEYS.items():  # reshaped on the host, bit for bit
+            assert rb_dev[k].shape == (4, 2) + ring_cell(shape)
+            np.testing.assert_array_equal(env_view(np.asarray(rb_dev[k]), shape)[:3], data[k])
         assert pos.tolist() == [3, 3]
         assert valid.tolist() == [3, 3]
+
+
+# -- the ring's stored view (data/ring.py:ring_cell) against the env-shaped scatter and gather --
+
+PIXEL = {"rgb": ((8, 16, 3), jnp.uint8), "actions": ((5,), jnp.float32), "is_first": ((1,), jnp.float32)}
+VECTOR = {"state": ((7,), jnp.float32), "rewards": ((1,), jnp.float32)}
+GRAD_CHUNK, SEQ, BATCH = 2, 3, 4
+
+# (ring keys, capacity, per-env heads before the append, per-env valid counts, (S, E) write masks)
+STORED_VIEW_CASES = {
+    "pixel-one-env": (PIXEL, 24, [5], [5], [[1], [1], [1]]),
+    "pixel-wrap-around": (PIXEL, 24, [23], [24], [[1], [1], [1]]),
+    "vector-dropped-slots": (VECTOR, 24, [4, 6, 3], [4, 6, 3], [[1, 0, 1], [0, 0, 1], [1, 0, 0]]),
+    "pixel-four-envs-ragged-wrap": (PIXEL, 24, [22, 3, 23, 9], [24, 3, 24, 9], [[1, 1, 1, 1], [0, 1, 1, 0], [1, 0, 1, 0]]),
+}
+
+
+def _ring_spec(keys, capacity, n_envs, rows=None):
+    spec = {"capacity": capacity, "n_envs": n_envs, "grad_chunk": GRAD_CHUNK, "seq_len": SEQ, "batch_size": BATCH,
+            "ring_keys": keys}
+    return spec if rows is None else {**spec, "stage_buckets": (rows,), "stage_max": rows}
+
+
+def _fill(rng, keys, lead):
+    return {
+        k: (rng.integers(0, 255, lead + shape) if dtype == jnp.uint8 else rng.normal(size=lead + shape)).astype(
+            np.dtype(dtype)
+        )
+        for k, (shape, dtype) in keys.items()
+    }
+
+
+def _collect_step(keys):
+    """A ``gradient_step`` that keeps every granted step's batch in the carry."""
+    carry = (jnp.int32(0), {k: jnp.zeros((GRAD_CHUNK, SEQ, BATCH) + shape, dtype) for k, (shape, dtype) in keys.items()})
+
+    def gradient_step(c, xs):
+        i, seen = c
+        batch, _key = xs
+        return (i + 1, {k: seen[k].at[i].set(batch[k]) for k in seen}), (jnp.zeros(()),)
+
+    return carry, gradient_step
+
+
+def _reference_windows(key, env_ring, pos, valid, capacity, n_envs):
+    """What the granted steps must see: the keys the burst derives, the env-shaped gather."""
+    out = []
+    for k in jax.random.split(jax.random.fold_in(key, 0), GRAD_CHUNK):
+        k_env, k_start, _k_grad = jax.random.split(k, 3)
+        env_idx = jax.random.randint(k_env, (BATCH,), 0, n_envs)
+        t_idx = ring_sample_windows(k_start, env_idx, pos, valid, capacity, SEQ)
+        out.append({name: np.asarray(arr)[np.asarray(t_idx), np.asarray(env_idx)[None, :]] for name, arr in env_ring.items()})
+    return out
+
+
+@pytest.mark.parametrize("backend", ["lax", "pallas"])  # pallas here is the interpreter
+@pytest.mark.parametrize("case", list(STORED_VIEW_CASES))
+def test_append_then_sample_through_stored_view_equals_env_shaped_reference(case, backend):
+    """One burst (append, then two granted steps) on the ring as stored,
+    against the literal env-shaped ``.at[row, col].set`` and fancy-indexed
+    gather, bit for bit: pixel and vector keys, a head that wraps, dropped
+    slots, several envs."""
+    from sheeprl_tpu.parallel.fabric import Fabric
+
+    keys, capacity, pos, valid, mask = STORED_VIEW_CASES[case]
+    pos, valid, mask = np.asarray(pos, np.int32), np.asarray(valid, np.int32), np.asarray(mask, np.int32)
+    n_envs, rows = mask.shape[1], mask.shape[0]
+    rng = np.random.default_rng(3)
+    env_ring, staged = _fill(rng, keys, (capacity, n_envs)), _fill(rng, keys, (rows, n_envs))
+    ring = _ring_spec(keys, capacity, n_envs, rows)
+    carry, gradient_step = _collect_step(keys)
+    key = jax.random.PRNGKey(7)
+    layout = make_blob_layouts(keys, n_envs, GRAD_CHUNK, (rows,))[rows]
+    blob = pack_burst_blob(
+        layout,
+        {**staged, "__mask__": mask, "__pos__": pos, "__valid_n__": valid, "__key__": np.asarray(key, np.uint32),
+         "__validmask__": np.ones(GRAD_CHUNK, np.float32)},
+    )
+    rb = {k: jnp.asarray(ring_view(env_ring[k], shape)) for k, (shape, _d) in keys.items()}
+    with K.use_backend(backend):
+        burst_fn = build_burst_train_step(gradient_step, Fabric(devices=1, accelerator="cpu").mesh, ring)
+        (steps, seen), rb_out, _metrics = burst_fn(carry, rb, jnp.asarray(blob))
+    assert int(steps) == GRAD_CHUNK
+
+    row, new_pos, new_valid = ring_append_rows(jnp.asarray(pos), jnp.asarray(valid), jnp.asarray(mask), capacity)
+    cols = jnp.broadcast_to(jnp.arange(n_envs)[None, :], row.shape)
+    want_ring = {k: np.asarray(jnp.asarray(env_ring[k]).at[row, cols].set(jnp.asarray(staged[k]), mode="drop")) for k in keys}
+    for k, (shape, _d) in keys.items():
+        assert rb_out[k].shape == (capacity, n_envs) + ring_cell(shape)
+        np.testing.assert_array_equal(env_view(np.asarray(rb_out[k]), shape), want_ring[k], err_msg=k)
+    want = _reference_windows(key, want_ring, new_pos, new_valid, capacity, n_envs)
+    for g in range(GRAD_CHUNK):
+        for k, (shape, _d) in keys.items():
+            assert seen[k][g].shape == (SEQ, BATCH) + shape  # gradient_step sees the env's shapes
+            np.testing.assert_array_equal(np.asarray(seen[k][g]), want[g][k], err_msg=f"{k} step {g}")
+
+
+@pytest.mark.parametrize("backend", ["lax", "pallas"])
+def test_sebulba_append_at_a_column_offset_then_sample_equals_reference(backend):
+    """The decoupled pair (``build_seq_append_step`` + ``build_seq_train_step``)
+    on the stored view: one actor's blob lands in env columns 2..3 of 4."""
+    from sheeprl_tpu.parallel.fabric import Fabric
+
+    keys, capacity, n_envs, local, rows, offset = PIXEL, 24, 4, 2, 3, 2
+    mesh = Fabric(devices=1, accelerator="cpu").mesh
+    rng = np.random.default_rng(5)
+    env_ring, staged = _fill(rng, keys, (capacity, n_envs)), _fill(rng, keys, (rows, local))
+    pos, valid = np.asarray([9, 9, 22, 7], np.int32), np.asarray([9, 9, 24, 7], np.int32)
+    mask = np.asarray([[1, 1], [1, 0], [1, 1]], np.int32)
+    key = np.asarray(jax.random.PRNGKey(11))  # the append donates the state, the key with it
+    state = {
+        "storage": {k: jnp.asarray(ring_view(env_ring[k], shape)) for k, (shape, _d) in keys.items()},
+        "pos": jnp.asarray(pos), "valid": jnp.asarray(valid), "key": jnp.asarray(key),
+    }
+    carry, gradient_step = _collect_step(keys)
+    ring = _ring_spec(keys, capacity, n_envs)
+    with K.use_backend(backend):
+        append_fn, layout = build_seq_append_step(mesh, keys, capacity, n_envs, local, rows)
+        train_fn, ctl_layout = build_seq_train_step(gradient_step, mesh, ring)
+        blob = pack_burst_blob(layout, {**staged, "__mask__": mask, "__offset__": np.asarray(offset, np.int32)})
+        state = append_fn(state, jnp.asarray(blob))
+        ctl = pack_burst_blob(ctl_layout, {"__validmask__": np.ones(GRAD_CHUNK, np.float32)})
+        (steps, seen), _new_key, _metrics = train_fn(carry, state, jnp.asarray(ctl))
+    assert int(steps) == GRAD_CHUNK
+
+    row, new_pos_l, new_valid_l = ring_append_rows(
+        jnp.asarray(pos[offset:]), jnp.asarray(valid[offset:]), jnp.asarray(mask), capacity
+    )
+    cols = offset + jnp.broadcast_to(jnp.arange(local)[None, :], row.shape)
+    want_ring = {k: np.asarray(jnp.asarray(env_ring[k]).at[row, cols].set(jnp.asarray(staged[k]), mode="drop")) for k in keys}
+    new_pos = jnp.asarray(pos).at[offset:].set(new_pos_l)
+    new_valid = jnp.asarray(valid).at[offset:].set(new_valid_l)
+    np.testing.assert_array_equal(np.asarray(state["pos"]), np.asarray(new_pos))
+    for k, (shape, _d) in keys.items():
+        np.testing.assert_array_equal(env_view(np.asarray(state["storage"][k]), shape), want_ring[k], err_msg=k)
+    # the train program splits its dispatch key off the ring's key stream first
+    _next_key, k_dispatch = jax.random.split(jnp.asarray(key))
+    want = _reference_windows(k_dispatch, want_ring, new_pos, new_valid, capacity, n_envs)
+    for g in range(GRAD_CHUNK):
+        for k in keys:
+            np.testing.assert_array_equal(np.asarray(seen[k][g]), want[g][k], err_msg=f"{k} step {g}")
+
+
+def _equations(jaxpr):
+    from jax.extend import core as jex
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                if isinstance(sub, jex.ClosedJaxpr):
+                    yield from _equations(sub.jaxpr)
+                elif isinstance(sub, jex.Jaxpr):
+                    yield from _equations(sub)
+
+
+@pytest.mark.parametrize("backend", ["lax", "pallas"])
+def test_traced_burst_program_never_reshapes_or_transposes_a_ring_key(backend):
+    """Structural: no ``reshape`` or ``transpose`` equation of the traced
+    burst program takes an operand whose leading dimension is the ring's
+    capacity. Under the TPU's tiled layouts such a reshape is no bitcast: XLA
+    answers it with copies of the whole ring, every burst (PERF.md, PR 28)."""
+    from sheeprl_tpu.parallel.fabric import Fabric
+
+    keys, capacity, n_envs, rows = PIXEL, 29, 2, 3  # no blob segment is 29 bytes long
+    ring = _ring_spec(keys, capacity, n_envs, rows)
+    carry, gradient_step = _collect_step(keys)
+    rb = {k: jax.ShapeDtypeStruct((capacity, n_envs) + ring_cell(shape), dtype) for k, (shape, dtype) in keys.items()}
+    blob = jax.ShapeDtypeStruct((make_blob_layouts(keys, n_envs, GRAD_CHUNK, (rows,))[rows].nbytes,), jnp.uint8)
+    with K.use_backend(backend):
+        burst_fn = build_burst_train_step(gradient_step, Fabric(devices=1, accelerator="cpu").mesh, ring)
+        jaxpr = jax.make_jaxpr(burst_fn)(carry, rb, blob).jaxpr
+    eqns = list(_equations(jaxpr))
+    names = {e.primitive.name for e in eqns}
+    assert {"shard_map", "scan", "cond", "gather"} <= names  # the walk reaches the loop body's gather
+    assert ("pallas_call" in names) == (backend == "pallas")
+    whole_ring = [
+        str(e) for e in eqns
+        if e.primitive.name in ("reshape", "transpose") and getattr(e.invars[0].aval, "shape", ())[:1] == (capacity,)
+    ]
+    assert whole_ring == []
 
 
 def test_dreamer_ring_keys_layout():
