@@ -250,14 +250,14 @@ class _StochHead(nn.Module):
     dtype: Any = None
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, addend: Optional[jax.Array] = None) -> jax.Array:
         x = MLP(
             hidden_sizes=(self.hidden_size,),
             activation="silu",
             layer_norm=True,
             dtype=self.dtype,
             name="model",
-        )(x)
+        )(x, addend=addend)
         return nn.Dense(self.stoch_state_size, dtype=self.dtype, name="out")(x)
 
 
@@ -335,14 +335,21 @@ class RSSM:
         logits = _unimix(logits, self.discrete, self.unimix)
         return logits, sample_stochastic(logits, self.discrete, key)
 
+    def _prior_logits(self, wmp, recurrent_state) -> jax.Array:
+        logits = self.transition_model.apply(wmp["transition_model"], recurrent_state)
+        return _unimix(logits, self.discrete, self.unimix)
+
     def _transition(self, wmp, recurrent_out, key=None, sample_state: bool = True) -> Tuple[jax.Array, jax.Array]:
-        logits = self.transition_model.apply(wmp["transition_model"], recurrent_out)
-        logits = _unimix(logits, self.discrete, self.unimix)
+        logits = self._prior_logits(wmp, recurrent_out)
         return logits, sample_stochastic(logits, self.discrete, key, sample=sample_state)
 
     def _recurrent_step(self, wmp, posterior, recurrent_state, action, is_first, initial_states):
-        """What both dynamic steps share: reset the rows that start an episode
-        to the initial states, advance the recurrent state, read the prior.
+        """What both dynamic steps share, and all of a step that the next
+        step's carry depends on: reset the rows that start an episode to the
+        initial states, advance the recurrent state. The prior's logits are a
+        function of the new recurrent state alone and feed no carry, so they
+        are not formed here: a lone step reads them with ``_prior_logits``,
+        ``dynamic_rollout`` once for all ``T`` steps after its loop.
         ``initial_states`` is ``get_initial_states``'s pair; a scan evaluates
         it once before the loop and passes it in (it does not depend on the
         step), a lone call may leave it ``None``."""
@@ -357,22 +364,18 @@ class RSSM:
         init_rec, init_post = initial_states
         recurrent_state = (1 - is_first) * recurrent_state + is_first * init_rec.astype(dtype)
         posterior = (1 - is_first) * posterior + is_first * init_post.astype(posterior.dtype)
-        recurrent_state = self.recurrent_model.apply(
+        return self.recurrent_model.apply(
             wmp["recurrent_model"], jnp.concatenate([posterior, action], axis=-1), recurrent_state
         )
-        prior_logits = self.transition_model.apply(wmp["transition_model"], recurrent_state)
-        return recurrent_state, _unimix(prior_logits, self.discrete, self.unimix)
 
     def dynamic(
         self, wmp, posterior, recurrent_state, action, embedded_obs, is_first, key, initial_states=None
     ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
         """One dynamic-learning step (reference: ``agent.py:396-436``).
         All tensors are batch-shaped ``(B, ...)``; ``posterior`` flat."""
-        recurrent_state, prior_logits = self._recurrent_step(
-            wmp, posterior, recurrent_state, action, is_first, initial_states
-        )
+        recurrent_state = self._recurrent_step(wmp, posterior, recurrent_state, action, is_first, initial_states)
         posterior_logits, posterior = self._representation(wmp, recurrent_state, embedded_obs, key)
-        return recurrent_state, posterior, posterior_logits, prior_logits
+        return recurrent_state, posterior, posterior_logits, self._prior_logits(wmp, recurrent_state)
 
     def dynamic_decoupled(
         self, wmp, posterior, recurrent_state, action, is_first, initial_states=None
@@ -380,20 +383,38 @@ class RSSM:
         """Decoupled dynamic step: the posterior is precomputed from the
         observations alone; only the recurrent state and the prior advance
         (reference DecoupledRSSM.dynamic, ``agent.py:542-581``)."""
-        return self._recurrent_step(wmp, posterior, recurrent_state, action, is_first, initial_states)
+        recurrent_state = self._recurrent_step(wmp, posterior, recurrent_state, action, is_first, initial_states)
+        return recurrent_state, self._prior_logits(wmp, recurrent_state)
 
     def dynamic_rollout(self, wmp, embedded, actions, is_first, key):
-        """The ``T``-step dynamic-learning rollout over ``(T, B, ...)`` inputs
-        as one scan: recurrent states, posteriors, posterior and prior logits.
-        The initial states are evaluated once, and the weight gradients of the
-        step's ``Dense`` layers are formed after the backward loop
-        (:func:`sheeprl_tpu.models.scan_grads.scan_dense_grads_after`)."""
+        """The ``T``-step dynamic-learning rollout over ``(T, B, ...)`` inputs:
+        recurrent states, posteriors, posterior and prior logits; the values
+        and gradients of ``T`` chained ``dynamic`` steps. The scan's step
+        computes only what the next step's carry depends on; what is a
+        function of the scan's stacked inputs or outputs alone runs once, at
+        ``T * B`` rows, outside it:
+
+        - before the loop, the initial states, and the ``embedded`` half of the
+          representation model's first ``Dense``: ``concat([rec, emb]) @ K`` is
+          ``rec @ K[:H] + emb @ K[H:]``, and ``emb`` is an input of the scan;
+        - after the loop, the transition model over the stacked recurrent
+          states: the prior's logits are an output that no carry reads.
+
+        The weight gradients of the ``Dense`` layers left in the step are
+        formed after the backward loop
+        (:func:`sheeprl_tpu.models.scan_grads.scan_dense_grads_after`). That
+        function hoists a kernel's gradient only where the kernel is a leaf of
+        the parameters it is handed and an ``nn.Dense`` applies it, so
+        ``K[:H]`` is sliced here, outside, and goes in as the ``dense_0``
+        kernel of the representation model applied to ``rec``: sliced inside
+        the step, or applied by a bare product, its ``(H, D)`` gradient would
+        be read and written by every step of the backward loop."""
         T, B = actions.shape[:2]
         dtype = embedded.dtype
-        rec0 = jnp.zeros((B, self.recurrent_model.recurrent_state_size), dtype=dtype)
+        H = self.recurrent_model.recurrent_state_size
+        rec0 = jnp.zeros((B, H), dtype=dtype)
         # what the step reads: only the models it applies, and the initial states as values
-        inside = ("recurrent_model", "transition_model") + (() if self.decoupled else ("representation_model",))
-        params = {"wmp": {k: wmp[k] for k in inside}, "initial": self.get_initial_states(wmp, (B,))}
+        params = {"wmp": {"recurrent_model": wmp["recurrent_model"]}, "initial": self.get_initial_states(wmp, (B,))}
 
         if self.decoupled:
             # posteriors come from the observations alone, computed in one
@@ -404,24 +425,34 @@ class RSSM:
 
             def step_dec(p, rec, xs):
                 post_prev, act_t, first_t = xs
-                rec, prior_logits = self.dynamic_decoupled(p["wmp"], post_prev, rec, act_t, first_t, p["initial"])
-                return rec, (rec, prior_logits)
+                rec = self._recurrent_step(p["wmp"], post_prev, rec, act_t, first_t, p["initial"])
+                return rec, rec
 
-            _, (recs, prior_logits) = scan_dense_grads_after(step_dec, params, rec0, (posts_prev, actions, is_first))
-            return recs, posts, post_logits, prior_logits
+            _, recs = scan_dense_grads_after(step_dec, params, rec0, (posts_prev, actions, is_first))
+            return recs, posts, post_logits, self._prior_logits(wmp, recs)
 
+        representation = wmp["representation_model"]["params"]
+        dense_0 = representation["model"]["dense_0"]
+        kernel = dense_0["kernel"]  # (H + E, D): rows [:H] meet the recurrent state, rows [H:] the embedded observation
+        # Dense's own product (operands in the module's dtype, default precision), accumulated in float32
+        emb, k_emb = nn.dtypes.promote_dtype(embedded, kernel[H:], dtype=self.representation_model.dtype)
+        pre = jnp.einsum("tbe,ed->tbd", emb, k_emb, preferred_element_type=jnp.float32)
+        rec_half = {**representation["model"], "dense_0": {**dense_0, "kernel": kernel[:H]}}
+        params["wmp"]["representation_model"] = {"params": {**representation, "model": rec_half}}
         post0 = jnp.zeros((B, self.transition_model.stoch_state_size), dtype=dtype)
 
         def step(p, carry, xs):
             rec, post = carry
-            emb_t, act_t, first_t, k = xs
-            rec, post, post_logits, prior_logits = self.dynamic(
-                p["wmp"], post, rec, act_t, emb_t, first_t, k, p["initial"]
-            )
-            return (rec, post), (rec, post, post_logits, prior_logits)
+            pre_t, act_t, first_t, k = xs
+            rec = self._recurrent_step(p["wmp"], post, rec, act_t, first_t, p["initial"])
+            post_logits = self.representation_model.apply(p["wmp"]["representation_model"], rec, addend=pre_t)
+            post_logits = _unimix(post_logits, self.discrete, self.unimix)
+            post = sample_stochastic(post_logits, self.discrete, k)
+            return (rec, post), (rec, post, post_logits)
 
-        xs = (embedded, actions, is_first, jax.random.split(key, T))
-        return scan_dense_grads_after(step, params, (rec0, post0), xs)[1]
+        xs = (pre, actions, is_first, jax.random.split(key, T))
+        recs, posts, post_logits = scan_dense_grads_after(step, params, (rec0, post0), xs)[1]
+        return recs, posts, post_logits, self._prior_logits(wmp, recs)
 
     def imagination(self, wmp, prior, recurrent_state, actions, key) -> Tuple[jax.Array, jax.Array]:
         """One latent imagination step (reference: ``agent.py:482-500``)."""

@@ -23,7 +23,7 @@ walk HLO through these helpers so the gate and the bench can never drift.
 from __future__ import annotations
 
 import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Set, Tuple
 
 __all__ = [
     "DTYPE_BYTES",
@@ -36,6 +36,7 @@ __all__ = [
     "find_dtype",
     "op_scopes",
     "while_carried_shapes",
+    "while_body_shapes",
     "whole_array_relayouts",
 ]
 
@@ -279,6 +280,10 @@ def op_scopes(
 _WHILE_RE = re.compile(r"=\s*(\(.*\))\s+while\(")
 
 
+def _shapes(text: str) -> List[Tuple[str, Tuple[int, ...]]]:
+    return [(t, tuple(int(d) for d in dims.split(",") if d)) for t, dims in _TUPLE_ELEM_RE.findall(text)]
+
+
 def while_carried_shapes(compiled_hlo_text: str) -> List[List[Tuple[str, Tuple[int, ...]]]]:
     """For every ``while`` of an optimized executable, the ``(dtype, dims)`` of
     each array its loop carries (the instruction's result tuple, in order).
@@ -289,13 +294,31 @@ def while_carried_shapes(compiled_hlo_text: str) -> List[List[Tuple[str, Tuple[i
     for line in compiled_hlo_text.splitlines():
         m = _WHILE_RE.search(line)
         if m:
-            loops.append(
-                [(t, tuple(int(d) for d in dims.split(",") if d)) for t, dims in _TUPLE_ELEM_RE.findall(m.group(1))]
-            )
+            loops.append(_shapes(m.group(1)))
     return loops
 
 
 _COMPUTATION_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
+_WHILE_BODY_RE = re.compile(r"\swhile\(.*\bbody=%?([\w.\-]+)")
+
+
+def while_body_shapes(compiled_hlo_text: str) -> List[Set[Tuple[str, Tuple[int, ...]]]]:
+    """For every ``while`` of an optimized executable, the ``(dtype, dims)`` of
+    every array its body names: the results and operands of the body's own
+    instructions, its parameter tuple included (what a fusion works on inside
+    shows as that fusion's operands and result). What a scan's step computes
+    from, at the shapes it computes at."""
+    lines: Dict[str, List[str]] = {}
+    computation = None
+    for line in compiled_hlo_text.splitlines():
+        head = _COMPUTATION_RE.match(line)
+        if head:
+            computation = head.group(2)
+        elif computation is not None:
+            lines.setdefault(computation, []).append(line)
+    return [set(_shapes("\n".join(lines.get(body, [])))) for body in _WHILE_BODY_RE.findall(compiled_hlo_text)]
+
+
 _RELAYOUT_RE = re.compile(r"=\s*([a-z][a-z0-9]*)\[([0-9,]*)\](\{[^}]*\})?\s+(copy|reshape|transpose)\(")
 
 

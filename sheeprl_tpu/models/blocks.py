@@ -80,6 +80,10 @@ class MLP(nn.Module):
 
     Args mirror the reference: per-layer norm/dropout/activation, optional
     final ``output_dim`` linear with no activation, optional input flatten.
+    ``addend`` is added to the first ``Dense``'s output, before its norm and
+    activation: the part of that layer's product a caller formed elsewhere
+    (``RSSM.dynamic_rollout`` forms the half that no scan carry feeds once,
+    before its loop).
     """
 
     hidden_sizes: Sequence[int] = ()
@@ -93,7 +97,7 @@ class MLP(nn.Module):
     param_dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x: jax.Array, deterministic: bool = True) -> jax.Array:
+    def __call__(self, x: jax.Array, deterministic: bool = True, addend: Optional[jax.Array] = None) -> jax.Array:
         if self.flatten_dim is not None:
             x = jnp.reshape(x, x.shape[: self.flatten_dim] + (-1,))
         acts = self.activation if isinstance(self.activation, (list, tuple)) else [self.activation] * len(
@@ -101,6 +105,8 @@ class MLP(nn.Module):
         )
         for i, size in enumerate(self.hidden_sizes):
             x = nn.Dense(size, dtype=self.dtype, param_dtype=self.param_dtype, name=f"dense_{i}")(x)
+            if i == 0 and addend is not None:
+                x = x + addend
             if self.dropout > 0:
                 x = nn.Dropout(self.dropout, deterministic=deterministic)(x)
             if self.layer_norm:

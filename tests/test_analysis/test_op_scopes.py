@@ -83,3 +83,21 @@ def test_joined_missing_and_unknown_op_names():
 def test_kernel_prefix_and_regions_are_the_callers():
     table = op_scopes(_compiled_text(), ("wm.dynamics",), kernel_prefix="nothing.")
     assert {v["scope"] for v in table.values()} == {None, "wm.dynamics"}
+
+
+def test_while_readers_tell_what_a_loop_holds_from_what_runs_outside_it():
+    """``while_carried_shapes`` and ``while_body_shapes``: a matrix the scan's
+    step applies rides the loop's carry and is named in its body; one applied
+    to the stacked outputs, after the loop, is in neither."""
+    from sheeprl_tpu.analysis.hlo import while_body_shapes, while_carried_shapes
+
+    def f(w_in, w_out, x):
+        _, hs = jax.lax.scan(lambda h, x_t: (jnp.tanh(h @ w_in + x_t),) * 2, jnp.zeros_like(x[0]), x)
+        return jnp.tanh(hs @ w_out)
+
+    text = jax.jit(f).lower(jnp.ones((8, 8)), jnp.ones((8, 6)), jnp.ones((5, 4, 8))).compile().as_text()
+    (carried,), (body,) = while_carried_shapes(text), while_body_shapes(text)
+    inside, outside = ("f32", (8, 8)), ("f32", (8, 6))
+    assert inside in carried and inside in body
+    assert outside not in carried and outside not in body
+    assert set(carried) <= body, "a loop's carry is its body's parameter"

@@ -36,6 +36,19 @@ def test_mlp_flatten():
     assert out.shape == (2, 3)
 
 
+def test_mlp_addend_is_the_other_half_of_the_first_layer():
+    """``MLP(x, addend=y @ K[n:])`` on the kernel's first ``n`` rows is
+    ``MLP(concat([x, y]))`` on the whole kernel: the seam
+    ``RSSM.dynamic_rollout`` splits the representation model's input at."""
+    m = MLP(hidden_sizes=(16, 8), output_dim=3, activation="silu", layer_norm=True)
+    x, y = jax.random.normal(jax.random.PRNGKey(1), (2, 4, 6)), jax.random.normal(jax.random.PRNGKey(2), (2, 4, 5))
+    params = m.init(jax.random.PRNGKey(0), jnp.zeros((4, 11)))
+    kernel = params["params"]["dense_0"]["kernel"]
+    first = {"params": {**params["params"], "dense_0": {**params["params"]["dense_0"], "kernel": kernel[:6]}}}
+    whole = m.apply(params, jnp.concatenate([x, y], axis=-1))
+    np.testing.assert_allclose(m.apply(first, x, addend=y @ kernel[6:]), whole, rtol=1e-5, atol=1e-6)
+
+
 def test_cnn_nhwc():
     m = CNN(hidden_channels=(8, 16), layer_args={"kernel_size": 3, "stride": 2, "padding": 1})
     params = m.init(jax.random.PRNGKey(0), jnp.zeros((2, 16, 16, 3)))

@@ -1,7 +1,11 @@
-"""``scan_dense_grads_after`` (``models/scan_grads.py``) against a plain
-closed-over ``lax.scan`` of the same step: same values, same gradients, and no
-weight-gradient accumulator left in the backward loop. The plain rollout, which
-the trainers ran before, lives on here only."""
+"""``RSSM.dynamic_rollout`` over ``scan_dense_grads_after``
+(``models/scan_grads.py``) against a plain closed-over ``lax.scan`` of
+``RSSM.dynamic``: same values, same gradients, no weight-gradient accumulator
+left in the backward loop, and nothing in either loop that feeds no carry (the
+transition model, the ``embedded`` half of the representation model). The plain
+rollout, which the trainers ran before, lives on here only."""
+
+import functools
 
 import gymnasium as gym
 import jax
@@ -10,14 +14,26 @@ import numpy as np
 import pytest
 
 from sheeprl_tpu.algos.dreamer_v3.agent import RSSM, RecurrentModel, _StochHead
-from sheeprl_tpu.analysis.hlo import while_carried_shapes
+from sheeprl_tpu.analysis.hlo import while_body_shapes, while_carried_shapes
 
 T, B = 6, 3
 ACTIONS, EMBED = 3, 10
-RECURRENT, DENSE, HIDDEN = 24, 8, 12
+RECURRENT, DENSE, HIDDEN, TRANSITION_HIDDEN = 24, 8, 12, 14
 STOCH, DISCRETE = 4, 4
+#: the width of ``concat([recurrent_state, embedded])``: no other array of the rollout has a dimension of it
+CONCAT = RECURRENT + EMBED
 #: the GRU's fused projection: no other array of the rollout has this shape
 FUSED = ("f32", (RECURRENT + DENSE, 3 * RECURRENT))
+#: every other kernel a step of the coupled rollout applies, each shape its own too: the recurrent MLP's, the ``rec``
+#: half of the representation model's first layer (sliced off the ``(CONCAT, HIDDEN)`` leaf), its output layer's
+STEP_KERNELS = {
+    FUSED,
+    ("f32", (STOCH * DISCRETE + ACTIONS, DENSE)),
+    ("f32", (RECURRENT, HIDDEN)),
+    ("f32", (HIDDEN, STOCH * DISCRETE)),
+}
+#: the transition model's two kernels: no other array of the rollout has either shape
+TRANSITION = {("f32", (RECURRENT, TRANSITION_HIDDEN)), ("f32", (TRANSITION_HIDDEN, STOCH * DISCRETE))}
 
 IS_FIRST = {
     "start_only": np.zeros((T, B, 1), np.float32),
@@ -59,7 +75,7 @@ def _rssm_and_inputs(decoupled, seed=0):
     rssm = RSSM(
         recurrent_model=RecurrentModel(recurrent_state_size=RECURRENT, dense_units=DENSE),
         representation_model=_StochHead(hidden_size=HIDDEN, stoch_state_size=STOCH * DISCRETE),
-        transition_model=_StochHead(hidden_size=HIDDEN, stoch_state_size=STOCH * DISCRETE),
+        transition_model=_StochHead(hidden_size=TRANSITION_HIDDEN, stoch_state_size=STOCH * DISCRETE),
         discrete=DISCRETE,
         decoupled=decoupled,
     )
@@ -84,7 +100,7 @@ def _rssm_and_inputs(decoupled, seed=0):
 def _loss(rollout, rssm, is_first, weights, key):
     def loss(wmp, embedded, actions):
         outs = rollout(rssm, wmp, embedded, actions, is_first, key)
-        return sum(jnp.sum(w * o) for w, o in zip(weights, outs))
+        return sum(jnp.sum(w * o) for w, o in zip(weights, outs)), outs
 
     return loss
 
@@ -101,14 +117,17 @@ def _assert_close(got, want, what, rel=1e-5):
 def test_rollout_gradients_match_plain_scan(decoupled, pattern):
     rssm, wmp, embedded, actions, weights, key = _rssm_and_inputs(decoupled)
     is_first = jnp.asarray(IS_FIRST[pattern])
-    (value, (g_wmp, g_emb)), (value_plain, (g_wmp_plain, g_emb_plain)) = [
-        jax.jit(jax.value_and_grad(_loss(rollout, rssm, is_first, weights, key), argnums=(0, 1)))(
+    ((_, outs), (g_wmp, g_emb, g_act)), ((_, outs_plain), (g_wmp_plain, g_emb_plain, g_act_plain)) = [
+        jax.jit(jax.value_and_grad(_loss(rollout, rssm, is_first, weights, key), argnums=(0, 1, 2), has_aux=True))(
             wmp, embedded, actions
         )
         for rollout in (RSSM.dynamic_rollout, plain_rollout)
     ]
-    np.testing.assert_allclose(value, value_plain, rtol=1e-6)
+    names = ("recurrent_states", "posteriors", "posterior_logits", "prior_logits")
+    for name, got, want in zip(names, outs, outs_plain):
+        _assert_close(got, want, name)
     _assert_close(g_emb, g_emb_plain, "embedded")
+    _assert_close(g_act, g_act_plain, "actions")
     paths = jax.tree_util.tree_flatten_with_path(g_wmp_plain)[0]
     assert jax.tree.structure(g_wmp) == jax.tree.structure(g_wmp_plain)
     for (path, want), got in zip(paths, jax.tree.leaves(g_wmp)):
@@ -125,23 +144,49 @@ def test_rollout_values_match_without_differentiation():
         np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 
 
+@functools.lru_cache(maxsize=None)
+def _gradient_hlo(decoupled, hoisted):
+    """The optimized executable of the rollout's gradient (``RSSM.dynamic_rollout`` if ``hoisted``, else the plain
+    scan) with resets in mid-sequence, as text; both structural tests read the same four programs."""
+    rssm, wmp, embedded, actions, weights, key = _rssm_and_inputs(decoupled)
+    rollout = RSSM.dynamic_rollout if hoisted else plain_rollout
+    loss = _loss(rollout, rssm, jnp.asarray(IS_FIRST["mid_sequence_resets"]), weights, key)
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True))
+    return grad.lower(wmp, embedded, actions).compile().as_text()
+
+
 @pytest.mark.parametrize("decoupled", [False, True], ids=["coupled", "decoupled"])
 def test_backward_loop_carries_no_weight_gradient(decoupled):
     """The guard against the accumulation coming back: in the optimized
-    executable of the rollout's gradient, no ``while`` carries the fused
-    kernel's shape more than once (the weight itself). The plain scan carries
-    it twice in its backward loop: weight and accumulator."""
-    rssm, wmp, embedded, actions, weights, key = _rssm_and_inputs(decoupled)
-    is_first = jnp.asarray(IS_FIRST["mid_sequence_resets"])
+    executable of the rollout's gradient, no ``while`` carries a kernel's
+    shape more than once (the weight itself). The plain scan carries the fused
+    kernel's twice in its backward loop: weight and accumulator."""
     most = {}
-    for name, rollout in (("hoisted", RSSM.dynamic_rollout), ("plain", plain_rollout)):
-        grad = jax.jit(jax.grad(_loss(rollout, rssm, is_first, weights, key), argnums=(0, 1)))
-        text = grad.lower(wmp, embedded, actions).compile().as_text()
-        loops = while_carried_shapes(text)
+    for hoisted in (True, False):
+        loops = while_carried_shapes(_gradient_hlo(decoupled, hoisted))
         assert len(loops) >= 2, "expected a forward and a backward loop"
-        most[name] = max(shapes.count(FUSED) for shapes in loops)
-    assert most["plain"] == 2, "the plain scan no longer shows the accumulator: the test has lost its teeth"
-    assert most["hoisted"] <= 1
+        most[hoisted] = {kernel: max(shapes.count(kernel) for shapes in loops) for kernel in STEP_KERNELS}
+    assert most[False][FUSED] == 2, "the plain scan no longer shows the accumulator: the test has lost its teeth"
+    assert max(most[True].values()) <= 1, most[True]
+
+
+@pytest.mark.parametrize("decoupled", [False, True], ids=["coupled", "decoupled"])
+def test_loops_hold_only_what_feeds_the_carry(decoupled):
+    """In the optimized executable of the rollout's gradient, no loop's body
+    (its carry is the body's parameter) names an array with a transition
+    kernel's shape or a dimension as wide as ``concat([rec, embedded])``: the
+    transition model runs after the loop, the ``embedded`` half of the
+    representation model's first layer before it. The plain scan holds both."""
+
+    def found(hoisted):
+        bodies = while_body_shapes(_gradient_hlo(decoupled, hoisted))
+        assert len(bodies) >= 2, "expected a forward and a backward loop"
+        shapes = set().union(*bodies)
+        return bool(shapes & TRANSITION), any(CONCAT in dims for _, dims in shapes)
+
+    # the decoupled posterior never was in the loop: only its transition model shows there
+    assert found(False) == (True, not decoupled), "the plain scan no longer shows them: the test has lost its teeth"
+    assert found(True) == (False, False)
 
 
 def test_train_step_matches_plain_rollout(tmp_path, monkeypatch):
