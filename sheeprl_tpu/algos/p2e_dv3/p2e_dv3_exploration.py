@@ -21,19 +21,17 @@ The env rollout uses the exploration actor (``cfg.algo.player.actor_type =
 
 from __future__ import annotations
 
-import os
-import warnings
 from typing import Any, Dict, Sequence
 
-import gymnasium as gym
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
-from sheeprl_tpu.algos.dreamer_v3.agent import PlayerDV3, actor_dists, actor_sample
+from sheeprl_tpu.algos.dreamer_v3.agent import actor_dists, actor_sample
+from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import host_player_factory, player_snapshot
 from sheeprl_tpu.algos.dreamer_v3.loss import reconstruction_loss
 from sheeprl_tpu.algos.p2e_dv3.agent import build_agent, ensembles_apply
 from sheeprl_tpu.algos.p2e_dv3.utils import (
@@ -43,7 +41,7 @@ from sheeprl_tpu.algos.p2e_dv3.utils import (
     prepare_obs,
     test,
 )
-from sheeprl_tpu.data.buffers import EnvIndependentReplayBuffer, SequentialReplayBuffer
+from sheeprl_tpu.algos.world_model_loop import Family, platform_trainer, run as run_loop
 from sheeprl_tpu.distributions import (
     BernoulliSafeMode,
     Independent,
@@ -53,12 +51,7 @@ from sheeprl_tpu.distributions import (
     TwoHotEncodingDistribution,
 )
 from sheeprl_tpu.parallel.comm import pmean_grads
-from sheeprl_tpu.envs.factory import vectorize_env
-from sheeprl_tpu.utils.logger import get_log_dir, get_logger
-from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric, build_aggregator
 from sheeprl_tpu.utils.registry import register_algorithm
-from sheeprl_tpu.utils.timer import timer
-from sheeprl_tpu.utils.utils import Ratio, resolve_hybrid_player, save_configs
 
 __all__ = ["main", "make_train_step"]
 
@@ -440,8 +433,6 @@ def main(fabric, cfg: Dict[str, Any]):
     from sheeprl_tpu.optim.builders import build_optimizer
     from sheeprl_tpu.fault import load_resume_state
 
-    rank = fabric.global_rank
-
     state = None
     if cfg.checkpoint.resume_from:
         state = load_resume_state(cfg.checkpoint.resume_from)
@@ -449,28 +440,6 @@ def main(fabric, cfg: Dict[str, Any]):
     # These arguments cannot be changed (reference: p2e_dv3_exploration.py:530-532)
     cfg.env.frame_stack = 1
     cfg.algo.player.actor_type = "exploration"
-
-    log_dir = get_log_dir(cfg, cfg.root_dir, cfg.run_name)
-    logger = get_logger(cfg, log_dir, rank)
-    if fabric.is_global_zero:
-        logger.log_hyperparams(cfg)
-    print(f"Log dir: {log_dir}")
-
-
-    envs = vectorize_env(
-        cfg, cfg.seed, rank, log_dir if rank == 0 else None, prefix="train", restart_on_exception=True
-    )
-    action_space = envs.single_action_space
-    observation_space = envs.single_observation_space
-
-    is_continuous = isinstance(action_space, gym.spaces.Box)
-    is_multidiscrete = isinstance(action_space, gym.spaces.MultiDiscrete)
-    actions_dim = tuple(
-        action_space.shape if is_continuous else (action_space.nvec.tolist() if is_multidiscrete else [action_space.n])
-    )
-    clip_rewards_fn = (lambda r: np.tanh(r)) if cfg.env.clip_rewards else (lambda r: r)
-    if not isinstance(observation_space, gym.spaces.Dict):
-        raise RuntimeError(f"Unexpected observation type, should be of type Dict, got: {observation_space}")
     if (
         len(set(cfg.algo.cnn_keys.encoder).intersection(set(cfg.algo.cnn_keys.decoder))) == 0
         and len(set(cfg.algo.mlp_keys.encoder).intersection(set(cfg.algo.mlp_keys.decoder))) == 0
@@ -479,413 +448,87 @@ def main(fabric, cfg: Dict[str, Any]):
     if cfg.metric.log_level > 0:
         print("Encoder CNN keys:", cfg.algo.cnn_keys.encoder)
         print("Encoder MLP keys:", cfg.algo.mlp_keys.encoder)
-    obs_keys = cfg.algo.cnn_keys.encoder + cfg.algo.mlp_keys.encoder
 
-    world_model, ens_module, actor, critic, critics_spec, params, player = build_agent(
-        fabric,
-        actions_dim,
-        is_continuous,
-        cfg,
-        observation_space,
-        state["world_model"] if state is not None else None,
-        state["ensembles"] if state is not None else None,
-        state["actor_task"] if state is not None else None,
-        state["critic_task"] if state is not None else None,
-        state["target_critic_task"] if state is not None else None,
-        state["actor_exploration"] if state is not None else None,
-        state["critics_exploration"] if state is not None else None,
+    model_keys = (
+        "world_model", "ensembles", "actor_task", "critic_task", "target_critic_task", "actor_exploration",
+        "critics_exploration",
     )
 
-    txs = {
-        "world": build_optimizer(cfg.algo.world_model.optimizer, max_grad_norm=cfg.algo.world_model.clip_gradients),
-        "actor_task": build_optimizer(cfg.algo.actor.optimizer, max_grad_norm=cfg.algo.actor.clip_gradients),
-        "critic_task": build_optimizer(cfg.algo.critic.optimizer, max_grad_norm=cfg.algo.critic.clip_gradients),
-        "actor_exploration": build_optimizer(cfg.algo.actor.optimizer, max_grad_norm=cfg.algo.actor.clip_gradients),
-        "ensembles": build_optimizer(cfg.algo.ensembles.optimizer, max_grad_norm=cfg.algo.ensembles.clip_gradients),
-        "critics_exploration": {
-            k: build_optimizer(cfg.algo.critic.optimizer, max_grad_norm=cfg.algo.critic.clip_gradients)
-            for k in critics_spec
-        },
-    }
-    opts = {
-        "world": txs["world"].init(params["world_model"]),
-        "actor_task": txs["actor_task"].init(params["actor_task"]),
-        "critic_task": txs["critic_task"].init(params["critic_task"]),
-        "actor_exploration": txs["actor_exploration"].init(params["actor_exploration"]),
-        "ensembles": txs["ensembles"].init(params["ensembles"]),
-        "critics_exploration": {
-            k: txs["critics_exploration"][k].init(params["critics_exploration"][k]["module"]) for k in critics_spec
-        },
-    }
-    if state is not None:
-        opts = jax.tree.map(lambda t, s: jnp.asarray(s) if hasattr(t, "dtype") else s, opts, state["optimizers"])
-    opts = fabric.put_replicated(opts)
+    def models(p):
+        return {k: p[k] for k in model_keys}
 
-    moments_state = {"task": init_moments(), "exploration": {k: init_moments() for k in critics_spec}}
-    if state is not None:
-        moments_state = jax.tree.map(jnp.asarray, state["moments"])
-    moments_state = fabric.put_replicated(moments_state)
-
-    if fabric.is_global_zero:
-        save_configs(cfg, log_dir)
-
-    aggregator = None
-    if not MetricAggregator.disabled:
-        aggregator = build_aggregator(cfg.metric.aggregator)
-
-    buffer_size = cfg.buffer.size // int(cfg.env.num_envs) if not cfg.dry_run else 2
-    rb = EnvIndependentReplayBuffer(
-        buffer_size,
-        n_envs=cfg.env.num_envs,
-        obs_keys=tuple(obs_keys),
-        memmap=cfg.buffer.memmap,
-        memmap_dir=os.path.join(log_dir, "memmap_buffer", f"rank_{rank}"),
-        buffer_cls=SequentialReplayBuffer,
-    )
-    if state is not None and cfg.buffer.checkpoint:
-        if isinstance(state["rb"], list):
-            rb = state["rb"][0]
-        elif isinstance(state["rb"], EnvIndependentReplayBuffer):
-            rb = state["rb"]
-        else:
-            raise RuntimeError(f"Cannot restore the replay buffer from {type(state['rb'])}")
-
-    train_step = 0
-    last_train = 0
-    start_iter = state["iter_num"] + 1 if state is not None else 1
-    policy_step = state["iter_num"] * cfg.env.num_envs if state is not None else 0
-    last_log = state["last_log"] if state is not None else 0
-    last_checkpoint = state["last_checkpoint"] if state is not None else 0
-    policy_steps_per_iter = int(cfg.env.num_envs)
-    total_iters = int(cfg.algo.total_steps // policy_steps_per_iter) if not cfg.dry_run else 1
-    learning_starts = cfg.algo.learning_starts // policy_steps_per_iter if not cfg.dry_run else 0
-    prefill_steps = learning_starts - int(learning_starts > 0)
-    if state is not None:
-        cfg.algo.per_rank_batch_size = state["batch_size"]
-        learning_starts += start_iter
-        prefill_steps += start_iter
-
-    ratio = Ratio(cfg.algo.replay_ratio, pretrain_steps=cfg.algo.per_rank_pretrain_steps)
-    if state is not None:
-        ratio.load_state_dict(state["ratio"])
-
-    if cfg.checkpoint.every % policy_steps_per_iter != 0:
-        warnings.warn(
-            f"The checkpoint.every parameter ({cfg.checkpoint.every}) is not a multiple of the "
-            f"policy_steps_per_iter value ({policy_steps_per_iter})."
-        )
-
-    batch_size = int(cfg.algo.per_rank_batch_size)
-    seq_len = int(cfg.algo.per_rank_sequence_length)
-    if batch_size % fabric.world_size != 0:
-        raise ValueError(
-            f"per_rank_batch_size ({batch_size}) must be divisible by the number of devices ({fabric.world_size})"
-        )
-    rng = jax.random.PRNGKey(cfg.seed)
-    cnn_keys = cfg.algo.cnn_keys.encoder
-    mlp_keys = cfg.algo.mlp_keys.encoder
-
-    def player_params():
-        return {"world_model": params["world_model"], "actor": params["actor_exploration"]}
-
-    # TPU-native overlap, shared with the Dreamer mains (`algo.hybrid_player`):
-    # host-CPU exploration policy from a packed bf16 snapshot, device-resident
-    # uint8 sequence ring, Ratio grants dispatched in bursts on a trainer
-    # thread (see dreamer_v3.py for the design rationale).
-    hp_cfg = cfg.algo.get("hybrid_player") or {}
-    burst_mode = resolve_hybrid_player(hp_cfg, fabric.mesh)
-    host_mirror = (not burst_mode) or bool(cfg.buffer.checkpoint)
-
-    if burst_mode:
-        from sheeprl_tpu.utils.burst import HybridPlayerHarness
-
-        wm_cfg_ = cfg.algo.world_model
-
-        def _player_subset(p):
-            wm = p["world_model"]
-            return {
-                "world_model": {
-                    "encoder": wm["encoder"],
-                    "recurrent_model": wm["recurrent_model"],
-                    "representation_model": wm["representation_model"],
-                    "transition_model": wm["transition_model"],
-                    "initial_recurrent_state": wm["initial_recurrent_state"],
-                },
-                "actor": p["actor_exploration"],
-            }
-
-        hp = HybridPlayerHarness(
-            fabric, cfg,
-            observation_space=observation_space, cnn_keys=cnn_keys, mlp_keys=mlp_keys,
-            actions_dim=actions_dim, capacity=buffer_size, seq_len=seq_len, batch_size=batch_size,
-            policy_steps_per_iter=policy_steps_per_iter,
-            make_burst_fn=lambda ring: make_train_step(
-                world_model, ens_module, actor, critic, critics_spec, cfg, fabric.mesh,
-                actions_dim, is_continuous, txs, ring=ring,
-            ),
-            player_subset=_player_subset,
-            carry=(params, opts, moments_state, jnp.int32(0)),
-            rb=rb if (state is not None and cfg.buffer.checkpoint) else None,
-            with_is_first=True, aggregator=aggregator,
-        )
-        host_player = PlayerDV3(
-            world_model,
-            actor,
-            actions_dim,
-            cfg.env.num_envs,
-            int(wm_cfg_.stochastic_size),
-            int(wm_cfg_.recurrent_model.recurrent_state_size),
-            discrete_size=int(wm_cfg_.discrete_size),
-            actor_type="exploration",
-            host_device=hp.host_device,
-        )
-    else:
-        train_fn = make_train_step(
-            world_model, ens_module, actor, critic, critics_spec, cfg, fabric.mesh, actions_dim, is_continuous, txs
-        )
-    data_sharding = NamedSharding(fabric.mesh, P(None, None, "dp"))
-
-    step_data: Dict[str, np.ndarray] = {}
-    obs = envs.reset(seed=cfg.seed)[0]
-    for k in obs_keys:
-        step_data[k] = np.asarray(obs[k])[np.newaxis]
-    step_data["rewards"] = np.zeros((1, cfg.env.num_envs, 1), dtype=np.float32)
-    step_data["truncated"] = np.zeros((1, cfg.env.num_envs, 1), dtype=np.float32)
-    step_data["terminated"] = np.zeros((1, cfg.env.num_envs, 1), dtype=np.float32)
-    step_data["is_first"] = np.ones_like(step_data["terminated"])
-    if burst_mode:
-        host_player.init_states(hp.host_params)
-    else:
-        player.init_states(player_params())
-
-    cumulative_per_rank_gradient_steps = 0
-    for iter_num in range(start_iter, total_iters + 1):
-        policy_step += policy_steps_per_iter
-
-        if burst_mode:
-            hp.poll()
-
-        with timer("Time/env_interaction_time", SumMetric):
-            if iter_num <= learning_starts and state is None:
-                real_actions = actions = np.array(envs.action_space.sample())
-                if not is_continuous:
-                    acts2d = actions.reshape(cfg.env.num_envs, len(actions_dim))
-                    actions = np.concatenate(
-                        [np.eye(d, dtype=np.float32)[acts2d[:, i]] for i, d in enumerate(actions_dim)],
-                        axis=-1,
-                    )
-            else:
-                jobs = prepare_obs(fabric, obs, cnn_keys=cnn_keys, num_envs=cfg.env.num_envs)
-                if burst_mode:
-                    # Host-CPU policy on the snapshot params (see dreamer_v3).
-                    action_list = host_player.get_actions(hp.host_params, jobs, hp.host_key())
-                else:
-                    rng, subkey = jax.random.split(rng)
-                    action_list = player.get_actions(player_params(), jobs, subkey)
-                actions = np.asarray(jnp.concatenate(action_list, axis=-1))
-                if is_continuous:
-                    real_actions = actions
-                else:
-                    real_actions = np.stack([np.asarray(a).argmax(axis=-1) for a in action_list], axis=-1)
-
-            step_data["actions"] = actions.reshape(1, cfg.env.num_envs, -1)
-            if host_mirror:
-                rb.add(step_data, validate_args=cfg.buffer.validate_args)
-            if burst_mode:
-                hp.stage_step(step_data)
-
-            next_obs, rewards, terminated, truncated, infos = envs.step(
-                real_actions.reshape(envs.action_space.shape)
-            )
-            dones = np.logical_or(terminated, truncated).astype(np.uint8)
-
-        step_data["is_first"] = np.zeros_like(step_data["terminated"])
-        if "restart_on_exception" in infos:
-            for i, agent_roe in enumerate(infos["restart_on_exception"]):
-                if agent_roe and not dones[i]:
-                    if host_mirror:
-                        sub_rb = rb.buffer[i]
-                        last_inserted_idx = (sub_rb._pos - 1) % sub_rb.buffer_size
-                        sub_rb["terminated"][last_inserted_idx] = np.zeros_like(
-                            sub_rb["terminated"][last_inserted_idx]
-                        )
-                        sub_rb["truncated"][last_inserted_idx] = np.ones_like(
-                            sub_rb["truncated"][last_inserted_idx]
-                        )
-                        sub_rb["is_first"][last_inserted_idx] = np.zeros_like(
-                            sub_rb["is_first"][last_inserted_idx]
-                        )
-                    step_data["is_first"][0, i] = np.ones_like(step_data["is_first"][0, i])
-                    if burst_mode:
-                        hp.patch_last(i, {"terminated": 0.0, "is_first": 0.0})
-
-        if cfg.metric.log_level > 0 and "final_info" in infos:
-            ep_info = infos["final_info"]
-            if isinstance(ep_info, dict) and "episode" in ep_info:
-                mask = ep_info.get("_episode", np.ones_like(np.asarray(ep_info["episode"]["r"]), dtype=bool))
-                rews = np.asarray(ep_info["episode"]["r"])[mask]
-                lens = np.asarray(ep_info["episode"]["l"])[mask]
-                for i, (ep_rew, ep_len) in enumerate(zip(rews, lens)):
-                    if aggregator and "Rewards/rew_avg" in aggregator:
-                        aggregator.update("Rewards/rew_avg", ep_rew)
-                    if aggregator and "Game/ep_len_avg" in aggregator:
-                        aggregator.update("Game/ep_len_avg", ep_len)
-                    print(f"Rank-0: policy_step={policy_step}, reward_env_{i}={ep_rew}")
-
-        real_next_obs = {k: np.asarray(next_obs[k]).copy() for k in obs_keys}
-        if "final_obs" in infos:
-            for idx, final_obs in enumerate(infos["final_obs"]):
-                if final_obs is not None:
-                    for k in obs_keys:
-                        real_next_obs[k][idx] = np.asarray(final_obs[k])
-
-        for k in obs_keys:
-            step_data[k] = np.asarray(next_obs[k])[np.newaxis]
-        obs = next_obs
-
-        rewards = np.asarray(rewards, dtype=np.float32).reshape(1, cfg.env.num_envs, -1)
-        step_data["terminated"] = np.asarray(terminated, dtype=np.float32).reshape(1, cfg.env.num_envs, -1)
-        step_data["truncated"] = np.asarray(truncated, dtype=np.float32).reshape(1, cfg.env.num_envs, -1)
-        step_data["rewards"] = clip_rewards_fn(rewards)
-
-        dones_idxes = dones.nonzero()[0].tolist()
-        reset_envs = len(dones_idxes)
-        if reset_envs > 0:
-            reset_data = {}
-            for k in obs_keys:
-                reset_data[k] = (real_next_obs[k][dones_idxes])[np.newaxis]
-            reset_data["terminated"] = step_data["terminated"][:, dones_idxes]
-            reset_data["truncated"] = step_data["truncated"][:, dones_idxes]
-            reset_data["actions"] = np.zeros((1, reset_envs, int(np.sum(actions_dim))), dtype=np.float32)
-            reset_data["rewards"] = step_data["rewards"][:, dones_idxes]
-            reset_data["is_first"] = np.zeros_like(reset_data["terminated"])
-            if host_mirror:
-                rb.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
-            if burst_mode:
-                hp.stage_reset(reset_data, dones_idxes)
-
-            step_data["rewards"][:, dones_idxes] = np.zeros_like(reset_data["rewards"])
-            step_data["terminated"][:, dones_idxes] = np.zeros_like(step_data["terminated"][:, dones_idxes])
-            step_data["truncated"][:, dones_idxes] = np.zeros_like(step_data["truncated"][:, dones_idxes])
-            step_data["is_first"][:, dones_idxes] = np.ones_like(step_data["is_first"][:, dones_idxes])
-            if burst_mode:
-                host_player.init_states(hp.host_params, dones_idxes)
-            else:
-                player.init_states(player_params(), dones_idxes)
-
-        if burst_mode:
-            if iter_num >= learning_starts:
-                hp.grant(ratio(policy_step - prefill_steps * policy_steps_per_iter))
-            hp.pump()
-            cumulative_per_rank_gradient_steps, train_step = hp.gradient_steps, hp.train_steps
-        elif iter_num >= learning_starts:
-            per_rank_gradient_steps = ratio(policy_step - prefill_steps * policy_steps_per_iter)
-            if per_rank_gradient_steps > 0:
-                sample = rb.sample(
-                    batch_size,
-                    sequence_length=seq_len,
-                    n_samples=per_rank_gradient_steps,
-                )
-                data = {
-                    k: jax.device_put(np.asarray(v, dtype=np.float32), data_sharding) for k, v in sample.items()
-                }
-                with timer("Time/train_time", SumMetric):
-                    rng, train_key = jax.random.split(rng)
-                    params, opts, moments_state, metrics = train_fn(
-                        params, opts, moments_state, data, train_key,
-                        jnp.int32(cumulative_per_rank_gradient_steps),
-                    )
-                    if aggregator and not aggregator.disabled:
-                        for name, value in metrics.items():
-                            if name in aggregator:
-                                aggregator.update(name, value)
-                cumulative_per_rank_gradient_steps += per_rank_gradient_steps
-                train_step += 1
-
-        if cfg.metric.log_level > 0 and (policy_step - last_log >= cfg.metric.log_every or iter_num == total_iters):
-            if aggregator and not aggregator.disabled:
-                logger.log_dict(aggregator.compute(), policy_step)
-                aggregator.reset()
-            if not timer.disabled:
-                timer_metrics = timer.compute()
-                if timer_metrics.get("Time/train_time", 0) > 0:
-                    logger.log_dict(
-                        {"Time/sps_train": (train_step - last_train) / timer_metrics["Time/train_time"]},
-                        policy_step,
-                    )
-                if timer_metrics.get("Time/env_interaction_time", 0) > 0:
-                    logger.log_dict(
-                        {
-                            "Time/sps_env_interaction": (
-                                (policy_step - last_log) * cfg.env.action_repeat
-                            )
-                            / timer_metrics["Time/env_interaction_time"]
-                        },
-                        policy_step,
-                    )
-                timer.reset()
-            last_log = policy_step
-            last_train = train_step
-
-        if (cfg.checkpoint.every > 0 and policy_step - last_checkpoint >= cfg.checkpoint.every) or (
-            iter_num == total_iters and cfg.checkpoint.save_last
-        ):
-            last_checkpoint = policy_step
-            if burst_mode:
-                # Latest trainer-thread handles (at most one burst stale).
-                params, opts, moments_state, _ = hp.carry
-            ckpt_state = {
-                "world_model": params["world_model"],
-                "ensembles": params["ensembles"],
-                "actor_task": params["actor_task"],
-                "critic_task": params["critic_task"],
-                "target_critic_task": params["target_critic_task"],
-                "actor_exploration": params["actor_exploration"],
-                "critics_exploration": params["critics_exploration"],
-                "optimizers": opts,
-                "moments": moments_state,
-                "ratio": ratio.state_dict(),
-                "iter_num": iter_num,
-                "batch_size": batch_size,
-                "last_log": last_log,
-                "last_checkpoint": last_checkpoint,
-            }
-            ckpt_path = os.path.join(log_dir, f"checkpoint/ckpt_{policy_step}_{rank}.ckpt")
-            fabric.call(
-                "on_checkpoint_coupled",
-                ckpt_path=ckpt_path,
-                state=ckpt_state,
-                replay_buffer=rb if cfg.buffer.checkpoint else None,
-            )
-
-    if burst_mode:
-        # Flush the tail; grants that can never execute are abandoned.
-        params, opts, moments_state, _ = hp.finish()
-
-    envs.close()
-    # Zero-shot task test (reference: p2e_dv3_exploration.py:800-812)
-    if fabric.is_global_zero and cfg.algo.run_test:
-        player.actor_type = "task"
-        test_params = {"world_model": params["world_model"], "actor": params["actor_task"]}
-        test(player, test_params, fabric, cfg, log_dir, "zero-shot", greedy=False, writer=logger)
-
-    if not cfg.model_manager.disabled and fabric.is_global_zero:  # pragma: no cover - mlflow optional
-        from sheeprl_tpu.utils.mlflow import log_models, register_model
-
-        register_model(
+    def build(observation_space, actions_dim, is_continuous):
+        world_model, ens_module, actor, critic, critics_spec, params, player = build_agent(
             fabric,
-            log_models,
+            actions_dim,
+            is_continuous,
             cfg,
-            {
-                "world_model": params["world_model"],
-                "ensembles": params["ensembles"],
-                "actor_task": params["actor_task"],
-                "critic_task": params["critic_task"],
-                "target_critic_task": params["target_critic_task"],
-                "moments_task": moments_state["task"],
-                "actor_exploration": params["actor_exploration"],
-                "critics_exploration": params["critics_exploration"],
-                "moments_exploration": moments_state["exploration"],
-            },
+            observation_space,
+            *(state[k] if state is not None else None for k in model_keys),
         )
-    logger.close()
+
+        txs = {
+            "world": build_optimizer(cfg.algo.world_model.optimizer, max_grad_norm=cfg.algo.world_model.clip_gradients),
+            "actor_task": build_optimizer(cfg.algo.actor.optimizer, max_grad_norm=cfg.algo.actor.clip_gradients),
+            "critic_task": build_optimizer(cfg.algo.critic.optimizer, max_grad_norm=cfg.algo.critic.clip_gradients),
+            "actor_exploration": build_optimizer(cfg.algo.actor.optimizer, max_grad_norm=cfg.algo.actor.clip_gradients),
+            "ensembles": build_optimizer(cfg.algo.ensembles.optimizer, max_grad_norm=cfg.algo.ensembles.clip_gradients),
+            "critics_exploration": {
+                k: build_optimizer(cfg.algo.critic.optimizer, max_grad_norm=cfg.algo.critic.clip_gradients)
+                for k in critics_spec
+            },
+        }
+        opts = {
+            "world": txs["world"].init(params["world_model"]),
+            "actor_task": txs["actor_task"].init(params["actor_task"]),
+            "critic_task": txs["critic_task"].init(params["critic_task"]),
+            "actor_exploration": txs["actor_exploration"].init(params["actor_exploration"]),
+            "ensembles": txs["ensembles"].init(params["ensembles"]),
+            "critics_exploration": {
+                k: txs["critics_exploration"][k].init(params["critics_exploration"][k]["module"]) for k in critics_spec
+            },
+        }
+        if state is not None:
+            opts = jax.tree.map(lambda t, s: jnp.asarray(s) if hasattr(t, "dtype") else s, opts, state["optimizers"])
+        opts = fabric.put_replicated(opts)
+
+        moments_state = {"task": init_moments(), "exploration": {k: init_moments() for k in critics_spec}}
+        if state is not None:
+            moments_state = jax.tree.map(jnp.asarray, state["moments"])
+        moments_state = fabric.put_replicated(moments_state)
+
+        def zero_shot_test(p, log_dir, logger):
+            # Zero-shot task test (reference: p2e_dv3_exploration.py:800-812)
+            player.actor_type = "task"
+            test_params = {"world_model": p["world_model"], "actor": p["actor_task"]}
+            test(player, test_params, fabric, cfg, log_dir, "zero-shot", greedy=False, writer=logger)
+
+        return Family(
+            carry=(params, opts, moments_state),
+            make_train_step=lambda ring=None: make_train_step(
+                world_model, ens_module, actor, critic, critics_spec, cfg, fabric.mesh, actions_dim, is_continuous,
+                txs, ring=ring,
+            ),
+            player=player,
+            prepare_obs=prepare_obs,
+            # the env rollout uses the exploration actor
+            player_params=lambda p, trained: {"world_model": p["world_model"], "actor": p["actor_exploration"]},
+            models=models,
+            test=zero_shot_test,
+            registered_models=lambda p, moments: {
+                **models(p),
+                "moments_task": moments["task"],
+                "moments_exploration": moments["exploration"],
+            },
+            make_host_player=host_player_factory(world_model, actor, actions_dim, cfg, actor_type="exploration"),
+            player_subset=lambda p: player_snapshot(p["world_model"], p["actor_exploration"]),
+        )
+
+    run_loop(
+        fabric,
+        cfg,
+        build,
+        trainer=platform_trainer(fabric, cfg),
+        resume=state,
+        replay=state["rb"] if state is not None and cfg.buffer.checkpoint else None,
+    )

@@ -12,37 +12,25 @@ the env-level pinning happens in ``cli.run_algorithm``).
 
 from __future__ import annotations
 
-import os
 import pathlib
-import warnings
 from typing import Any, Dict
 
-import gymnasium as gym
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
-from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import make_train_step
+from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import build_optimizers, make_train_step
 from sheeprl_tpu.algos.dreamer_v3.utils import init_moments, prepare_obs, test
 from sheeprl_tpu.algos.p2e_dv3.agent import build_agent
-from sheeprl_tpu.data.buffers import EnvIndependentReplayBuffer, SequentialReplayBuffer
-from sheeprl_tpu.envs.factory import vectorize_env
-from sheeprl_tpu.utils.logger import get_log_dir, get_logger
-from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric, build_aggregator
+from sheeprl_tpu.algos.world_model_loop import Family, HostSampledTrainer, run as run_loop
+from sheeprl_tpu.utils.burst import DREAMER_METRIC_NAMES
 from sheeprl_tpu.utils.registry import register_algorithm
-from sheeprl_tpu.utils.timer import timer
-from sheeprl_tpu.utils.utils import Ratio, save_configs
 
 __all__ = ["main"]
 
 
 @register_algorithm()
 def main(fabric, cfg: Dict[str, Any], exploration_cfg: Dict[str, Any]):
-    from sheeprl_tpu.optim.builders import build_optimizer
     from sheeprl_tpu.utils.checkpoint import load_state
-
-    rank = fabric.global_rank
 
     ckpt_path = pathlib.Path(cfg.checkpoint.exploration_ckpt_path)
     resume_from_checkpoint = bool(cfg.checkpoint.resume_from)
@@ -65,342 +53,85 @@ def main(fabric, cfg: Dict[str, Any], exploration_cfg: Dict[str, Any]):
     cfg.algo.mlp_keys = exploration_cfg.algo.mlp_keys
     cfg.env.frame_stack = 1
 
-    log_dir = get_log_dir(cfg, cfg.root_dir, cfg.run_name)
-    logger = get_logger(cfg, log_dir, rank)
-    if fabric.is_global_zero:
-        logger.log_hyperparams(cfg)
-    print(f"Log dir: {log_dir}")
-
-
-    envs = vectorize_env(
-        cfg, cfg.seed, rank, log_dir if rank == 0 else None, prefix="train", restart_on_exception=True
-    )
-    action_space = envs.single_action_space
-    observation_space = envs.single_observation_space
-
-    is_continuous = isinstance(action_space, gym.spaces.Box)
-    is_multidiscrete = isinstance(action_space, gym.spaces.MultiDiscrete)
-    actions_dim = tuple(
-        action_space.shape if is_continuous else (action_space.nvec.tolist() if is_multidiscrete else [action_space.n])
-    )
-    clip_rewards_fn = (lambda r: np.tanh(r)) if cfg.env.clip_rewards else (lambda r: r)
-    if not isinstance(observation_space, gym.spaces.Dict):
-        raise RuntimeError(f"Unexpected observation type, should be of type Dict, got: {observation_space}")
-    obs_keys = cfg.algo.cnn_keys.encoder + cfg.algo.mlp_keys.encoder
-
-    world_model, _, actor, critic, _, p2e_params, player = build_agent(
-        fabric,
-        actions_dim,
-        is_continuous,
-        cfg,
-        observation_space,
-        state["world_model"],
-        state.get("ensembles"),
-        state["actor_task"],
-        state["critic_task"],
-        state["target_critic_task"],
-        state["actor_exploration"],
-        state.get("critics_exploration"),
-    )
-    # Dreamer-V3-shaped params for the shared train step; the exploration
-    # actor rides along for the pre-switch player.
-    params = {
-        "world_model": p2e_params["world_model"],
-        "actor": p2e_params["actor_task"],
-        "critic": p2e_params["critic_task"],
-        "target_critic": p2e_params["target_critic_task"],
-    }
-    actor_exploration_params = p2e_params["actor_exploration"]
-
-    txs = {
-        "world": build_optimizer(cfg.algo.world_model.optimizer, max_grad_norm=cfg.algo.world_model.clip_gradients),
-        "actor": build_optimizer(cfg.algo.actor.optimizer, max_grad_norm=cfg.algo.actor.clip_gradients),
-        "critic": build_optimizer(cfg.algo.critic.optimizer, max_grad_norm=cfg.algo.critic.clip_gradients),
-    }
-    opts = {
-        "world": txs["world"].init(params["world_model"]),
-        "actor": txs["actor"].init(params["actor"]),
-        "critic": txs["critic"].init(params["critic"]),
-    }
-    saved_opts = state.get("optimizers", {})
-    opt_key_map = {"world": "world", "actor": "actor_task", "critic": "critic_task"}
-    if resume_from_checkpoint:
-        opt_key_map = {"world": "world", "actor": "actor", "critic": "critic"}
-    for mine, theirs in opt_key_map.items():
-        if theirs in saved_opts:
-            opts[mine] = jax.tree.map(
-                lambda t, s: jnp.asarray(s) if hasattr(t, "dtype") else s, opts[mine], saved_opts[theirs]
-            )
-    opts = fabric.put_replicated(opts)
-
-    moments_state = init_moments()
-    saved_moments = state.get("moments")
-    if saved_moments is not None:
-        if not resume_from_checkpoint and "task" in saved_moments:
-            saved_moments = saved_moments["task"]
-        moments_state = jax.tree.map(jnp.asarray, saved_moments)
-    moments_state = fabric.put_replicated(moments_state)
-
-    if fabric.is_global_zero:
-        save_configs(cfg, log_dir)
-
-    aggregator = None
-    if not MetricAggregator.disabled:
-        aggregator = build_aggregator(cfg.metric.aggregator)
-
-    buffer_size = cfg.buffer.size // int(cfg.env.num_envs) if not cfg.dry_run else 4
-    rb = EnvIndependentReplayBuffer(
-        buffer_size,
-        n_envs=cfg.env.num_envs,
-        obs_keys=tuple(obs_keys),
-        memmap=cfg.buffer.memmap,
-        memmap_dir=os.path.join(log_dir, "memmap_buffer", f"rank_{rank}"),
-        buffer_cls=SequentialReplayBuffer,
-    )
-    if resume_from_checkpoint or (cfg.buffer.load_from_exploration and exploration_cfg.buffer.checkpoint):
-        if isinstance(state["rb"], list):
-            rb = state["rb"][0]
-        elif isinstance(state["rb"], EnvIndependentReplayBuffer):
-            rb = state["rb"]
-        else:
-            raise RuntimeError(f"Cannot restore the replay buffer from {type(state['rb'])}")
-
-    train_step = 0
-    last_train = 0
-    start_iter = state["iter_num"] + 1 if resume_from_checkpoint else 1
-    policy_step = state["iter_num"] * cfg.env.num_envs if resume_from_checkpoint else 0
-    last_log = state["last_log"] if resume_from_checkpoint else 0
-    last_checkpoint = state["last_checkpoint"] if resume_from_checkpoint else 0
-    policy_steps_per_iter = int(cfg.env.num_envs)
-    total_iters = int(cfg.algo.total_steps // policy_steps_per_iter) if not cfg.dry_run else 1
-    learning_starts = cfg.algo.learning_starts // policy_steps_per_iter if not cfg.dry_run else 0
-    prefill_steps = learning_starts - int(learning_starts > 0)
-    if resume_from_checkpoint:
-        cfg.algo.per_rank_batch_size = state["batch_size"]
-        learning_starts += start_iter
-        prefill_steps += start_iter
-
-    ratio = Ratio(cfg.algo.replay_ratio, pretrain_steps=cfg.algo.per_rank_pretrain_steps)
-    if resume_from_checkpoint:
-        ratio.load_state_dict(state["ratio"])
-
-    if cfg.checkpoint.every % policy_steps_per_iter != 0:
-        warnings.warn(
-            f"The checkpoint.every parameter ({cfg.checkpoint.every}) is not a multiple of the "
-            f"policy_steps_per_iter value ({policy_steps_per_iter})."
-        )
-
-    batch_size = int(cfg.algo.per_rank_batch_size)
-    seq_len = int(cfg.algo.per_rank_sequence_length)
-    if batch_size % fabric.world_size != 0:
-        raise ValueError(
-            f"per_rank_batch_size ({batch_size}) must be divisible by the number of devices ({fabric.world_size})"
-        )
-    train_fn = make_train_step(world_model, actor, critic, cfg, fabric.mesh, actions_dim, is_continuous, txs)
-    data_sharding = NamedSharding(fabric.mesh, P(None, None, "dp"))
-
-    rng = jax.random.PRNGKey(cfg.seed)
-    cnn_keys = cfg.algo.cnn_keys.encoder
-
-    player.actor_type = "exploration"
-
-    def player_params():
-        actor_p = params["actor"] if player.actor_type == "task" else actor_exploration_params
-        return {"world_model": params["world_model"], "actor": actor_p}
-
-    step_data: Dict[str, np.ndarray] = {}
-    obs = envs.reset(seed=cfg.seed)[0]
-    for k in obs_keys:
-        step_data[k] = np.asarray(obs[k])[np.newaxis]
-    step_data["rewards"] = np.zeros((1, cfg.env.num_envs, 1), dtype=np.float32)
-    step_data["truncated"] = np.zeros((1, cfg.env.num_envs, 1), dtype=np.float32)
-    step_data["terminated"] = np.zeros((1, cfg.env.num_envs, 1), dtype=np.float32)
-    step_data["is_first"] = np.ones_like(step_data["terminated"])
-    player.init_states(player_params())
-
-    cumulative_per_rank_gradient_steps = 0
-    for iter_num in range(start_iter, total_iters + 1):
-        policy_step += policy_steps_per_iter
-
-        with timer("Time/env_interaction_time", SumMetric):
-            jobs = prepare_obs(fabric, obs, cnn_keys=cnn_keys, num_envs=cfg.env.num_envs)
-            rng, subkey = jax.random.split(rng)
-            action_list = player.get_actions(player_params(), jobs, subkey)
-            actions = np.asarray(jnp.concatenate(action_list, axis=-1))
-            if is_continuous:
-                real_actions = actions
-            else:
-                real_actions = np.stack([np.asarray(a).argmax(axis=-1) for a in action_list], axis=-1)
-
-            step_data["actions"] = actions.reshape(1, cfg.env.num_envs, -1)
-            rb.add(step_data, validate_args=cfg.buffer.validate_args)
-
-            next_obs, rewards, terminated, truncated, infos = envs.step(
-                real_actions.reshape(envs.action_space.shape)
-            )
-            dones = np.logical_or(terminated, truncated).astype(np.uint8)
-
-        step_data["is_first"] = np.zeros_like(step_data["terminated"])
-        if "restart_on_exception" in infos:
-            for i, agent_roe in enumerate(infos["restart_on_exception"]):
-                if agent_roe and not dones[i]:
-                    sub_rb = rb.buffer[i]
-                    last_inserted_idx = (sub_rb._pos - 1) % sub_rb.buffer_size
-                    sub_rb["terminated"][last_inserted_idx] = np.zeros_like(sub_rb["terminated"][last_inserted_idx])
-                    sub_rb["truncated"][last_inserted_idx] = np.ones_like(sub_rb["truncated"][last_inserted_idx])
-                    sub_rb["is_first"][last_inserted_idx] = np.zeros_like(sub_rb["is_first"][last_inserted_idx])
-                    step_data["is_first"][0, i] = np.ones_like(step_data["is_first"][0, i])
-
-        if cfg.metric.log_level > 0 and "final_info" in infos:
-            ep_info = infos["final_info"]
-            if isinstance(ep_info, dict) and "episode" in ep_info:
-                mask = ep_info.get("_episode", np.ones_like(np.asarray(ep_info["episode"]["r"]), dtype=bool))
-                rews = np.asarray(ep_info["episode"]["r"])[mask]
-                lens = np.asarray(ep_info["episode"]["l"])[mask]
-                for i, (ep_rew, ep_len) in enumerate(zip(rews, lens)):
-                    if aggregator and "Rewards/rew_avg" in aggregator:
-                        aggregator.update("Rewards/rew_avg", ep_rew)
-                    if aggregator and "Game/ep_len_avg" in aggregator:
-                        aggregator.update("Game/ep_len_avg", ep_len)
-                    print(f"Rank-0: policy_step={policy_step}, reward_env_{i}={ep_rew}")
-
-        real_next_obs = {k: np.asarray(next_obs[k]).copy() for k in obs_keys}
-        if "final_obs" in infos:
-            for idx, final_obs in enumerate(infos["final_obs"]):
-                if final_obs is not None:
-                    for k in obs_keys:
-                        real_next_obs[k][idx] = np.asarray(final_obs[k])
-
-        for k in obs_keys:
-            step_data[k] = np.asarray(next_obs[k])[np.newaxis]
-        obs = next_obs
-
-        rewards = np.asarray(rewards, dtype=np.float32).reshape(1, cfg.env.num_envs, -1)
-        step_data["terminated"] = np.asarray(terminated, dtype=np.float32).reshape(1, cfg.env.num_envs, -1)
-        step_data["truncated"] = np.asarray(truncated, dtype=np.float32).reshape(1, cfg.env.num_envs, -1)
-        step_data["rewards"] = clip_rewards_fn(rewards)
-
-        dones_idxes = dones.nonzero()[0].tolist()
-        reset_envs = len(dones_idxes)
-        if reset_envs > 0:
-            reset_data = {}
-            for k in obs_keys:
-                reset_data[k] = (real_next_obs[k][dones_idxes])[np.newaxis]
-            reset_data["terminated"] = step_data["terminated"][:, dones_idxes]
-            reset_data["truncated"] = step_data["truncated"][:, dones_idxes]
-            reset_data["actions"] = np.zeros((1, reset_envs, int(np.sum(actions_dim))), dtype=np.float32)
-            reset_data["rewards"] = step_data["rewards"][:, dones_idxes]
-            reset_data["is_first"] = np.zeros_like(reset_data["terminated"])
-            rb.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
-
-            step_data["rewards"][:, dones_idxes] = np.zeros_like(reset_data["rewards"])
-            step_data["terminated"][:, dones_idxes] = np.zeros_like(step_data["terminated"][:, dones_idxes])
-            step_data["truncated"][:, dones_idxes] = np.zeros_like(step_data["truncated"][:, dones_idxes])
-            step_data["is_first"][:, dones_idxes] = np.ones_like(step_data["is_first"][:, dones_idxes])
-            player.init_states(player_params(), dones_idxes)
-
-        if iter_num >= learning_starts:
-            per_rank_gradient_steps = ratio(policy_step - prefill_steps * policy_steps_per_iter)
-            if per_rank_gradient_steps > 0:
-                # Switch the player to the task actor at the first granted
-                # gradient step (reference: p2e_dv3_finetuning.py:344-356)
-                if player.actor_type != "task":
-                    player.actor_type = "task"
-                sample = rb.sample(
-                    batch_size,
-                    sequence_length=seq_len,
-                    n_samples=per_rank_gradient_steps,
-                )
-                data = {
-                    k: jax.device_put(np.asarray(v, dtype=np.float32), data_sharding) for k, v in sample.items()
-                }
-                with timer("Time/train_time", SumMetric):
-                    rng, train_key = jax.random.split(rng)
-                    params, opts, moments_state, metrics = train_fn(
-                        params, opts, moments_state, data, train_key,
-                        jnp.int32(cumulative_per_rank_gradient_steps),
-                    )
-                    if aggregator and not aggregator.disabled:
-                        names = (
-                            "Loss/world_model_loss", "Loss/observation_loss", "Loss/reward_loss",
-                            "Loss/state_loss", "Loss/continue_loss", "State/kl", "State/post_entropy",
-                            "State/prior_entropy", "Loss/policy_loss", "Loss/value_loss",
-                        )
-                        for name, value in zip(names, metrics):
-                            if name in aggregator:
-                                aggregator.update(name, value)
-                cumulative_per_rank_gradient_steps += per_rank_gradient_steps
-                train_step += 1
-
-        if cfg.metric.log_level > 0 and (policy_step - last_log >= cfg.metric.log_every or iter_num == total_iters):
-            if aggregator and not aggregator.disabled:
-                logger.log_dict(aggregator.compute(), policy_step)
-                aggregator.reset()
-            if not timer.disabled:
-                timer_metrics = timer.compute()
-                if timer_metrics.get("Time/train_time", 0) > 0:
-                    logger.log_dict(
-                        {"Time/sps_train": (train_step - last_train) / timer_metrics["Time/train_time"]},
-                        policy_step,
-                    )
-                if timer_metrics.get("Time/env_interaction_time", 0) > 0:
-                    logger.log_dict(
-                        {
-                            "Time/sps_env_interaction": (
-                                (policy_step - last_log) * cfg.env.action_repeat
-                            )
-                            / timer_metrics["Time/env_interaction_time"]
-                        },
-                        policy_step,
-                    )
-                timer.reset()
-            last_log = policy_step
-            last_train = train_step
-
-        if (cfg.checkpoint.every > 0 and policy_step - last_checkpoint >= cfg.checkpoint.every) or (
-            iter_num == total_iters and cfg.checkpoint.save_last
-        ):
-            last_checkpoint = policy_step
-            ckpt_state = {
-                "world_model": params["world_model"],
-                "actor_task": params["actor"],
-                "critic_task": params["critic"],
-                "target_critic_task": params["target_critic"],
-                "actor_exploration": actor_exploration_params,
-                "optimizers": opts,
-                "moments": moments_state,
-                "ratio": ratio.state_dict(),
-                "iter_num": iter_num,
-                "batch_size": batch_size,
-                "last_log": last_log,
-                "last_checkpoint": last_checkpoint,
-            }
-            ckpt_path_out = os.path.join(log_dir, f"checkpoint/ckpt_{policy_step}_{rank}.ckpt")
-            fabric.call(
-                "on_checkpoint_coupled",
-                ckpt_path=ckpt_path_out,
-                state=ckpt_state,
-                replay_buffer=rb if cfg.buffer.checkpoint else None,
-            )
-
-    envs.close()
-    if fabric.is_global_zero and cfg.algo.run_test:
-        player.actor_type = "task"
-        test(player, player_params(), fabric, cfg, log_dir, greedy=False, writer=logger)
-
-    if not cfg.model_manager.disabled and fabric.is_global_zero:  # pragma: no cover - mlflow optional
-        from sheeprl_tpu.utils.mlflow import log_models, register_model
-
-        register_model(
+    def build(observation_space, actions_dim, is_continuous):
+        world_model, _, actor, critic, _, p2e_params, player = build_agent(
             fabric,
-            log_models,
+            actions_dim,
+            is_continuous,
             cfg,
-            {
-                "world_model": params["world_model"],
-                "actor_task": params["actor"],
-                "critic_task": params["critic"],
-                "target_critic_task": params["target_critic"],
-                "moments_task": moments_state,
-            },
+            observation_space,
+            state["world_model"],
+            state.get("ensembles"),
+            state["actor_task"],
+            state["critic_task"],
+            state["target_critic_task"],
+            state["actor_exploration"],
+            state.get("critics_exploration"),
         )
-    logger.close()
+        # Dreamer-V3-shaped params for the shared train step; the exploration
+        # actor rides along for the pre-switch player.
+        params = {
+            "world_model": p2e_params["world_model"],
+            "actor": p2e_params["actor_task"],
+            "critic": p2e_params["critic_task"],
+            "target_critic": p2e_params["target_critic_task"],
+        }
+        actor_exploration_params = p2e_params["actor_exploration"]
+
+        saved_opts = state.get("optimizers", {})
+        opt_key_map = {"world": "world", "actor": "actor_task", "critic": "critic_task"}
+        if resume_from_checkpoint:
+            opt_key_map = {"world": "world", "actor": "actor", "critic": "critic"}
+        txs, opts = build_optimizers(
+            cfg, fabric, params, {mine: saved_opts[theirs] for mine, theirs in opt_key_map.items() if theirs in saved_opts}
+        )
+
+        moments_state = init_moments()
+        saved_moments = state.get("moments")
+        if saved_moments is not None:
+            if not resume_from_checkpoint and "task" in saved_moments:
+                saved_moments = saved_moments["task"]
+            moments_state = jax.tree.map(jnp.asarray, saved_moments)
+        moments_state = fabric.put_replicated(moments_state)
+
+        def player_params(p, trained):
+            # The player starts with the exploration actor and switches to the
+            # task actor at the first granted gradient step
+            # (reference: p2e_dv3_finetuning.py:344-356)
+            player.actor_type = "task" if trained else "exploration"
+            return {"world_model": p["world_model"], "actor": p["actor"] if trained else actor_exploration_params}
+
+        def models(p):
+            return {
+                "world_model": p["world_model"],
+                "actor_task": p["actor"],
+                "critic_task": p["critic"],
+                "target_critic_task": p["target_critic"],
+            }
+
+        return Family(
+            carry=(params, opts, moments_state),
+            make_train_step=lambda: make_train_step(
+                world_model, actor, critic, cfg, fabric.mesh, actions_dim, is_continuous, txs
+            ),
+            player=player,
+            prepare_obs=prepare_obs,
+            player_params=player_params,
+            models=lambda p: {**models(p), "actor_exploration": actor_exploration_params},
+            test=lambda p, log_dir, logger: test(
+                player, player_params(p, True), fabric, cfg, log_dir, greedy=False, writer=logger
+            ),
+            registered_models=lambda p, moments: {**models(p), "moments_task": moments},
+            metric_names=DREAMER_METRIC_NAMES,
+        )
+
+    load_replay = resume_from_checkpoint or (cfg.buffer.load_from_exploration and exploration_cfg.buffer.checkpoint)
+    run_loop(
+        fabric,
+        cfg,
+        build,
+        trainer=HostSampledTrainer,  # the burst topology for this main is a named debt (ROADMAP D1)
+        resume=state if resume_from_checkpoint else None,
+        replay=state["rb"] if load_replay else None,
+        random_prefill=False,  # the loaded exploration actor acts from the first step
+    )

@@ -11,8 +11,8 @@ IN-GRAPH so sample+train is a single dispatch per env step.
 - :mod:`~sheeprl_tpu.replay.sumtree` — in-graph sum-tree for PER;
 - :mod:`~sheeprl_tpu.replay.device_buffer` — :class:`DeviceReplayBuffer`
   (scalar-head uniform/PER ring, SAC-shaped) + spillover sizing;
-- :mod:`~sheeprl_tpu.replay.driver` — :class:`SequenceRingDriver`
-  (per-env-head sequence ring, Dreamer-shaped).
+- :mod:`~sheeprl_tpu.replay.driver` — :class:`AsyncSequenceRing`
+  (per-env-head sequence ring of the decoupled Dreamer tier).
 
 See ``howto/device_replay.md`` for when to use the device tier vs the host
 memmap spillover tier, and the HBM sizing math.
@@ -26,14 +26,13 @@ from sheeprl_tpu.replay.device_buffer import (
     restore_host_buffer,
     restore_host_env_buffer,
 )
-from sheeprl_tpu.replay.driver import AsyncSequenceRing, SeqBlobWriter, SequenceRingDriver
+from sheeprl_tpu.replay.driver import AsyncSequenceRing, SeqBlobWriter
 
 __all__ = [
     "AsyncSequenceRing",
     "DeviceReplayBuffer",
     "DeviceReplayState",
     "SeqBlobWriter",
-    "SequenceRingDriver",
     "estimate_ring_bytes",
     "resolve_device_resident",
     "restore_host_buffer",

@@ -1,25 +1,15 @@
-"""Synchronous device-resident sequence replay for the Dreamer-family
-coupled mains.
-
-The hybrid burst path (``utils/burst.py``) already keeps a device sequence
-ring — but it is welded to the host-CPU player and a trainer thread. This
-driver provides the same ring (reusing ``data/ring.py``'s jitted burst
-program, per-env write heads, window-validity sampling, and packed-blob
-uploads) for the **standard coupled topology**: the device player stays, and
-every env step dispatches exactly ONE program that appends the staged
-transitions and runs the granted gradient steps with windows sampled
-in-graph. No per-step host sampling, no per-gradient-step batch upload.
-
-The caller (the algo main) keeps ownership of the training carry
-(params/opts/...), grant accounting feed (``Ratio``), and logging; the driver
-owns the ring handle, staging, the packed train-key stream, grant backlog
-mechanics, and the checkpointable ring state.
+"""The decoupled (Sebulba) device sequence ring of the Dreamer family:
+:class:`AsyncSequenceRing` (storage, per-env write heads and the train-key
+stream on the device; actors pack ragged blobs, the one learner commits them)
+and :class:`SeqBlobWriter` (the actor's zero-copy blob writer). The coupled
+mains keep their device ring behind ``utils/burst.py`` (the burst topology of
+``algos/world_model_loop.py``).
 """
 
 from __future__ import annotations
 
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -27,18 +17,13 @@ import numpy as np
 from sheeprl_tpu.data.ring import (
     build_seq_append_step,
     env_view,
-    make_blob_layouts,
     pack_burst_blob,
     ring_view,
 )
 from sheeprl_tpu.replay.device_buffer import DeviceReplayState
 from sheeprl_tpu.utils.burst import init_device_ring
-from sheeprl_tpu.utils.utils import host_cpu_device
 
-__all__ = ["AsyncSequenceRing", "SeqBlobWriter", "SequenceRingDriver"]
-
-# One env step stages at most one all-envs row plus one ragged reset row.
-_STAGE_MAX = 2
+__all__ = ["AsyncSequenceRing", "SeqBlobWriter"]
 
 
 def _storage_to_host(storage, ring_keys) -> Dict[str, np.ndarray]:
@@ -58,207 +43,10 @@ def _storage_from_host(fabric, snap: DeviceReplayState, ring_keys) -> Dict[str, 
     }
 
 
-class SequenceRingDriver:
-    """Owns a per-env-head device sequence ring and dispatches the fused
-    append+sample+train program synchronously, once per env step.
-
-    ``make_burst_fn(ring_spec)`` must return the jitted packed burst program
-    (the Dreamer mains pass ``make_train_step(..., ring=ring_spec)``, which
-    routes through :func:`sheeprl_tpu.data.ring.build_burst_train_step`).
-    """
-
-    def __init__(
-        self,
-        fabric,
-        ring_keys: Dict[str, Tuple[tuple, Any]],
-        capacity: int,
-        n_envs: int,
-        seq_len: int,
-        batch_size: int,
-        grad_chunk: int,
-        make_burst_fn: Callable[[Dict[str, Any]], Callable],
-        seed: int = 0,
-        restore: Optional[Any] = None,
-        trace_name: Optional[str] = None,
-    ) -> None:
-        self.fabric = fabric
-        self.ring_keys = {k: (tuple(shape), jax.numpy.dtype(dtype)) for k, (shape, dtype) in ring_keys.items()}
-        self.capacity = int(capacity)
-        self.n_envs = int(n_envs)
-        self.seq_len = int(seq_len)
-        self.grad_chunk = int(grad_chunk)
-        buckets = (1, _STAGE_MAX)
-        self._burst_fn = make_burst_fn(
-            {
-                "capacity": self.capacity,
-                "n_envs": self.n_envs,
-                "grad_chunk": self.grad_chunk,
-                "seq_len": self.seq_len,
-                "batch_size": int(batch_size),
-                "ring_keys": self.ring_keys,
-                "stage_buckets": buckets,
-                "stage_max": _STAGE_MAX,
-            }
-        )
-        if trace_name is not None:
-            # one compile per flush bucket (the two blob lengths) is the
-            # expected signature set; anything past it is a real retrace
-            from sheeprl_tpu.analysis.tracecheck import tracecheck
-
-            self._burst_fn = tracecheck.instrument(
-                self._burst_fn, name=trace_name, warmup=len(buckets)
-            )
-        self._layouts = make_blob_layouts(self.ring_keys, self.n_envs, self.grad_chunk, buckets)
-
-        host_rb = restore if not isinstance(restore, DeviceReplayState) else None
-        self.rb_dev, pos, valid = init_device_ring(
-            fabric, self.ring_keys, self.capacity, self.n_envs, rb=host_rb
-        )
-        self.dev_pos = np.asarray(pos, np.int64)
-        self.dev_valid = np.asarray(valid, np.int64)
-        # Packed flushes read the key bytes on the host; a device-resident
-        # key would cost one device pull per env step (threefry is platform-
-        # deterministic, so the stream is unchanged).
-        self._host_device = host_cpu_device()
-        self._key = jax.device_put(jax.random.PRNGKey(seed), self._host_device)
-        if isinstance(restore, DeviceReplayState):
-            self.load_state_dict(restore)
-
-        self._staged: List[Tuple[Dict[str, np.ndarray], np.ndarray]] = []
-        self.grant_backlog = 0
-        self.gradient_steps = 0
-        self.train_steps = 0
-        self._metrics = {"flushes": 0, "bytes_staged": 0, "insert_latency_s": 0.0, "dispatch_latency_s": 0.0}
-
-    # -- staging (mirrors utils/burst.BurstRunner) ---------------------------
-    def stage_step(self, step_data: Dict[str, np.ndarray]) -> None:
-        """Stage a regular all-envs row from ``(1, n_envs, ...)`` step data."""
-        row = {k: np.asarray(step_data[k][0]) for k in self.ring_keys}
-        self._staged.append((row, np.ones(self.n_envs, np.int32)))
-
-    def stage_reset(self, reset_data: Dict[str, np.ndarray], env_idxes) -> None:
-        """Stage a ragged reset row: only the done envs advance their heads
-        (mirrors ``EnvIndependentReplayBuffer.add(data, env_idxes)``)."""
-        mask = np.zeros(self.n_envs, np.int32)
-        mask[env_idxes] = 1
-        row = {}
-        for k, (shape, dtype) in self.ring_keys.items():
-            full_row = np.zeros((self.n_envs,) + shape, dtype)
-            full_row[env_idxes] = np.asarray(reset_data[k][0])
-            row[k] = full_row
-        self._staged.append((row, mask))
-
-    def patch_last(self, env_idx: int, updates: Dict[str, float]) -> None:
-        """In-place edit of the newest staged row for one env (the
-        truncation patch on env restart)."""
-        if self._staged:
-            for k, v in updates.items():
-                self._staged[-1][0][k][env_idx] = v
-
-    # -- grants + dispatch ---------------------------------------------------
-    def grant(self, n: int) -> None:
-        self.grant_backlog += int(n)
-
-    def _flush(self, carry: Any) -> Tuple[Any, int, Any]:
-        t0 = time.perf_counter()
-        n_rows = len(self._staged)
-        size = next(b for b in sorted(self._layouts) if b >= max(n_rows, 1))
-        arrs = {}
-        for k, (shape, dtype) in self.ring_keys.items():
-            arr = np.zeros((size, self.n_envs) + shape, dtype)
-            for i, (row, _m) in enumerate(self._staged):
-                arr[i] = row[k]
-            arrs[k] = arr
-        mask = np.zeros((size, self.n_envs), np.int32)
-        for i, (_r, m) in enumerate(self._staged):
-            mask[i] = m
-        self._staged.clear()
-        env_counts = mask.sum(axis=0)
-        # Hold grants while any env is shorter than a sample window (the
-        # host buffer refuses to sample in that state).
-        ready = (self.dev_valid + env_counts).min() >= self.seq_len
-        chunk = min(self.grad_chunk, self.grant_backlog) if ready else 0
-        validmask = np.zeros((self.grad_chunk,), np.float32)
-        validmask[:chunk] = 1.0
-        self._key, train_key = jax.random.split(self._key)
-        values = dict(arrs)
-        values["__mask__"] = mask
-        values["__pos__"] = self.dev_pos
-        values["__valid_n__"] = self.dev_valid
-        values["__key__"] = np.asarray(train_key, np.uint32)
-        values["__validmask__"] = validmask
-        blob = pack_burst_blob(self._layouts[size], values)
-        self._metrics["insert_latency_s"] += time.perf_counter() - t0
-
-        t1 = time.perf_counter()
-        carry, self.rb_dev, metrics = self._burst_fn(carry, self.rb_dev, blob)
-        self._metrics["dispatch_latency_s"] += time.perf_counter() - t1
-
-        self.dev_pos[:] = (self.dev_pos + env_counts) % self.capacity
-        self.dev_valid[:] = np.minimum(self.dev_valid + env_counts, self.capacity)
-        self.grant_backlog -= chunk
-        self._metrics["flushes"] += 1
-        self._metrics["bytes_staged"] += int(blob.nbytes)
-        if chunk > 0:
-            self.gradient_steps += chunk
-            self.train_steps += 1
-        return carry, chunk, (metrics if chunk > 0 else None)
-
-    def pump(self, carry: Any) -> Tuple[Any, Any]:
-        """One per-env-step dispatch (append + up to ``grad_chunk`` granted
-        steps), plus append-free drains while a full chunk of backlog
-        remains. Returns ``(carry, last trained metrics or None)``."""
-        carry, chunk, metrics = self._flush(carry)
-        while self.grant_backlog >= self.grad_chunk:
-            carry, chunk, m = self._flush(carry)
-            if m is not None:
-                metrics = m
-            if chunk == 0:
-                break
-        return carry, metrics
-
-    # -- metrics + checkpoint ------------------------------------------------
-    def metrics(self) -> Dict[str, float]:
-        return {
-            "Replay/occupancy": float(self.dev_valid.sum()) / (self.capacity * self.n_envs),
-            "Replay/size": int(self.dev_valid.sum()),
-            "Replay/flushes": self._metrics["flushes"],
-            "Replay/bytes_staged": self._metrics["bytes_staged"],
-            "Replay/insert_latency_s": round(self._metrics["insert_latency_s"], 4),
-            "Replay/dispatch_latency_s": round(self._metrics["dispatch_latency_s"], 4),
-        }
-
-    def state_dict(self) -> DeviceReplayState:
-        if self._staged:
-            raise RuntimeError("checkpointing with staged-but-unflushed rows would drop them")
-        arrays = _storage_to_host(self.rb_dev, self.ring_keys)
-        arrays["pos"] = self.dev_pos.copy()
-        arrays["valid"] = self.dev_valid.copy()
-        arrays["key"] = np.asarray(self._key)
-        meta = {"capacity": self.capacity, "n_envs": self.n_envs, "seq_len": self.seq_len}
-        return DeviceReplayState("sequence", arrays, meta)
-
-    def load_state_dict(self, snap: DeviceReplayState) -> "SequenceRingDriver":
-        if snap.kind != "sequence":
-            raise ValueError(f"cannot restore a '{snap.kind}' replay snapshot into SequenceRingDriver")
-        if snap.meta["capacity"] != self.capacity or snap.meta["n_envs"] != self.n_envs:
-            raise ValueError(
-                f"replay snapshot shape mismatch: checkpoint ({snap.meta['capacity']}, "
-                f"{snap.meta['n_envs']}) vs configured ({self.capacity}, {self.n_envs})"
-            )
-        self.rb_dev = _storage_from_host(self.fabric, snap, self.ring_keys)
-        self.dev_pos = np.asarray(snap.arrays["pos"], np.int64).copy()
-        self.dev_valid = np.asarray(snap.arrays["valid"], np.int64).copy()
-        self._key = jax.device_put(snap.arrays["key"], self._host_device)
-        return self
-
-
 class AsyncSequenceRing:
     """Decoupled (Sebulba) per-env-head sequence ring for the Dreamer family.
 
-    Unlike :class:`SequenceRingDriver` (synchronous: one fused
-    append+sample+train dispatch per env step from the main thread), this
-    ring serves CONCURRENT actor threads: the storage, the per-env write
+    This ring serves CONCURRENT actor threads: the storage, the per-env write
     heads, and the train-key stream all live ON DEVICE in :attr:`state`;
     actors :meth:`pack_rows` their per-env sequence heads into ragged uint8
     blobs (a pure function — nothing on ``self`` is touched, so N writers

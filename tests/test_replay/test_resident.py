@@ -1,6 +1,7 @@
 """End-to-end device-resident replay runs through the real CLI: SAC dry runs
 (uniform + PER, 1/2 devices, env-sharded), checkpoint → resume round trips,
-and the DreamerV3 resident path (auto-marked slow by conftest)."""
+(the coupled Dreamer mains keep their replay on the device through the burst
+topology: ``tests/test_algos/test_wm_loop.py``)."""
 
 import glob
 import os
@@ -106,64 +107,3 @@ def test_sac_spillover_falls_back_to_host(tmp_path):
     )
     args[args.index("buffer.device_resident=true")] = "buffer.device_resident=auto"
     run(args)
-
-
-DREAMER_RESIDENT = [
-    "exp=dreamer_v3",
-    "env=dummy",
-    "env.num_envs=2",
-    "env.sync_env=True",
-    "env.capture_video=False",
-    "buffer.memmap=False",
-    "buffer.size=32",
-    "buffer.device_resident=true",
-    "fabric.devices=1",
-    "metric.log_level=0",
-    "algo.run_test=False",
-    "algo=dreamer_v3_XS",
-    "algo.per_rank_batch_size=2",
-    "algo.per_rank_sequence_length=2",
-    "algo.horizon=4",
-    "algo.dense_units=8",
-    "algo.mlp_layers=1",
-    "algo.world_model.encoder.cnn_channels_multiplier=2",
-    "algo.world_model.recurrent_model.recurrent_state_size=16",
-    "algo.world_model.representation_model.hidden_size=8",
-    "algo.world_model.transition_model.hidden_size=8",
-    "algo.world_model.discrete_size=4",
-    "algo.world_model.stochastic_size=4",
-    "algo.world_model.reward_model.bins=17",
-    "algo.critic.bins=17",
-    "algo.cnn_keys.encoder=[rgb]",
-    "algo.mlp_keys.encoder=[state]",
-    "env.screen_size=64",
-    "algo.learning_starts=4",
-]
-
-
-def test_dreamer_v3_resident_checkpoint_resume(tmp_path):
-    """Resident sequence ring (per-env heads, uint8 pixels) end-to-end:
-    train, checkpoint, resume. Slow lane (conftest auto-marks dreamer)."""
-    run(
-        DREAMER_RESIDENT
-        + [
-            f"log_root={tmp_path}/logs",
-            "algo.total_steps=16",
-            "checkpoint.every=8",
-            "checkpoint.save_last=True",
-        ]
-    )
-    ckpts = sorted(
-        glob.glob(f"{tmp_path}/logs/**/*.ckpt", recursive=True), key=os.path.getmtime
-    )
-    assert ckpts
-    run(
-        DREAMER_RESIDENT
-        + [
-            f"log_root={tmp_path}/logs",
-            "algo.total_steps=24",
-            "checkpoint.every=0",
-            "checkpoint.save_last=False",
-            f"checkpoint.resume_from={ckpts[-1]}",
-        ]
-    )
