@@ -9,7 +9,8 @@ imports JAX):
 
 1. ``kernels``   — this file again, in-process: names the device, then runs
    every registered kernel's Pallas variant compiled by Mosaic (never the
-   interpreter) at the call-site shapes of the two runs below and at the
+   interpreter; the entries of ``COMPILED_BY_XLA`` as XLA compiles them) at
+   the call-site shapes of the two runs below and at the
    shapes of ``sheeprl_tpu/ops/kernels/audit.py``, against its lax reference
    (integer outputs exactly); kernels the registry routes to lax on TPU by
    name are checked to be routed. With ``--devices N > 1`` also checks that
@@ -388,7 +389,8 @@ def check_kernels(devices: int) -> dict:
                       f"kernel {name} [{label}]: the lax reference gave a non-finite output")
             report[name] = {"tier": "lax", "routed_by_name": K.AUTO_LAX_ON_TPU[name]}
             continue
-        check(tier == "pallas", f"kernel {name} resolves to {tier} on a TPU, not to its Pallas variant")
+        by_xla = name in K.COMPILED_BY_XLA  # the kernel tier's entry is plain jax.numpy: no Mosaic call to find
+        check(tier == ("xla" if by_xla else "pallas"), f"kernel {name} resolves to {tier} on a TPU, not to its kernel-tier entry")
         kernel = K.get(name)
         worst: dict = {}  # max |got - want| / (1 + |want|) per output dtype
         for args, label in cases[name]:
@@ -396,8 +398,8 @@ def check_kernels(devices: int) -> dict:
             statics = tuple(a for a in args if not isinstance(a, jax.Array))
             pallas = jax.jit(lambda *xs, _f=kernel.pallas, _s=statics: _f(*xs, *_s))
             reference = jax.jit(lambda *xs, _f=kernel.reference, _s=statics: _f(*xs, *_s))
-            check("tpu_custom_call" in pallas.lower(*arrays).as_text(),
-                  f"kernel {name} [{label}]: the TPU lowering holds no Mosaic custom call")
+            check(("tpu_custom_call" in pallas.lower(*arrays).as_text()) != by_xla,
+                  f"kernel {name} [{label}]: the TPU lowering holds {'a' if by_xla else 'no'} Mosaic custom call")
             for g, w in zip(jax.tree.leaves(pallas(*arrays)), jax.tree.leaves(reference(*arrays))):
                 check(g.shape == w.shape and g.dtype == w.dtype, f"kernel {name} [{label}]: {g.shape} {g.dtype} vs "
                       f"reference {w.shape} {w.dtype}")
@@ -413,7 +415,7 @@ def check_kernels(devices: int) -> dict:
                 worst[g.dtype.name] = max(worst.get(g.dtype.name, 0.0), err)
                 print(f"kernel {name} [{label}]: scaled error {err:.3g}", flush=True)
             print(f"kernel {name} [{label}]: ok", flush=True)
-        report[name] = {"tier": "pallas", "cases": len(cases[name]), "max_scaled_error": worst}
+        report[name] = {"tier": tier, "cases": len(cases[name]), "max_scaled_error": worst}
     return report
 
 

@@ -9,6 +9,7 @@ package registers every kernel.
 
 from sheeprl_tpu.ops.kernels.registry import (
     AUTO_LAX_ON_TPU,
+    COMPILED_BY_XLA,
     Kernel,
     UnknownKernelError,
     UnknownOpsBackendError,
@@ -38,6 +39,7 @@ from sheeprl_tpu.ops.kernels.scatter import ragged_ring_scatter, ragged_ring_sca
 
 __all__ = [
     "AUTO_LAX_ON_TPU",
+    "COMPILED_BY_XLA",
     "Kernel",
     "UnknownKernelError",
     "UnknownOpsBackendError",

@@ -6,7 +6,9 @@ Every kernel in :mod:`sheeprl_tpu.ops.kernels` ships as a triple:
   call site ran before the kernel existed, so ``ops.backend=lax`` reproduces
   the historical graphs bit-for-bit;
 - a **Pallas kernel** wrapped in ``jax.custom_vjp`` (Pallas forward, the
-  reference chain re-derived on the backward);
+  reference chain re-derived on the backward), or, for the names in
+  :data:`COMPILED_BY_XLA`, the same fused form in plain ``jax.numpy`` where
+  that measured faster on the chip than a Mosaic body;
 - a **registry entry** binding the two under one name.
 
 Call sites go through :func:`dispatch`, which picks the implementation from
@@ -43,6 +45,7 @@ from sheeprl_tpu.utils.profiler import KERNEL_PREFIX
 
 __all__ = [
     "AUTO_LAX_ON_TPU",
+    "COMPILED_BY_XLA",
     "Kernel",
     "UnknownKernelError",
     "UnknownOpsBackendError",
@@ -73,6 +76,20 @@ AUTO_LAX_ON_TPU: Dict[str, str] = {
         "indices and output' for the (1, 2P) tree against (1, B) draws; with equal "
         "shapes Mosaic stops at 'Not implemented: Multiple source vregs along gather "
         "dimension' (tpu.dynamic_gather reaches 128 lanes or 8 sublanes, not a tree)"
+    ),
+}
+
+# Kernels whose kernel-tier entry is plain ``jax.numpy`` on every platform,
+# with the measurement that decided it: the same body as a Mosaic kernel was
+# slower on the chip and was deleted. ``auto`` still resolves them to the
+# kernel tier on a TPU (the lax reference stays the literal extraction the
+# parity tests compare against); :func:`tier` names them ``xla``, and the
+# checks that every other entry lowers to a Mosaic custom call pass them by.
+COMPILED_BY_XLA: Dict[str, str] = {
+    "two_hot_symlog_loss": (
+        "PERF.md finding 31, TPU v5e: one hat-function contraction fused by XLA, the critic's two "
+        "losses in one read of their logits, 0.029 ms a gradient step against 0.064 ms as a Mosaic "
+        "kernel (block 1024 rows); train_step_ms 18.116 against 18.237"
     ),
 }
 
@@ -217,10 +234,13 @@ def resolve(name: str, backend: Optional[str] = None) -> str:
 
 def tier(name: str) -> str:
     """What kernel ``name`` lowers to in this process: ``lax``, ``pallas``
-    (Mosaic on TPU lowerings, the lax reference on host-CPU lowerings) or
-    ``pallas-interpret`` (explicit Pallas opt-in without a TPU)."""
+    (Mosaic on TPU lowerings, the lax reference on host-CPU lowerings),
+    ``pallas-interpret`` (explicit Pallas opt-in without a TPU) or ``xla``
+    (a kernel-tier entry of :data:`COMPILED_BY_XLA`, with or without a TPU)."""
     if resolve(name) == "lax":
         return "lax"
+    if name in COMPILED_BY_XLA:
+        return "xla"
     return "pallas" if _process_has_tpu() else "pallas-interpret"
 
 

@@ -32,6 +32,41 @@ def _norm_logits(rng, shape, dtype=np.float32):
     return logits - jax.scipy.special.logsumexp(logits, axis=-1, keepdims=True)
 
 
+def _symlog_preimage(b):
+    """A float32 whose symlog is EXACTLY ``b`` (searched around symexp(b)),
+    or None where symlog steps over ``b`` there."""
+    from sheeprl_tpu.ops.core import symexp, symlog
+
+    near = np.float32(symexp(jnp.float32(b)))
+    candidates = [near]
+    for toward in (np.float32(np.inf), np.float32(-np.inf)):
+        v = near
+        for _ in range(64):
+            v = np.nextafter(v, toward)
+            candidates.append(v)
+    candidates = np.asarray(candidates, np.float32)
+    hits = candidates[np.asarray(symlog(jnp.asarray(candidates))) == np.float32(b)]
+    return float(hits[0]) if hits.size else None
+
+
+def _two_hot_values(rng, lead, num_buckets, kind, low=-20.0, high=20.0):
+    """Targets ``lead + (1,)`` for the two-hot loss. ``random``: between bins.
+    ``edges``: besides, values that symlog puts exactly ON a bin of the
+    reference's support (``low`` and ``high`` among them), zero, and values
+    beyond the support on both sides."""
+    n = int(np.prod(lead))
+    values = rng.normal(size=(n,)).astype(np.float32) * 4
+    if kind == "edges":
+        bins = np.asarray(jnp.linspace(low, high, num_buckets, dtype=jnp.float32))
+        picks = sorted({0, 1, num_buckets // 3, num_buckets // 2, 2 * num_buckets // 3, num_buckets - 2, num_buckets - 1})
+        on_a_bin = [v for v in (_symlog_preimage(bins[k]) for k in picks) if v is not None]
+        assert len(on_a_bin) >= 5 and on_a_bin[0] < -4e8 and on_a_bin[-1] > 4e8  # low and high themselves
+        edges = on_a_bin + [0.0, 1e9, -1e9, 6e8, -6e8]  # symexp(20) is 4.85e8
+        assert n > len(edges)
+        values[: len(edges)] = edges
+    return jnp.asarray(values).reshape(lead + (1,))
+
+
 def _gae_inputs(rng, T=16, B=6, trailing=(1,), dtype=np.float32):
     shape = (T, B) + trailing
     r = jnp.asarray(rng.normal(size=shape).astype(dtype))
@@ -172,12 +207,25 @@ def test_gru_gates_grad_parity():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
 
 
+# the loss in every shape it has to serve: K odd and even, few rows and a
+# count that is no multiple of 8 or 128, targets between bins and on every
+# edge of the two-hot
+_TWO_HOT_LOSS_CASES = [
+    pytest.param((6, 255), "random", id="flat"),
+    pytest.param((3, 4, 63), "random", id="batched"),
+    pytest.param((7, 41), "random", id="41-buckets"),
+    pytest.param((24, 255), "edges", id="edges"),
+    pytest.param((4, 6, 64), "edges", id="edges-even-buckets"),
+    pytest.param((300, 255), "edges", id="edges-300-rows"),
+]
+
+
 @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16], ids=["f32", "bf16"])
-@pytest.mark.parametrize("shape", [(6, 255), (3, 4, 63)], ids=["flat", "batched"])
-def test_two_hot_symlog_loss_parity(dtype, shape):
+@pytest.mark.parametrize("shape,values", _TWO_HOT_LOSS_CASES)
+def test_two_hot_symlog_loss_parity(dtype, shape, values):
     rng = _rng(3)
     logits = _norm_logits(rng, shape).astype(dtype)
-    value = jnp.asarray(rng.normal(size=shape[:-1] + (1,)).astype(np.float32), dtype=dtype) * 4
+    value = _two_hot_values(rng, shape[:-1], shape[-1], values).astype(dtype)
     got = K.two_hot_symlog_loss(logits, value, backend="pallas")
     want = K.two_hot_symlog_loss_reference(logits, value)
     assert got.shape == want.shape and got.dtype == want.dtype
@@ -193,13 +241,18 @@ def test_two_hot_symlog_loss_parity(dtype, shape):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
-def test_two_hot_symlog_loss_grad_parity():
+@pytest.mark.parametrize("shape,values", _TWO_HOT_LOSS_CASES)
+def test_two_hot_symlog_loss_grad_parity(shape, values):
+    # the backward is a closed form of the hat, not the reference's chain:
+    # both arguments, a cotangent that differs by row
     rng = _rng(4)
-    logits = _norm_logits(rng, (6, 63))
-    value = jnp.asarray(rng.normal(size=(6, 1)).astype(np.float32)) * 4
-    g_got = jax.grad(lambda l, v: K.two_hot_symlog_loss(l, v, backend="pallas").sum(), (0, 1))(logits, value)
-    g_want = jax.grad(lambda l, v: K.two_hot_symlog_loss_reference(l, v).sum(), (0, 1))(logits, value)
+    logits = _norm_logits(rng, shape)
+    value = _two_hot_values(rng, shape[:-1], shape[-1], values)
+    ct = jnp.asarray(rng.normal(size=shape[:-1]).astype(np.float32))
+    g_got = jax.grad(lambda l, v: (K.two_hot_symlog_loss(l, v, backend="pallas") * ct).sum(), (0, 1))(logits, value)
+    g_want = jax.grad(lambda l, v: (K.two_hot_symlog_loss_reference(l, v) * ct).sum(), (0, 1))(logits, value)
     for a, b in zip(g_got, g_want):
+        assert a.shape == b.shape and a.dtype == b.dtype
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
 
 
@@ -429,15 +482,15 @@ def test_auto_on_a_tpu_is_pallas_except_the_kernels_routed_by_name(pretend_tpu):
     with K.use_backend("auto"):
         for name in K.names():
             want = "lax" if name in K.AUTO_LAX_ON_TPU else "pallas"
-            assert K.resolve(name) == want and K.tier(name) == want
-    assert set(K.AUTO_LAX_ON_TPU) == {"sumtree_sample"}
+            assert K.resolve(name) == want and K.tier(name) == ("xla" if name in K.COMPILED_BY_XLA else want)
+    assert set(K.AUTO_LAX_ON_TPU) == {"sumtree_sample"} and set(K.COMPILED_BY_XLA) == {"two_hot_symlog_loss"}
     with K.use_backend("pallas"):  # an explicit choice still reaches the kernel
         assert K.resolve("sumtree_sample") == "pallas"
 
 
 def test_interpret_tier_is_named_without_a_tpu():
     with K.use_backend("pallas"):
-        assert all(K.tier(name) == "pallas-interpret" for name in K.names())
+        assert all(K.tier(name) == ("xla" if name in K.COMPILED_BY_XLA else "pallas-interpret") for name in K.names())
 
 
 def test_cpu_lowering_in_a_tpu_process_takes_the_reference(pretend_tpu):
@@ -481,7 +534,29 @@ def test_every_pallas_kernel_compiles_for_tpu(pretend_tpu, tpu_topology):
                 _compile_for_tpu(tpu_topology, fn, arrays)
             continue
         compiled = _compile_for_tpu(tpu_topology, fn, arrays)
-        assert "tpu_custom_call" in compiled.as_text(), name
+        assert ("tpu_custom_call" in compiled.as_text()) == (name not in K.COMPILED_BY_XLA), name
+
+
+@pytest.mark.parametrize("argnums,most", [(0, 4), ((0, 1), 8)], ids=["d-logits", "d-logits-d-value"])
+def test_two_hot_loss_backward_for_tpu_is_a_closed_form(pretend_tpu, tpu_topology, argnums, most):
+    """The loss and its gradient at the critic's shape (horizon 15 x 1024
+    rows x 255 bins), as dispatched, through the TPU compiler: a handful of
+    fusions, the contraction and ``d logits`` in one, and the support a
+    constant. The reference's chain differentiated is 23 launches for
+    ``d logits`` alone: the support rebuilt by ``_linspace``, two gathers
+    over ``f32[15360]``, clamps, compare/selects."""
+    import re
+
+    f32 = jnp.float32
+    avals = (jax.ShapeDtypeStruct((15, 1024, 255), f32), jax.ShapeDtypeStruct((15, 1024, 1), f32))
+    step = jax.value_and_grad(lambda logits, value: K.two_hot_symlog_loss(logits, value).sum(), argnums)
+    text = _compile_for_tpu(tpu_topology, step, avals).as_text()
+    entry = [line for line in text[text.index("\nENTRY ") :].splitlines() if " = " in line]
+    free = re.compile(r" (constant|parameter|bitcast|get-tuple-element|copy-start|copy-done)\(")
+    launched = [line for line in entry if not free.search(line)]
+    assert any("kernel.two_hot_symlog_loss" in line for line in launched), launched  # the scope the trace is read by
+    assert "_linspace" not in text and " gather(" not in text and "custom-call" not in text
+    assert len(launched) <= most, launched
 
 
 @pytest.mark.parametrize("stored", [True, False], ids=["stored-view", "env-shaped-ring"])
