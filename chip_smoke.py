@@ -24,6 +24,9 @@ imports JAX):
 4. ``python -m sheeprl_tpu run exp=ppo_anakin`` on the pure-JAX CartPole, twice:
    the second process must read from the persistent compile cache what the
    first one wrote.
+5. ``python -m sheeprl_tpu run exp=ppo_anakin_lm`` at toy widths: the same
+   trainer with a decoder language model as the policy on the token MDP (both
+   of its kernels through their TPU tier), four iterations.
 
 It fails unless JAX's platform is ``tpu`` with N devices, every child exits 0,
 the runs log finite ``Loss/*`` after training began, no update was skipped by
@@ -61,6 +64,18 @@ DREAMER_LEARNING_STARTS = 1024  # the exp's own value, restated so the check bel
 DREAMER_TOTAL_STEPS = DREAMER_LEARNING_STARTS + 384  # replay_ratio 1: one gradient step per policy step
 DREAMER_MIN_GRAD_STEPS = 256
 PPO_TOTAL_STEPS = 4096  # 8 iterations of 4 envs x 128 rollout steps
+# the decoder policy at toy widths (heads of 128, 128-token prompts and 256-token sequences: what both kernels' TPU
+# tiers take)
+LM_OVERRIDES = (
+    "exp=ppo_anakin_lm", "algo.lm.hidden_size=256", "algo.lm.num_attention_heads=4", "algo.lm.num_key_value_heads=2",
+    "algo.lm.head_dim=128", "algo.lm.moe_ffn_hidden_size=128", "algo.lm.moe_num_primary_experts=8",
+    "algo.lm.moe_num_active_primary_experts=2", "algo.lm.experts_held=4", "algo.lm.expert_offset=2",
+    "algo.lm.num_hidden_layers=4", "algo.lm.sliding_window_size=128", "algo.lm.vocab_size=1024",
+    "algo.lm.vocab_held=512", "env.prompt_len=128", "algo.rollout_steps=128",
+)
+LM_ITERATIONS = 4
+# kernels that round their operands to bfloat16 by design: their float32 outputs are held to the bfloat16 tolerance
+BF16_OPERAND_KERNELS = ("moe_grouped_ffn", "window_attention")
 
 
 class SmokeFailure(Exception):
@@ -220,6 +235,15 @@ def ppo_overrides(devices: int) -> list:
     ]
 
 
+def lm_overrides(devices: int) -> list:
+    envs = 2 * devices
+    return [
+        *LM_OVERRIDES, f"env.num_envs={envs}", "algo.per_rank_batch_size=1", f"fabric.devices={devices}",
+        f"algo.total_steps={LM_ITERATIONS * envs * 128}", "metric.log_level=1", f"metric.log_every={envs * 128}",
+        "checkpoint.every=1000000", "checkpoint.save_last=True", f"log_root={RUNS}",
+    ]
+
+
 def smoke(devices: int) -> dict:
     check(os.path.isdir(os.path.join(HERE, "sheeprl_tpu")), f"no sheeprl_tpu package next to {__file__}")
     for directory in (WORK, RUNS):
@@ -278,6 +302,17 @@ def smoke(devices: int) -> dict:
           "the two PPO-Anakin processes used different compile cache directories")
     check(result["ppo_anakin_again"]["cache_hits"] > 0,
           "the second PPO-Anakin process read nothing from the persistent compile cache")
+
+    # 5. the same trainer with a language-model policy on the token MDP, toy widths
+    out, secs = run_child("ppo_anakin_lm", cli("run", *lm_overrides(devices)), timeout=600)
+    info = check_common("ppo_anakin_lm", out, devices)
+    for kernel in BF16_OPERAND_KERNELS:
+        check(f"{kernel}=pallas" in info["kernels"], f"ppo_anakin_lm: {kernel} did not take its TPU tier: {info['kernels']}")
+    run_dir = run_dir_of(out)
+    losses = check_losses("ppo_anakin_lm", run_dir, ("Loss/policy_loss", "Loss/value_loss", "Loss/entropy_loss"), 0)
+    check_manifest("ppo_anakin_lm", run_dir)
+    result["ppo_anakin_lm"] = {**info, "seconds": round(secs, 1), "losses": losses["last"]}
+    print(f"[chip_smoke] ppo_anakin_lm {json.dumps(result['ppo_anakin_lm'])}", flush=True)
     return result
 
 
@@ -376,6 +411,18 @@ def check_kernels(devices: int) -> dict:
         ((tree, jnp.asarray(rng.uniform(size=(256,)), jnp.float32), jnp.int32(3000), jnp.float32(0.4)), "8192 x 256")
     )
 
+    # the decoder policy's kernels at the shapes of the language-model cell: one 8192-token sequence's
+    # 49152 sorted assignments over 16 held experts at uneven loads (one empty, a quarter held), hidden 2560,
+    # expert width 768; 28 query heads over 4 key-value heads of 128, whole and over a 4096-token window
+    loads = rng.multinomial(12288, rng.dirichlet(np.full(15, 2.0))).tolist() + [0]
+    cases["moe_grouped_ffn"].append(
+        ((normal((49152, 2560)), normal((16, 2560, 768), scale=0.02), normal((16, 2560, 768), scale=0.02),
+          normal((16, 768, 2560), scale=0.02), jnp.asarray(loads, jnp.int32)), "49152 x 2560 over 16 x 768"))
+    for window in (0, 4096):
+        cases["window_attention"].append(
+            ((normal((1, 8192, 28, 128), scale=0.3), normal((1, 8192, 4, 128), scale=0.3), normal((1, 8192, 4, 128)), window),
+             f"8192 x 28/4 x 128 window {window}"))
+
     report = {}
     for name in K.names():
         tier = K.tier(name)
@@ -410,7 +457,7 @@ def check_kernels(devices: int) -> dict:
                 g32, w32 = g.astype(jnp.float32), w.astype(jnp.float32)
                 check(bool(jnp.isfinite(g32).all()), f"kernel {name} [{label}]: non-finite output")
                 err = float(jnp.max(jnp.abs(g32 - w32) / (1.0 + jnp.abs(w32))))
-                tol = 2e-2 if g.dtype.itemsize == 2 else 1e-4
+                tol = 2e-2 if g.dtype.itemsize == 2 or name in BF16_OPERAND_KERNELS else 1e-4
                 check(err <= tol, f"kernel {name} [{label}]: error {err:.3g} against the reference exceeds {tol}")
                 worst[g.dtype.name] = max(worst.get(g.dtype.name, 0.0), err)
                 print(f"kernel {name} [{label}]: scaled error {err:.3g}", flush=True)

@@ -1,0 +1,9 @@
+"""Device self time per gradient step of the instructions whose innermost scope is
+`kernel.window_attention` (whichever tier ran), prefill and update, forward and backward.
+Counted in its region metric too."""
+
+from layers._program_record import kernel_ms
+
+
+def read(run):
+    return kernel_ms(run, "window_attention")

@@ -330,18 +330,56 @@ class AnakinBlockCache:
     member axis and traced hparams change the program, not the cache rule).
     """
 
-    def __init__(self, builder, name: str):
+    def __init__(self, builder, name: str, program: str = ""):
         self._builder = builder
         self._name = name
+        self._program = program or name  # what the blocks are dispatched and registered with the recorder as
         self._fns: Dict[int, Any] = {}
 
     def __call__(self, n_iters: int):
         if n_iters not in self._fns:
-            self._fns[n_iters] = tracecheck.instrument(self._builder(n_iters), name=self._name)
+            fn = self._builder(n_iters)
+            self._fns[n_iters] = _RegisteredBlock(tracecheck.instrument(fn, name=self._name), fn, self.program_name(n_iters))
         return self._fns[n_iters]
+
+    def program_name(self, n_iters: int) -> str:
+        """The name a block of ``n_iters`` iterations is dispatched and registered under."""
+        return f"{self._program}/{n_iters}"
 
     def __len__(self) -> int:
         return len(self._fns)
+
+
+class _RegisteredBlock:
+    """A jitted block whose executable the recorder can be asked for
+    (``profiler.register_program``): its scope table joins a device trace's
+    instruction names to the block's region names. The block is called as the
+    ``jax.jit`` it is (``call``: the same, instrumented). Right after its first
+    call ``fn`` is lowered once more for that call's abstract arguments, in the
+    same context, which JAX answers from the trace and the lowering it has just
+    made; the text, when asked for, is that lowering's executable, which is the
+    call's: no second compile (``tests/test_algos/test_ppo_anakin_lm.py``)."""
+
+    def __init__(self, call, fn, name: str):
+        self._call, self._fn, self._name, self._lowered = call, fn, name, None
+
+    def as_text(self) -> str:
+        return self._lowered.compile().as_text()
+
+    def __call__(self, *args):
+        if self._lowered is not None:
+            return self._call(*args)
+        from sheeprl_tpu.utils import profiler
+
+        # the arguments are donated: their shapes are taken before the call; an uncommitted one (a fresh key) is
+        # lowered with no sharding of its own, as the call lowers it
+        avals = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding if x.committed else None), args
+        )
+        out = self._call(*args)
+        self._lowered = self._fn.lower(*avals)
+        profiler.register_program(self._name, self)
+        return out
 
 
 @register_algorithm()
@@ -395,6 +433,14 @@ def main(fabric, cfg: Dict[str, Any]):
     env_kwargs: Dict[str, Any] = {}
     if cfg.env.max_episode_steps and cfg.env.max_episode_steps > 0:
         env_kwargs["max_episode_steps"] = int(cfg.env.max_episode_steps)
+    # algo.lm set: the policy is a language model and the env the token MDP
+    # (ppo_anakin_lm.py builds both and the block; the loop below is shared)
+    lm_cfg = cfg.algo.get("lm")
+    if lm_cfg:
+        env_kwargs.update(
+            vocab_size=int(lm_cfg.vocab_held or lm_cfg.vocab_size), prompt_len=int(cfg.env.prompt_len),
+            response_len=int(cfg.algo.rollout_steps),
+        )
     jenv = make_jax_env(cfg.env.id, **env_kwargs)
 
     cnn_keys = list(cfg.algo.cnn_keys.encoder or [])
@@ -415,10 +461,16 @@ def main(fabric, cfg: Dict[str, Any]):
         else (jenv.action_space.nvec.tolist() if is_multidiscrete else [jenv.action_space.n])
     )
 
-    agent, params, player = build_agent(
-        fabric, actions_dim, is_continuous, cfg, observation_space,
-        state["agent"] if state is not None else None,
-    )
+    if lm_cfg:
+        from sheeprl_tpu.algos.ppo import ppo_anakin_lm
+
+        agent, params = ppo_anakin_lm.build_lm_agent(fabric, cfg, jenv, state["agent"] if state is not None else None)
+        player = None  # no greedy test episode for a token policy
+    else:
+        agent, params, player = build_agent(
+            fabric, actions_dim, is_continuous, cfg, observation_space,
+            state["agent"] if state is not None else None,
+        )
 
     from sheeprl_tpu.optim.builders import build_optimizer
 
@@ -497,18 +549,28 @@ def main(fabric, cfg: Dict[str, Any]):
     ep_len = jax.device_put(jnp.zeros((num_envs,), jnp.int32), env_sharding)
     env_keys = jax.device_put(jax.random.split(rollout_root, world), env_sharding)
 
-    get_block_fn = AnakinBlockCache(
-        lambda n_iters: make_anakin_block(
-            agent, tx, cfg, fabric.mesh, benv, local_envs, n_iters, obs_key,
-            ferry_episodes=ferry_episodes, guard=guard,
-        ),
-        name="ppo_anakin.block",
-    )
+    minibatch = int(cfg.algo.per_rank_batch_size)
+    if lm_cfg:
+        build_block = lambda n_iters: ppo_anakin_lm.make_anakin_lm_block(  # noqa: E731
+            agent, tx, cfg, fabric.mesh, benv, local_envs, n_iters, ferry_episodes=ferry_episodes, guard=guard,
+        )
+        get_block_fn = AnakinBlockCache(build_block, name="ppo_anakin_lm.block", program=ppo_anakin_lm.PROGRAM_NAME)
+        minibatches = local_envs // minibatch  # of whole sequences
+    else:
+        build_block = lambda n_iters: make_anakin_block(  # noqa: E731
+            agent, tx, cfg, fabric.mesh, benv, local_envs, n_iters, obs_key, ferry_episodes=ferry_episodes, guard=guard,
+        )
+        get_block_fn = AnakinBlockCache(build_block, name="ppo_anakin.block")
+        minibatches = max(1, -(-T * local_envs // minibatch))
+    grad_steps_per_iter = int(cfg.algo.update_epochs) * minibatches
+    # the language-model block takes the gradient steps it may run an iteration as an input (all of them, here)
+    block_extra = (fabric.put_replicated(jnp.asarray(grad_steps_per_iter, jnp.int32)),) if lm_cfg else ()
 
     lr = lr0
     clip_coef = float(cfg.algo.clip_coef)
     ent_coef = float(cfg.algo.ent_coef)
 
+    from sheeprl_tpu.utils import profiler as recorder
     from sheeprl_tpu.utils.profiler import TraceProfiler
 
     profiler = TraceProfiler(cfg.metric.get("profiler"), log_dir)
@@ -525,11 +587,17 @@ def main(fabric, cfg: Dict[str, Any]):
         # implicitly inside the guarded dispatch
         clip_arr = fabric.put_replicated(jnp.asarray(clip_coef, dtype=jnp.float32))
         ent_arr = fabric.put_replicated(jnp.asarray(ent_coef, dtype=jnp.float32))
-        with timer("Time/train_time", SumMetric):
-            params, opt_state, env_state, obs, ep_ret, ep_len, env_keys, metrics = block_fn(
-                params, opt_state, env_state, obs, ep_ret, ep_len, env_keys, train_key,
-                clip_arr, ent_arr, env_params,
-            )
+        # host spans (utils/profiler.py): one `iter` per block, holding the block's dispatch
+        # (named after the program it runs) and the wait for its metrics
+        with timer("Time/train_time", SumMetric), recorder.span(
+            "iter", parent=recorder.ROOT, iter_num=iter_num + 1, policy_step=policy_step,
+            grad_steps=block_iters * grad_steps_per_iter,
+        ):
+            with recorder.span("burst.dispatch", program=get_block_fn.program_name(block_iters)):
+                params, opt_state, env_state, obs, ep_ret, ep_len, env_keys, metrics = block_fn(
+                    params, opt_state, env_state, obs, ep_ret, ep_len, env_keys, train_key,
+                    clip_arr, ent_arr, env_params, *block_extra,
+                )
             metrics = jax.device_get(metrics)
 
         # Host-side bookkeeping for the fused block, iteration by iteration
@@ -635,7 +703,7 @@ def main(fabric, cfg: Dict[str, Any]):
             fabric.call("on_checkpoint_coupled", ckpt_path=ckpt_path, state=ckpt_state)
 
     profiler.close()
-    if fabric.is_global_zero and cfg.algo.run_test:
+    if fabric.is_global_zero and cfg.algo.run_test and player is not None:
         test(player, params, fabric, cfg, log_dir, writer=logger)
 
     if not cfg.model_manager.disabled and fabric.is_global_zero:  # pragma: no cover - mlflow optional

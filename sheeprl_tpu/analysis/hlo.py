@@ -256,16 +256,13 @@ def op_scopes(
     """
     known = set(regions)
     out: Dict[str, Dict[str, object]] = {}
-    for line in compiled_hlo_text.splitlines():
-        m = _INSTRUCTION_RE.match(line)
-        if not m:
-            continue
+
+    def entry(op_name: Optional[str]) -> Dict[str, object]:
         outer: Optional[str] = None
         scope: Optional[str] = None
         backward = False
-        meta = _OP_NAME_RE.search(line)
-        if meta:
-            op_name = meta.group(1).split(";", 1)[0]
+        if op_name is not None:
+            op_name = op_name.split(";", 1)[0]
             for token in _NAME_STACK_SPLIT_RE.split(op_name):
                 if token in known:
                     outer = outer or token
@@ -273,7 +270,21 @@ def op_scopes(
                 elif token.startswith(kernel_prefix) and len(token) > len(kernel_prefix):
                     scope = token
             backward = "transpose(" in op_name
-        out[m.group(1)] = {"scope": scope, "outer": outer, "backward": backward}
+        return {"scope": scope, "outer": outer, "backward": backward}
+
+    waiting: Optional[str] = None  # an instruction whose text runs on over the next lines, its metadata not seen yet
+    for line in compiled_hlo_text.splitlines():
+        m = _INSTRUCTION_RE.match(line)
+        meta = _OP_NAME_RE.search(line)
+        if not m:
+            # a continuation line (a Pallas call's `kernel_metadata` is printed over several): the
+            # instruction's own `metadata={op_name=...}` follows it there
+            if waiting is not None and meta:
+                out[waiting] = entry(meta.group(1))
+                waiting = None
+            continue
+        out[m.group(1)] = entry(meta.group(1) if meta else None)
+        waiting = None if meta else m.group(1)
     return out
 
 

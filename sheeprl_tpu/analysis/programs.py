@@ -39,6 +39,7 @@ __all__ = [
 AUDIT_SOURCES: Tuple[str, ...] = (
     "sheeprl_tpu.algos.ppo.ppo",
     "sheeprl_tpu.algos.ppo.ppo_anakin",
+    "sheeprl_tpu.algos.ppo.ppo_anakin_lm",
     "sheeprl_tpu.algos.ppo.ppo_anakin_population",
     "sheeprl_tpu.algos.ppo.ppo_sebulba",
     "sheeprl_tpu.algos.sac.sac",

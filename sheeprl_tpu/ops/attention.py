@@ -45,19 +45,24 @@ def block_attention(
     k_offset: jax.Array,
     causal: bool,
     scale: float,
+    window: int = 0,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One (q-block, kv-block) step of blockwise attention.
 
     Returns the un-normalized accumulator pieces for online-softmax merging:
     ``(out_block, row_max, row_sum)`` with ``out_block = exp(s - m) @ v``.
     ``q_offset``/``k_offset`` are the blocks' global sequence positions, so a
-    causal mask stays correct when blocks travel around a ring.
+    causal mask stays correct when blocks travel around a ring. A ``window``
+    (with ``causal``) also hides keys more than ``window - 1`` positions
+    behind the query: the window counts the query's own position.
     """
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale  # (B, H, Tq, Tk)
     if causal:
         q_pos = q_offset + jnp.arange(q.shape[1])
         k_pos = k_offset + jnp.arange(k.shape[1])
         mask = q_pos[:, None] >= k_pos[None, :]
+        if window:
+            mask &= q_pos[:, None] - k_pos[None, :] < window
         scores = jnp.where(mask[None, None], scores, -jnp.inf)
     m = jnp.max(scores, axis=-1)  # (B, H, Tq)
     # fully-masked rows produce m = -inf; exp(-inf - -inf) would be nan

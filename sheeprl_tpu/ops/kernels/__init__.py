@@ -36,6 +36,8 @@ from sheeprl_tpu.ops.kernels.twohot import (
 from sheeprl_tpu.ops.kernels.gae import gae, gae_reference
 from sheeprl_tpu.ops.kernels.sumtree import sumtree_sample, sumtree_sample_reference
 from sheeprl_tpu.ops.kernels.scatter import ragged_ring_scatter, ragged_ring_scatter_reference
+from sheeprl_tpu.ops.kernels.moe import moe_grouped_ffn, moe_grouped_ffn_reference
+from sheeprl_tpu.ops.kernels.attn import window_attention, window_attention_reference
 
 __all__ = [
     "AUTO_LAX_ON_TPU",
@@ -54,6 +56,8 @@ __all__ = [
     "gru_gates",
     "gru_gates_pallas",
     "gru_gates_reference",
+    "moe_grouped_ffn",
+    "moe_grouped_ffn_reference",
     "names",
     "overrides",
     "ragged_ring_scatter",
@@ -68,4 +72,6 @@ __all__ = [
     "two_hot_symlog_loss",
     "two_hot_symlog_loss_reference",
     "use_backend",
+    "window_attention",
+    "window_attention_reference",
 ]

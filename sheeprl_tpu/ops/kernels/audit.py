@@ -15,8 +15,9 @@ accidental f64) exactly like every other program row.
 
 Shapes mirror the real call sites at CI scale: the RSSM recurrent width for
 the GRU gates, the Dreamer return head's 255-bucket support, a PPO
-``(T, num_envs)`` rollout for GAE, the SAC PER tree, and a Sebulba-style
-burst append for the ring scatter.
+``(T, num_envs)`` rollout for GAE, the SAC PER tree, a Sebulba-style
+burst append for the ring scatter, and the decoder policy's routed
+feed-forward and windowed attention at the smallest tiles their kernels take.
 """
 
 from __future__ import annotations
@@ -70,6 +71,16 @@ def _audit_programs(spec: AuditMesh):
                 aval((4, 8), jnp.int32),
                 aval((8,), jnp.int32),
             ),
+        ),
+        # the routed layer: 256 sorted assignment rows over 4 held experts at uneven loads
+        "moe_grouped_ffn": (
+            jax.jit(k["moe_grouped_ffn"]),
+            (aval((256, 128)), aval((4, 128, 128)), aval((4, 128, 128)), aval((4, 128, 128)), aval((4,), jnp.int32)),
+        ),
+        # grouped-query attention, 4 query heads over 2 key-value heads, a 128-token window over 256 positions
+        "window_attention": (
+            jax.jit(lambda q, kk, v: k["window_attention"](q, kk, v, 128)),
+            (aval((1, 256, 4, 128)), aval((1, 256, 2, 128)), aval((1, 256, 2, 128))),
         ),
     }
     for name, (fn, args) in cases.items():

@@ -43,6 +43,8 @@ from typing import Any, Dict, List, Mapping, Optional, Tuple
 __all__ = [
     "TraceProfiler",
     "REGIONS",
+    "BURST_REGIONS",
+    "LM_BLOCK_REGIONS",
     "KERNEL_PREFIX",
     "SPANS",
     "CAPACITY",
@@ -63,7 +65,7 @@ __all__ = [
 #: Regions are disjoint: an instruction belongs to the outermost region on
 #: its ``op_name`` path. Backward work of a region carries
 #: ``transpose(jvp(<region>))``.
-REGIONS: Tuple[str, ...] = (
+BURST_REGIONS: Tuple[str, ...] = (
     "wm.encoder",
     "wm.dynamics",
     "wm.decoder",
@@ -77,6 +79,23 @@ REGIONS: Tuple[str, ...] = (
     "ring.append",
     "ring.sample",
 )
+#: The same for the on-device PPO block with a language-model policy
+#: (``algos/ppo/ppo_anakin_lm.py``): the two rollout phases are outermost
+#: there, so the model's own scopes inside them count to the phase; the rest
+#: lies in the update.
+LM_BLOCK_REGIONS: Tuple[str, ...] = (
+    "rollout.prefill",
+    "rollout.decode",
+    "lm.embed",
+    "lm.attn_global",
+    "lm.attn_window",
+    "lm.moe",
+    "lm.head_loss",
+    "ppo.gae",
+    "ppo.optim",
+    "env.token",
+)
+REGIONS: Tuple[str, ...] = BURST_REGIONS + LM_BLOCK_REGIONS
 #: ``ops.kernels.registry.dispatch(name)`` runs its kernel, whichever tier,
 #: under ``jax.named_scope(KERNEL_PREFIX + name)``.
 KERNEL_PREFIX = "kernel."

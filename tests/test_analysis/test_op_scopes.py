@@ -101,3 +101,23 @@ def test_while_readers_tell_what_a_loop_holds_from_what_runs_outside_it():
     assert inside in carried and inside in body
     assert outside not in carried and outside not in body
     assert set(carried) <= body, "a loop's carry is its body's parameter"
+
+
+def test_an_instruction_printed_over_several_lines_keeps_its_op_name():
+    """A Pallas call that carries `kernel_metadata` is printed over three lines, its `metadata=` on the last."""
+    from sheeprl_tpu.analysis.hlo import op_scopes
+
+    text = "\n".join([
+        '  %splash_mqa_fwd.1 = (f32[4,512,128]{2,1,0}, bf16[4,7,8192,128]{3,2,1,0}) custom-call(%a, %b), '
+        'custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={',
+        '"xprof_metadata":"{\\"block_q\\": 512}"',
+        '}}, metadata={op_name="jit(block)/while/body/transpose(jvp())/lm.attn_window/kernel.window_attention/'
+        'vmap(jit(_splash_attention))/pallas_call" stack_frame_id=5}, backend_config={"x":1}',
+        '  %pallas_call.2 = bf16[4,7,8192,128]{3,2,1,0} get-tuple-element(%splash_mqa_fwd.1), index=1',
+        '  %gmm.3 = f32[8,8]{1,0} custom-call(%c), frontend_attributes={kernel_metadata={}}, '
+        'metadata={op_name="jit(block)/rollout.decode/lm.moe/kernel.moe_grouped_ffn/gmm"}',
+    ])
+    table = op_scopes(text, regions=("lm.attn_window", "lm.moe", "rollout.decode"))
+    assert table["splash_mqa_fwd.1"] == {"scope": "kernel.window_attention", "outer": "lm.attn_window", "backward": True}
+    assert table["pallas_call.2"] == {"scope": None, "outer": None, "backward": False}  # no metadata of its own
+    assert table["gmm.3"] == {"scope": "kernel.moe_grouped_ffn", "outer": "rollout.decode", "backward": False}

@@ -16,10 +16,12 @@ from sheeprl_tpu.replay import sumtree as st
 EXPECTED_KERNELS = (
     "gae",
     "gru_gates",
+    "moe_grouped_ffn",
     "ragged_ring_scatter",
     "sumtree_sample",
     "two_hot_symexp_decode",
     "two_hot_symlog_loss",
+    "window_attention",
 )
 
 
@@ -470,6 +472,13 @@ def _kernel_cases(rng):
     yield "ragged_ring_scatter", _ring_case(rng, C=8, E=3, S=4, e=3, feat=(18,))
     yield "ragged_ring_scatter", _ring_case(rng, C=8, E=1, S=4, e=1, feat=(64, 64, 3), dtype=np.uint8)
     yield "ragged_ring_scatter", _ring_case(rng, C=8, E=3, S=4, e=3, feat=(64, 64, 3), dtype=np.uint8)
+    # the decoder policy: one sequence's sorted assignments over held experts at uneven loads (one empty), and
+    # grouped-query attention at the published head size, whole and windowed
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape).astype(f32))  # noqa: E731
+    yield "moe_grouped_ffn", (normal(1536, 256), normal(4, 256, 128), normal(4, 256, 128), normal(4, 128, 256),
+                              jnp.asarray([0, 700, 90, 10], jnp.int32))
+    yield "window_attention", (normal(1, 1024, 14, 128), normal(1, 1024, 2, 128), normal(1, 1024, 2, 128), 0)
+    yield "window_attention", (normal(1, 1024, 14, 128), normal(1, 1024, 2, 128), normal(1, 1024, 2, 128), 256)
 
 
 def _split_statics(args):

@@ -15,7 +15,7 @@ import pytest
 
 import sheeprl_tpu.utils.profiler as profiler_mod
 from sheeprl_tpu.utils import burst as burst_mod
-from sheeprl_tpu.utils.profiler import REGIONS, SPANS
+from sheeprl_tpu.utils.profiler import BURST_REGIONS, SPANS
 
 N_ENVS, TOTAL_STEPS, TRAIN_EVERY = 2, 96, 4
 ARGS = [
@@ -84,6 +84,7 @@ def burst_run(tmp_path_factory):
     mp.setattr(burst_mod.BurstRunner, "flush", flush)
     mp.setattr(burst_mod.BurstRunner, "_step", _step)
     profiler_mod.reset()
+    seen["programs_at_start"] = profiler_mod.programs()  # what earlier tests of this process registered
     try:
         run(ARGS + [f"log_root={tmp_path_factory.mktemp('burst_run')}/logs"])
     finally:
@@ -159,7 +160,7 @@ def test_dispatched_programs_are_registered_with_their_scope_tables(burst_run):
     assert programs and programs <= set(profiler_mod.programs())
     assert all(p.startswith("packed_burst/") for p in programs)
     table = profiler_mod.scope_table(sorted(programs)[0])
-    assert {v["outer"] for v in table.values()} == set(REGIONS) | {None}
+    assert {v["outer"] for v in table.values()} == set(BURST_REGIONS) | {None}
     assert any(v["scope"] == "kernel.ragged_ring_scatter" and v["outer"] == "ring.append" for v in table.values())
     assert profiler_mod.scope_table(sorted(programs)[0]) is table  # parsed once
 
@@ -189,6 +190,7 @@ def test_burst_fn_is_callable_from_the_trainer_thread_without_a_second_compile(b
     # the probe ran the bucket's one compiled program (compiling it if it came first):
     # nothing is registered that a burst.dispatch span does not name
     dispatched = {d["counters"]["program"] for d in _named(burst_run, "burst.dispatch")}
-    assert set(probe["programs_before"]) <= set(probe["programs_after"]) <= dispatched
+    earlier = set(burst_run["programs_at_start"])
+    assert set(probe["programs_before"]) - earlier <= set(probe["programs_after"]) - earlier <= dispatched
     assert probe["cum_after"] == probe["cum_before"]  # no step granted, no step taken
     assert probe["n_metrics"] == 10
