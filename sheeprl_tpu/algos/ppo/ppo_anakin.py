@@ -592,13 +592,16 @@ def main(fabric, cfg: Dict[str, Any]):
         with timer("Time/train_time", SumMetric), recorder.span(
             "iter", parent=recorder.ROOT, iter_num=iter_num + 1, policy_step=policy_step,
             grad_steps=block_iters * grad_steps_per_iter,
-        ):
+        ) as iter_span:
             with recorder.span("burst.dispatch", program=get_block_fn.program_name(block_iters)):
                 params, opt_state, env_state, obs, ep_ret, ep_len, env_keys, metrics = block_fn(
                     params, opt_state, env_state, obs, ep_ret, ep_len, env_keys, train_key,
                     clip_arr, ent_arr, env_params, *block_extra,
                 )
             metrics = jax.device_get(metrics)
+            # what the block counted of itself, for the record's readers (a block without the counter sets none)
+            iter_span.set(**{k: int(np.sum(metrics[k])) for k in ("moe_compact_calls", "moe_compactable_calls")
+                             if k in metrics})
 
         # Host-side bookkeeping for the fused block, iteration by iteration
         # (same counters/cadence the host loop maintains per iteration)

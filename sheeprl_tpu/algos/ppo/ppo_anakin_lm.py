@@ -24,9 +24,12 @@ The input is ``grad_steps``: how many of an iteration's gradient steps the
 update may run (the host loop grants all; the update's loop runs that many
 times and no step is computed and thrown away). Its metrics add the per-step
 losses and gradient norms of the update and the routed layer's counters
-(``moe_local_assignments``, ``moe_max_expert_load`` per layer, and
+(``moe_local_assignments``, ``moe_max_expert_load`` per layer;
 ``moe_dropped``: over rollout and update, the assignments to held experts
-less the rows the grouped products were handed, :func:`decoder_lm.moe_share`).
+less the rows the grouped products were handed, :func:`decoder_lm.moe_share`;
+``moe_compact_calls`` of ``moe_compactable_calls``: of the routed layer's
+calls that could move only the head of their sorted rows, prefill's and the
+update's forwards, those that did).
 ``algo.ferry_rollout`` adds what the rollout recorded (tokens,
 log-probabilities, values, rewards) to the metrics, for a check against
 another implementation.
@@ -154,7 +157,7 @@ def make_sequence_train(policy: LMPolicy, tx, cfg, local_envs: int, guard: bool)
 
         # a step that is not granted is not run: its row of the per-step outputs stays zero
         out = {k: jnp.zeros((total,), jnp.float32) for k in ("pg", "v", "ent", "grad_norm", "bad")}
-        out["counters"] = jnp.zeros((total, layers, 3), jnp.int32)
+        out["counters"] = jnp.zeros((total, layers, 5), jnp.int32)
         granted = jnp.clip(grad_steps, 0, total)
         params, opt_state, out = jax.lax.fori_loop(0, granted, one_step, (params, opt_state, out))
         steps = {k: out[k] for k in ("pg", "v", "ent")}
@@ -162,10 +165,12 @@ def make_sequence_train(policy: LMPolicy, tx, cfg, local_envs: int, guard: bool)
         metrics = {k: jax.lax.pmean(x.sum() / ran, "dp") for k, x in steps.items()}
         metrics.update({k + "_steps": jax.lax.pmean(x, "dp") for k, x in steps.items()})
         metrics["grad_norm_steps"] = out["grad_norm"]
-        # counters: (steps, layers, 3) -> what the update's forwards saw, per layer
+        # counters: (steps, layers, 5) -> what the update's forwards saw, per layer
         metrics["moe_local_assignments"] = jax.lax.psum(out["counters"][..., 0].sum(axis=0), "dp")
         metrics["moe_max_expert_load"] = jax.lax.pmax(out["counters"][..., 1].max(axis=0), "dp")
         metrics["moe_dropped"] = out["counters"][..., 2].sum()
+        metrics["moe_compact_calls"] = out["counters"][..., 3].sum()
+        metrics["moe_compactable_calls"] = out["counters"][..., 4].sum()
         if guard:
             metrics["bad"] = out["bad"].sum()
         return params, opt_state, metrics
@@ -216,7 +221,7 @@ def make_anakin_lm_local_block(policy: LMPolicy, tx, cfg, benv, local_envs: int,
         (env_state, _, _, last_value, key), traj = jax.lax.scan(
             decode, (env_state, cache, logits, value, key), jnp.arange(R)
         )
-        counters = prefill_counters.sum(axis=0) + traj.pop("counters").sum(axis=0)  # (layers, 3): prefill and decode
+        counters = prefill_counters.sum(axis=0) + traj.pop("counters").sum(axis=0)  # (layers, 5): prefill and decode
         return env_state, prompts, traj, last_value, key, counters
 
     def local_block(params, opt_state, env_state, obs, ep_ret, ep_len, env_keys, train_key, clip_coef, ent_coef, env_params,
@@ -237,7 +242,8 @@ def make_anakin_lm_local_block(policy: LMPolicy, tx, cfg, benv, local_envs: int,
             }
             params, opt_state, metrics = sequence_train(params, opt_state, data, train_key, clip_coef, ent_coef, grad_steps)
             metrics["moe_rollout_assignments"] = jax.lax.psum(rollout_counters[:, 0], "dp")
-            metrics["moe_dropped"] = jax.lax.psum(metrics["moe_dropped"] + rollout_counters[:, 2].sum(), "dp")
+            for name, column in (("moe_dropped", 2), ("moe_compact_calls", 3), ("moe_compactable_calls", 4)):
+                metrics[name] = jax.lax.psum(metrics[name] + rollout_counters[:, column].sum(), "dp")
             ep_return = traj["raw_rewards"].sum(axis=0)
             metrics["reward"] = jax.lax.pmean(ep_return.mean(), "dp")
             if ferry_episodes:
@@ -261,7 +267,7 @@ def make_anakin_lm_local_block(policy: LMPolicy, tx, cfg, benv, local_envs: int,
 def metric_specs(ferry_episodes: bool, guard: bool, ferry_rollout: bool) -> Dict[str, Any]:
     specs = {k: P() for k in ("pg", "v", "ent", "pg_steps", "v_steps", "ent_steps", "grad_norm_steps",
                               "moe_local_assignments", "moe_rollout_assignments", "moe_max_expert_load", "moe_dropped",
-                              "reward")}
+                              "moe_compact_calls", "moe_compactable_calls", "reward")}
     if guard:
         specs["bad"] = P()
     if ferry_episodes:

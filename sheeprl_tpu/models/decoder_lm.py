@@ -16,10 +16,14 @@ One layer, for input ``x`` (tokens x hidden), RMSNorm without bias:
 **The layer is told which experts it holds** (``experts_held`` of them from
 ``expert_offset``): it routes over all ``experts``, computes its own experts'
 part and leaves the rest out, which is one chip's share under expert
-parallelism; the sum over the shares is the whole layer. Nothing is dropped:
-the sorted buffer has a row for every assignment and the grouped product takes
-whatever load the router gives (the layer counts the assignments to its
-experts against the rows it hands the product, :func:`moe_share`).
+parallelism; the sum over the shares is the whole layer. It moves the rows it
+holds, not every assignment: assignments are numbered slot-major (``k * N +
+n``), those to experts elsewhere sort last, and only the head of the sorted
+order (:func:`compact_rows`: twice the mean share, in whole row tiles of the
+grouped product) is gathered, multiplied and combined. Nothing is dropped: a
+call whose experts hold more than the head takes every row, and the grouped
+product takes whatever load the router gives (the layer counts the assignments
+to its experts against the rows it hands the product, :func:`moe_share`).
 ``vocab_held`` rows of the embedding and columns of the head are held the
 same way.
 
@@ -41,7 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from sheeprl_tpu.ops.kernels.attn import window_attention
-from sheeprl_tpu.ops.kernels.moe import moe_grouped_ffn
+from sheeprl_tpu.ops.kernels.moe import GMM_ROW_TILE, moe_grouped_ffn
 
 __all__ = ["DecoderConfig", "init_params", "forward", "prefill", "decode_step", "heads", "parameter_count"]
 
@@ -129,49 +133,101 @@ def rope(x, positions, theta):
 
 
 # -- the routed layer's share -------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _gather_sorted(u, order, inverse, k):
-    """Row ``order[i] // k`` of ``u`` for every slot ``i`` of the sorted
-    assignments. The backward pass is a gather too (through ``inverse``), not
-    the scatter-add that differentiating the gather would give."""
-    return u[order // k]
+# An assignment is numbered slot-major: ``j = k * N + n`` is token ``n``'s ``k``-th expert, so everything per
+# assignment is ``K`` contiguous ``(N, ...)`` slabs and a sum over a token's experts is a sum over slabs.
+def compact_rows(cfg: DecoderConfig, tokens: int) -> int:
+    """How many rows of the sorted assignments the routed share moves for
+    ``tokens`` tokens: twice the mean number that land on the experts held,
+    rounded up to the grouped product's row tile, and never more than there
+    are assignments (an uncut layer, or a call of less than a tile: all)."""
+    made = tokens * cfg.top_k
+    twice_mean = -(-2 * made * cfg.experts_held // cfg.experts)
+    return min(made, -(-twice_mean // GMM_ROW_TILE) * GMM_ROW_TILE)
 
 
-def _gather_sorted_fwd(u, order, inverse, k):
-    return u[order // k], inverse
+@jax.custom_vjp
+def _gather_sorted(u, tokens, index, held):
+    """Row ``tokens[i]`` of ``u`` for every row ``i`` of the sorted buffer.
+    The backward pass is a gather too (through ``index``, ``(K, N)``: each
+    assignment's row of the sorted buffer), not the scatter-add that
+    differentiating the gather would give; an assignment that is not ``held``
+    has no row, and reads one under a zero."""
+    return u[tokens]
 
 
-def _gather_sorted_bwd(k, inverse, g):
-    return g[inverse].reshape(-1, k, g.shape[-1]).sum(axis=1), None, None
+def _gather_sorted_fwd(u, tokens, index, held):
+    return _gather_sorted(u, tokens, index, held), (index, held)
+
+
+def _gather_sorted_bwd(res, g):
+    index, held = res
+    return jnp.sum(jnp.where(held[..., None], g[index], 0.0), axis=0), None, None, None
 
 
 _gather_sorted.defvjp(_gather_sorted_fwd, _gather_sorted_bwd)
 
 
-def _by_token(ys, weights, inverse):
-    """The sorted rows ``ys`` back in ``(token, k, hidden)`` order."""
-    return ys[inverse].reshape(*weights.shape, ys.shape[-1])
-
-
 @jax.custom_vjp
-def _combine(ys, weights, order, inverse):
-    """``sum_k weights[n, k] * ys[inverse[n * K + k]]``: each token's experts'
-    outputs back in the token's place, weighted and summed. Gathers in the
-    backward pass too."""
-    return jnp.einsum("nkh,nk->nh", _by_token(ys, weights, inverse), weights)
+def _combine(ys, weights, picked, index):
+    """``sum_k weights[k, n] * ys[index[k, n]]``: each token's experts'
+    outputs back in the token's place, weighted and summed over the ``K``
+    slabs. Gathers in the backward pass too."""
+    return jnp.sum(weights[..., None] * ys[index], axis=0)
 
 
-def _combine_fwd(ys, weights, order, inverse):
-    return jnp.einsum("nkh,nk->nh", _by_token(ys, weights, inverse), weights), (ys, weights, order, inverse)
+def _combine_fwd(ys, weights, picked, index):
+    return _combine(ys, weights, picked, index), (ys, weights, picked, index)
 
 
 def _combine_bwd(res, g):
-    ys, weights, order, inverse = res
-    d_ys = (g[:, None, :] * weights[:, :, None]).reshape(ys.shape)[order]
-    return d_ys, jnp.einsum("nkh,nh->nk", _by_token(ys, weights, inverse), g), None, None
+    ys, weights, picked, index = res
+    d_ys = weights.reshape(-1)[picked][:, None] * g[picked % g.shape[0]]
+    return d_ys, jnp.sum(ys[index] * g[None], axis=-1), None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _share_rows(rows, experts, u, weights, routing):
+    """The share over the first ``rows`` rows of the sorted order: gather,
+    grouped products, combine. ``weights`` and ``held`` are ``(K, N)``; an
+    assignment sorted past ``rows`` reads the last row under a weight of 0."""
+    order, inverse, group_sizes, held = routing
+    picked = order[:rows]
+    index = jnp.minimum(inverse, rows - 1).reshape(weights.shape)
+    xs = _gather_sorted(u, picked % u.shape[0], index, held)
+    ys = moe_grouped_ffn(xs, experts["w_gate"], experts["w_up"], experts["w_down"], group_sizes)
+    return _combine(ys, weights, picked, index)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _share_head_or_all(rows, fits, experts, u, weights, routing):
+    """:func:`_share_rows` over the first ``rows`` rows where the assignments
+    held fit them (``fits``), over every row where they do not. One ``cond``
+    forward and one backward, each branch's backward formed from its own
+    forward: a ``cond`` differentiated as it stands hands its backward the
+    residuals of both branches, the untaken one's as zeros of full size."""
+    every = routing[0].shape[0]
+    return jax.lax.cond(fits, functools.partial(_share_rows, rows), functools.partial(_share_rows, every),
+                        experts, u, weights, routing)
+
+
+def _share_head_or_all_fwd(rows, fits, experts, u, weights, routing):
+    return _share_head_or_all(rows, fits, experts, u, weights, routing), (fits, experts, u, weights, routing)
+
+
+def _share_head_or_all_bwd(rows, res, g):
+    fits, experts, u, weights, routing = res
+
+    def backward(rows):
+        return lambda experts, u, weights, g: jax.vjp(
+            lambda experts, u, weights: _share_rows(rows, experts, u, weights, routing), experts, u, weights)[1](g)
+
+    grads = jax.lax.cond(fits, backward(rows), backward(routing[0].shape[0]), experts, u, weights, g)
+    return (None, *grads, None)
+
+
+_share_head_or_all.defvjp(_share_head_or_all_fwd, _share_head_or_all_bwd)
 
 
 def route(cfg: DecoderConfig, h, router):
@@ -184,23 +240,37 @@ def route(cfg: DecoderConfig, h, router):
 def moe_share(cfg: DecoderConfig, layer, u, weights, experts):
     """This chip's part of the routed layer for tokens ``u`` (N, hidden): the
     assignments that land on the experts held, sorted by expert, through the
-    grouped feed-forward and back. Returns the partial sum and the counters
-    ``(assignments held here, the largest expert's load, assignments
-    dropped)``: the last is the router's assignments to the experts held less
-    the rows of the sorted buffer that the grouped product is handed as some
-    expert's. A capacity would make it positive; there is none."""
+    grouped feed-forward and back. Assignments to experts elsewhere sort last,
+    and only the first :func:`compact_rows` rows of the order are moved; a
+    call whose experts hold more than that takes the same arithmetic over
+    every row, so nothing is dropped. Returns the partial sum and the counters
+    ``(assignments held here, the largest expert's load, assignments dropped,
+    whether the call compacted, whether it could)``: the third is the router's
+    assignments to the experts held less the rows of the sorted buffer that
+    the grouped product is handed as some expert's (a capacity would make it
+    positive; there is none); the last is static, 0 where every row is moved
+    anyway and there is no branch."""
     N, K, E = u.shape[0], cfg.top_k, cfg.experts_held
-    local = experts - cfg.expert_offset
+    local = (experts - cfg.expert_offset).T  # (K, N): slot-major from here on
     held = (local >= 0) & (local < E)
     slot = jnp.where(held, local, E).reshape(-1)  # assignments elsewhere sort last
     order = jnp.argsort(slot, stable=True).astype(jnp.int32)
     inverse = jnp.zeros_like(order).at[order].set(jnp.arange(N * K, dtype=jnp.int32))
     group_sizes = jnp.sum(slot[:, None] == jnp.arange(E)[None, :], axis=0, dtype=jnp.int32)
-    xs = _gather_sorted(u, order, inverse, K)
-    ys = moe_grouped_ffn(xs, layer["w_gate"], layer["w_up"], layer["w_down"], group_sizes)
-    out = _combine(ys, jnp.where(held, weights, 0.0), order, inverse)
-    computed = jnp.minimum(jnp.sum(group_sizes), xs.shape[0])
-    return out, (computed, jnp.max(group_sizes), jnp.sum(held, dtype=jnp.int32) - computed)
+    rows = compact_rows(cfg, N)
+    routed = jnp.sum(group_sizes)
+    args = ({k: layer[k] for k in ("w_gate", "w_up", "w_down")}, u, jnp.where(held, weights.T, 0.0),
+            (order, inverse, group_sizes, held))
+    could = rows < N * K
+    if could:
+        fits = routed <= rows
+        out, handed = _share_head_or_all(rows, fits, *args), jnp.where(fits, rows, N * K)
+    else:  # every row is moved anyway: no branch, and no call that could compact
+        fits = jnp.bool_(False)
+        out, handed = _share_rows(rows, *args), rows
+    computed = jnp.minimum(routed, handed)
+    return out, (computed, jnp.max(group_sizes), jnp.sum(held, dtype=jnp.int32) - computed, fits.astype(jnp.int32),
+                 jnp.int32(could))
 
 
 # -- one layer, full sequence -------------------------------------------------
@@ -240,7 +310,7 @@ def layer_forward(cfg: DecoderConfig, index: int, layer, x):
 
 def forward(cfg: DecoderConfig, params, tokens, return_kv: bool = False):
     """Full-sequence forward of ``tokens`` (B, T): the hidden states before the
-    final norm, the routing counters per layer ``(layers, 3)`` and, if asked,
+    final norm, the routing counters per layer ``(layers, 5)`` and, if asked,
     each layer's ``(k, v)``. With ``cfg.remat`` each layer is rematerialised
     in the backward pass."""
     with jax.named_scope("lm.embed"):

@@ -4,7 +4,10 @@ rows already sorted by expert.
 ``moe_grouped_ffn(xs, w_gate, w_up, w_down, group_sizes)``: ``xs`` is
 ``(M, hidden)`` with the rows of expert 0 first, then expert 1, ... (``E``
 experts held, ``group_sizes[e]`` rows each); rows past ``sum(group_sizes)``
-belong to no expert held here and come back as zeros. Each expert is the ReGLU
+belong to no expert held here and come back as zeros. ``M`` is whatever the
+caller sorted and cut: every assignment of its tokens, or the head of them
+that its experts' rows fit (``sum(group_sizes) <= M`` is the caller's to
+see to; a multiple of ``GMM_ROW_TILE`` tiles evenly). Each expert is the ReGLU
 ``W_down (relu(W_gate u) * (W_up u))``. No capacity: a group may hold every
 row or none.
 
@@ -24,10 +27,12 @@ import jax.numpy as jnp
 
 from sheeprl_tpu.ops.kernels import registry
 
-__all__ = ["moe_grouped_ffn", "moe_grouped_ffn_reference", "moe_grouped_ffn_pallas"]
+__all__ = ["moe_grouped_ffn", "moe_grouped_ffn_reference", "moe_grouped_ffn_pallas", "GMM_ROW_TILE"]
 
 # (rows, contraction, columns) tiles of the grouped product at most this large
 GMM_TILING = (512, 1024, 1024)
+#: a caller that hands the product fewer rows than it has assignments cuts them to a multiple of this
+GMM_ROW_TILE = GMM_TILING[0]
 
 
 def _tile(dim: int, cap: int) -> int:

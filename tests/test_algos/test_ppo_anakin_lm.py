@@ -81,6 +81,22 @@ def test_two_iterations_one_compile_finite_losses(tmp_path, trace_hygiene, devic
     assert not backward & {"rollout.prefill", "rollout.decode", "ppo.optim"}
 
 
+def test_the_iter_span_carries_how_often_the_routed_layer_compacted(tmp_path):
+    """A dry run whose sequences are long enough for the share to leave rows out (2 of 8 experts held, 1016 + 8
+    positions: 1024 of a call's ~2048 sorted rows moved): the block counts the calls that could compact and those
+    that did, and the host loop puts both on the block's `iter` span."""
+    profiler.reset()
+    run([*_args(tmp_path, 1, iterations=1), "dry_run=True", "algo.lm.experts_held=2", "env.prompt_len=1016"])
+    (span,) = [s for s in profiler.snapshot()["spans"] if s["name"] == "iter"]
+    # 4 layers x (2 prompts prefilled + 2 gradient steps' forwards); a fresh router sends a quarter to 2 of 8 experts
+    assert span["counters"]["moe_compactable_calls"] == 16 and span["counters"]["moe_compact_calls"] == 16
+    # at the toy sizes of the other tests every row is moved anyway: nothing could compact, and the span says so
+    profiler.reset()
+    run([*_args(tmp_path, 1, iterations=1), "dry_run=True"])
+    (span,) = [s for s in profiler.snapshot()["spans"] if s["name"] == "iter"]
+    assert span["counters"]["moe_compactable_calls"] == 0 and span["counters"]["moe_compact_calls"] == 0
+
+
 def _toy_block(extra=()):
     """The block at toy widths on one device, with a fresh state for every call (the block donates it)."""
     import jax
