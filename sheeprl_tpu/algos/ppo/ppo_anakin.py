@@ -600,8 +600,10 @@ def main(fabric, cfg: Dict[str, Any]):
                 )
             metrics = jax.device_get(metrics)
             # what the block counted of itself, for the record's readers (a block without the counter sets none)
-            iter_span.set(**{k: int(np.sum(metrics[k])) for k in ("moe_compact_calls", "moe_compactable_calls")
-                             if k in metrics})
+            iter_span.set(**{k: int(np.sum(metrics[k])) for k in ("moe_compact_calls", "moe_compactable_calls",
+                                                                    "moe_bias_moved", "moe_bias_movable") if k in metrics})
+            if "rollout_cache_bytes" in metrics:  # a size, the same every iteration of the block
+                iter_span.set(rollout_cache_bytes=int(np.max(metrics["rollout_cache_bytes"])))
 
         # Host-side bookkeeping for the fused block, iteration by iteration
         # (same counters/cadence the host loop maintains per iteration)

@@ -6,8 +6,9 @@ One iteration, all inside the one jitted ``shard_map`` block, is one batch of
 prompts, as in language-model post-training:
 
 - every env resets (a new prompt each);
-- **rollout**: prefill of the prompts (a full-sequence forward that fills both
-  kinds of cache), then a ``lax.scan`` of ``rollout_steps`` decode steps that
+- **rollout**: prefill of the prompts (a full-sequence forward that fills the
+  cache, whichever kinds of state the policy's layers keep), then a
+  ``lax.scan`` of ``rollout_steps`` decode steps that
   carries ``(env state, cache, logits, value, key)``: each step samples a
   token from the categorical over the held vocabulary, records token,
   log-probability and value, steps the env and decodes the token through the
@@ -29,7 +30,11 @@ losses and gradient norms of the update and the routed layer's counters
 less the rows the grouped products were handed, :func:`decoder_lm.moe_share`;
 ``moe_compact_calls`` of ``moe_compactable_calls``: of the routed layer's
 calls that could move only the head of their sorted rows, prefill's and the
-update's forwards, those that did).
+update's forwards, those that did; ``rollout_cache_bytes``: what the rollout's
+cache holds on all devices, from its arrays' sizes; and, for a policy whose
+router has a selection bias, ``moe_bias_moved`` of ``moe_bias_movable``: of
+the assignments made in prefill and the update's forwards, those that the
+unbiased scores would not have kept).
 ``algo.ferry_rollout`` adds what the rollout recorded (tokens,
 log-probabilities, values, rewards) to the metrics, for a check against
 another implementation.
@@ -80,6 +85,12 @@ def build_lm_agent(fabric, cfg, jenv, agent_state=None):
     return policy, fabric.put_replicated(params)
 
 
+def routed_assignments(policy: LMPolicy, tokens: int) -> int:
+    """The assignments the routed layers make for ``tokens`` tokens of one sequence."""
+    model = policy.model
+    return tokens * model.top_k * (model.layers - model.dense_layers)
+
+
 def _response_outputs(policy: LMPolicy, params, tokens):
     """New log-probabilities, entropies and values on the response positions
     of ``tokens`` (B, P + R), and the routing counters. The head is applied to
@@ -110,7 +121,7 @@ def make_sequence_train(policy: LMPolicy, tx, cfg, local_envs: int, guard: bool)
     vf_coef, reduction = float(cfg.algo.vf_coef), str(cfg.algo.loss_reduction)
 
     total = update_epochs * n_mb
-    layers = policy.model.layers
+    layers, width = policy.model.layers, policy.model.counters
 
     def gradient_step(params, opt_state, batch, clip_coef, ent_coef):
         advantages = batch["advantages"]
@@ -157,7 +168,7 @@ def make_sequence_train(policy: LMPolicy, tx, cfg, local_envs: int, guard: bool)
 
         # a step that is not granted is not run: its row of the per-step outputs stays zero
         out = {k: jnp.zeros((total,), jnp.float32) for k in ("pg", "v", "ent", "grad_norm", "bad")}
-        out["counters"] = jnp.zeros((total, layers, 5), jnp.int32)
+        out["counters"] = jnp.zeros((total, layers, width), jnp.int32)
         granted = jnp.clip(grad_steps, 0, total)
         params, opt_state, out = jax.lax.fori_loop(0, granted, one_step, (params, opt_state, out))
         steps = {k: out[k] for k in ("pg", "v", "ent")}
@@ -165,12 +176,15 @@ def make_sequence_train(policy: LMPolicy, tx, cfg, local_envs: int, guard: bool)
         metrics = {k: jax.lax.pmean(x.sum() / ran, "dp") for k, x in steps.items()}
         metrics.update({k + "_steps": jax.lax.pmean(x, "dp") for k, x in steps.items()})
         metrics["grad_norm_steps"] = out["grad_norm"]
-        # counters: (steps, layers, 5) -> what the update's forwards saw, per layer
+        # counters: (steps, layers, 5 or 6) -> what the update's forwards saw, per layer
         metrics["moe_local_assignments"] = jax.lax.psum(out["counters"][..., 0].sum(axis=0), "dp")
         metrics["moe_max_expert_load"] = jax.lax.pmax(out["counters"][..., 1].max(axis=0), "dp")
         metrics["moe_dropped"] = out["counters"][..., 2].sum()
         metrics["moe_compact_calls"] = out["counters"][..., 3].sum()
         metrics["moe_compactable_calls"] = out["counters"][..., 4].sum()
+        if policy.model.selection_bias:  # the router's selection bias: the assignments it moved, of those the granted steps made
+            metrics["moe_bias_moved"] = out["counters"][..., 5].sum()
+            metrics["moe_bias_movable"] = granted * mb_size * routed_assignments(policy, policy.prompt_len + policy.response_len)
         if guard:
             metrics["bad"] = out["bad"].sum()
         return params, opt_state, metrics
@@ -202,6 +216,7 @@ def make_anakin_lm_local_block(policy: LMPolicy, tx, cfg, benv, local_envs: int,
             x, cache, prefill_counters = jax.lax.map(lambda p: lm.prefill(model, params, p[None], P_ + R), prompts)
             x, cache = jax.tree.map(lambda a: a[:, 0], (x, cache))
             logits, value = lm.heads(model, params, x)
+        cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
 
         def decode(carry, t):
             env_state, cache, logits, value, key = carry
@@ -221,14 +236,14 @@ def make_anakin_lm_local_block(policy: LMPolicy, tx, cfg, benv, local_envs: int,
         (env_state, _, _, last_value, key), traj = jax.lax.scan(
             decode, (env_state, cache, logits, value, key), jnp.arange(R)
         )
-        counters = prefill_counters.sum(axis=0) + traj.pop("counters").sum(axis=0)  # (layers, 5): prefill and decode
-        return env_state, prompts, traj, last_value, key, counters
+        counters = prefill_counters.sum(axis=0) + traj.pop("counters").sum(axis=0)  # (layers, 5 or 6): prefill and decode
+        return env_state, prompts, traj, last_value, key, counters, cache_bytes
 
     def local_block(params, opt_state, env_state, obs, ep_ret, ep_len, env_keys, train_key, clip_coef, ent_coef, env_params,
                     grad_steps):
         def one_iter(carry, train_key):
             params, opt_state, _, _, ep_ret, ep_len, env_key = carry
-            env_state, prompts, traj, last_value, env_key, rollout_counters = rollout(params, env_key, env_params)
+            env_state, prompts, traj, last_value, env_key, rollout_counters, cache_bytes = rollout(params, env_key, env_params)
             with jax.named_scope("ppo.gae"):
                 returns, advantages = gae_op(
                     traj["rewards"][..., None], traj["values"][..., None], traj["dones"][..., None],
@@ -242,8 +257,15 @@ def make_anakin_lm_local_block(policy: LMPolicy, tx, cfg, benv, local_envs: int,
             }
             params, opt_state, metrics = sequence_train(params, opt_state, data, train_key, clip_coef, ent_coef, grad_steps)
             metrics["moe_rollout_assignments"] = jax.lax.psum(rollout_counters[:, 0], "dp")
-            for name, column in (("moe_dropped", 2), ("moe_compact_calls", 3), ("moe_compactable_calls", 4)):
+            columns = [("moe_dropped", 2), ("moe_compact_calls", 3), ("moe_compactable_calls", 4)]
+            if model.selection_bias:  # prefill's assignments could be moved too (decode counts none)
+                columns.append(("moe_bias_moved", 5))
+                metrics["moe_bias_movable"] = jax.lax.psum(
+                    metrics["moe_bias_movable"] + local_envs * routed_assignments(policy, P_), "dp")
+            for name, column in columns:
                 metrics[name] = jax.lax.psum(metrics[name] + rollout_counters[:, column].sum(), "dp")
+            # what the rollout's cache held, every device's: a size, known when the block is traced (float32: over 2**31)
+            metrics["rollout_cache_bytes"] = jnp.float32(cache_bytes * jax.lax.psum(1, "dp"))
             ep_return = traj["raw_rewards"].sum(axis=0)
             metrics["reward"] = jax.lax.pmean(ep_return.mean(), "dp")
             if ferry_episodes:
@@ -264,10 +286,12 @@ def make_anakin_lm_local_block(policy: LMPolicy, tx, cfg, benv, local_envs: int,
     return local_block
 
 
-def metric_specs(ferry_episodes: bool, guard: bool, ferry_rollout: bool) -> Dict[str, Any]:
+def metric_specs(ferry_episodes: bool, guard: bool, ferry_rollout: bool, selection_bias: bool = False) -> Dict[str, Any]:
     specs = {k: P() for k in ("pg", "v", "ent", "pg_steps", "v_steps", "ent_steps", "grad_norm_steps",
                               "moe_local_assignments", "moe_rollout_assignments", "moe_max_expert_load", "moe_dropped",
-                              "moe_compact_calls", "moe_compactable_calls", "reward")}
+                              "moe_compact_calls", "moe_compactable_calls", "rollout_cache_bytes", "reward")}
+    if selection_bias:
+        specs.update(moe_bias_moved=P(), moe_bias_movable=P())
     if guard:
         specs["bad"] = P()
     if ferry_episodes:
@@ -291,7 +315,8 @@ def make_anakin_lm_block(policy: LMPolicy, tx, cfg, mesh, benv, local_envs: int,
         local_block, mesh=mesh,
         in_specs=(P(), P(), env_sharded, env_sharded, env_sharded, env_sharded, env_sharded, P(), P(), P(), P(), P()),
         out_specs=(P(), P(), env_sharded, env_sharded, env_sharded, env_sharded, env_sharded,
-                   metric_specs(ferry_episodes, guard, bool(cfg.algo.get("ferry_rollout", False)))),
+                   metric_specs(ferry_episodes, guard, bool(cfg.algo.get("ferry_rollout", False)),
+                                policy.model.selection_bias)),
         check_vma=False,
     )
     env_out, rep_out = NamedSharding(mesh, env_sharded), NamedSharding(mesh, P())
@@ -316,10 +341,18 @@ TOY_OVERRIDES = (
     "algo.lm.num_hidden_layers=4", "algo.lm.sliding_window_size=8", "algo.lm.vocab_size=96", "algo.lm.vocab_held=64",
     "env.prompt_len=24", "algo.rollout_steps=8",
 )
+#: the second policy at toy widths: a dense layer and two routed ones with shared experts, latent attention
+TOY_OVERRIDES_LATENT = (
+    "exp=ppo_anakin_lm_kanana2", "algo.lm.hidden_size=64", "algo.lm.num_attention_heads=4", "algo.lm.num_key_value_heads=4",
+    "algo.lm.qk_nope_head_dim=16", "algo.lm.qk_rope_head_dim=8", "algo.lm.qk_head_dim=24", "algo.lm.v_head_dim=16",
+    "algo.lm.kv_lora_rank=32", "algo.lm.intermediate_size=96", "algo.lm.moe_intermediate_size=32",
+    "algo.lm.n_routed_experts=8", "algo.lm.num_experts_per_tok=2", "algo.lm.experts_held=4", "algo.lm.expert_offset=2",
+    "algo.lm.num_hidden_layers=3", "algo.lm.vocab_size=96", "algo.lm.vocab_held=64", "env.prompt_len=24",
+    "algo.rollout_steps=8",
+)
 
 
-@register_audit_programs("ppo_anakin_lm.block")
-def _audit_programs(spec: AuditMesh):
+def _audit_block(spec: AuditMesh, name: str, overrides):
     from sheeprl_tpu.algos.ppo.ppo import _abstract_like
     from sheeprl_tpu.config import compose
     from sheeprl_tpu.envs.jax_envs import BatchedJaxEnv, make_jax_env
@@ -327,7 +360,7 @@ def _audit_programs(spec: AuditMesh):
 
     mesh = spec.build()
     num_envs = 2 * spec.devices
-    cfg = compose([*TOY_OVERRIDES, f"env.num_envs={num_envs}", "algo.per_rank_batch_size=1"])
+    cfg = compose([*overrides, f"env.num_envs={num_envs}", "algo.per_rank_batch_size=1"])
     model = lm.DecoderConfig.from_config(cfg.algo.lm)
     jenv = make_jax_env(cfg.env.id, vocab_size=model.vocab_held, prompt_len=int(cfg.env.prompt_len),
                         response_len=int(cfg.algo.rollout_steps))
@@ -342,8 +375,8 @@ def _audit_programs(spec: AuditMesh):
     env_state, obs = jax.eval_shape(benv.reset, jax.random.PRNGKey(1))
     fn = make_anakin_lm_block(policy, tx, cfg, mesh, benv, num_envs // spec.devices, 2, ferry_episodes=True, guard=True)
     scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)
-    yield AuditProgram(
-        name="ppo_anakin_lm.block",
+    return AuditProgram(
+        name=name,
         fn=fn,
         args=(
             _abstract_like(params, rep), _abstract_like(opt_state, rep), _abstract_like(env_state, env_sh),
@@ -360,3 +393,13 @@ def _audit_programs(spec: AuditMesh):
         mesh=mesh,
         wire_dtype=spec.wire_dtype,
     )
+
+
+@register_audit_programs("ppo_anakin_lm.block")
+def _audit_programs(spec: AuditMesh):
+    yield _audit_block(spec, "ppo_anakin_lm.block", TOY_OVERRIDES)
+
+
+@register_audit_programs("ppo_anakin_lm.block_latent")
+def _audit_programs_latent(spec: AuditMesh):
+    yield _audit_block(spec, "ppo_anakin_lm.block_latent", TOY_OVERRIDES_LATENT)

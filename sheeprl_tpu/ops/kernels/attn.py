@@ -1,11 +1,15 @@
-"""Causal grouped-query attention with an optional sliding window, never as a
-``(T, T)`` array.
+"""Causal attention over grouped or ungrouped key-value heads with an optional
+sliding window, never as a ``(T, T)`` array.
 
-``window_attention(q, k, v, window)``: ``q`` is ``(B, T, Hq, D)``, ``k`` and
-``v`` ``(B, T, Hkv, D)`` with ``Hq`` a multiple of ``Hkv`` (query head ``h``
-reads key-value head ``h // (Hq // Hkv)``); query ``i`` sees keys ``j`` with
-``j <= i`` and, where ``window`` is a number, ``i - window < j`` (the window
-counts the query's own position). ``window`` is static: ``0`` means none.
+``window_attention(q, k, v, window)``: ``q`` is ``(B, T, Hq, D)``, ``k``
+``(B, T, Hkv, D)`` and ``v`` ``(B, T, Hkv, Dv)`` with ``Hq`` a multiple of
+``Hkv`` (query head ``h`` reads key-value head ``h // (Hq // Hkv)``; one each
+where they are as many) and ``Dv`` the values' own head size, which is the
+output's (``(B, T, Hq, Dv)``; latent attention's expanded form has ``D`` 192
+and ``Dv`` 128). Scores are scaled by ``D ** -0.5``; query ``i`` sees keys
+``j`` with ``j <= i`` and, where ``window`` is a number, ``i - window < j``
+(the window counts the query's own position). ``window`` is static: ``0``
+means none.
 
 - reference tier: a scan over query blocks and, inside it, over the key blocks
   the mask can reach, on :func:`sheeprl_tpu.ops.attention.block_attention` and
@@ -54,7 +58,7 @@ def _expand(x, groups):  # (B, b, Hkv, D) -> (B, b, Hkv * groups, D)
 
 def window_attention_reference(q, k, v, window=0):
     B, T, Hq, D = q.shape
-    groups = Hq // k.shape[2]
+    groups, Dv = Hq // k.shape[2], v.shape[-1]
     scale = D**-0.5
     block, n, reach = _blocking(T, window)
     qb = q.reshape(B, n, block, Hq, D).swapaxes(0, 1)
@@ -75,7 +79,7 @@ def window_attention_reference(q, k, v, window=0):
             return jax.lax.cond(ki >= 0, visit, lambda acc: acc, acc), None
 
         acc0 = (
-            jnp.zeros((B, block, Hq, D), jnp.float32),
+            jnp.zeros((B, block, Hq, Dv), jnp.float32),
             jnp.full((B, Hq, block), -1e30, jnp.float32),
             jnp.zeros((B, Hq, block), jnp.float32),
         )
@@ -83,7 +87,7 @@ def window_attention_reference(q, k, v, window=0):
         return (out / jnp.transpose(l, (0, 2, 1))[..., None]).astype(q.dtype)
 
     _, o = jax.lax.scan(lambda _, xs: (None, one_query_block(*xs)), None, (jnp.arange(n), qb))
-    return o.swapaxes(0, 1).reshape(B, T, Hq, D)
+    return o.swapaxes(0, 1).reshape(B, T, Hq, Dv)
 
 
 def _splash_kernel(seq: int, heads_per_kv: int, window: int, interpret: bool):
@@ -111,7 +115,7 @@ def _splash(q, k, v, window, interpret=False):
     kh = k.astype(jnp.bfloat16).transpose(0, 2, 1, 3)
     vh = v.astype(jnp.bfloat16).transpose(0, 2, 1, 3)
     o = jax.vmap(jax.vmap(kernel))(qh, kh, vh)
-    return o.transpose(0, 3, 1, 2, 4).reshape(B, T, Hq, D).astype(q.dtype)
+    return o.transpose(0, 3, 1, 2, 4).reshape(B, T, Hq, v.shape[-1]).astype(q.dtype)
 
 
 def window_attention_pallas(q, k, v, window=0):
@@ -124,7 +128,8 @@ registry.register(
     "window_attention",
     reference=window_attention_reference,
     pallas=window_attention_pallas,
-    doc="causal grouped-query attention, whole or over a sliding window, blockwise",
+    doc="causal attention, grouped key-value heads or one a query head, values of their own head size, whole or over a "
+        "sliding window, blockwise",
 )
 
 

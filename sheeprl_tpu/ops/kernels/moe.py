@@ -1,15 +1,16 @@
 """Grouped expert feed-forward: the routed layer's three matrix products over
 rows already sorted by expert.
 
-``moe_grouped_ffn(xs, w_gate, w_up, w_down, group_sizes)``: ``xs`` is
+``moe_grouped_ffn(xs, w_gate, w_up, w_down, group_sizes, activation)``: ``xs`` is
 ``(M, hidden)`` with the rows of expert 0 first, then expert 1, ... (``E``
 experts held, ``group_sizes[e]`` rows each); rows past ``sum(group_sizes)``
 belong to no expert held here and come back as zeros. ``M`` is whatever the
 caller sorted and cut: every assignment of its tokens, or the head of them
 that its experts' rows fit (``sum(group_sizes) <= M`` is the caller's to
-see to; a multiple of ``GMM_ROW_TILE`` tiles evenly). Each expert is the ReGLU
-``W_down (relu(W_gate u) * (W_up u))``. No capacity: a group may hold every
-row or none.
+see to; a multiple of ``GMM_ROW_TILE`` tiles evenly). Each expert is the gated
+``W_down (act(W_gate u) * (W_up u))`` with ``activation`` (static) ``"relu"``
+(ReGLU, the default) or ``"silu"`` (SwiGLU). No capacity: a group may hold
+every row or none.
 
 - reference tier: ``lax.ragged_dot`` (plain lax, differentiable as it is);
 - kernel tier: the grouped matrix product that ships in jax
@@ -27,7 +28,10 @@ import jax.numpy as jnp
 
 from sheeprl_tpu.ops.kernels import registry
 
-__all__ = ["moe_grouped_ffn", "moe_grouped_ffn_reference", "moe_grouped_ffn_pallas", "GMM_ROW_TILE"]
+__all__ = ["moe_grouped_ffn", "moe_grouped_ffn_reference", "moe_grouped_ffn_pallas", "GMM_ROW_TILE", "ACTIVATIONS"]
+
+#: the gate's activation, by the name a caller gives
+ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
 
 # (rows, contraction, columns) tiles of the grouped product at most this large
 GMM_TILING = (512, 1024, 1024)
@@ -47,8 +51,8 @@ def _zero_rows_past(y: jax.Array, group_sizes: jax.Array) -> jax.Array:
     return jnp.where(rows < jnp.sum(group_sizes), y, jnp.zeros((), y.dtype))
 
 
-def _ffn(product, xs, w_gate, w_up, w_down, group_sizes):
-    """The three grouped products of the ReGLU experts. A grouped product
+def _ffn(product, xs, w_gate, w_up, w_down, group_sizes, activation="relu"):
+    """The three grouped products of the gated experts. A grouped product
     leaves the rows past the last group unwritten on the chip, forward and
     backward alike: they are zeroed on the way in and on the way out of each
     product, so that the same holds for every gradient."""
@@ -57,12 +61,12 @@ def _ffn(product, xs, w_gate, w_up, w_down, group_sizes):
     def guarded(lhs, rhs):
         return _zero_rows_past(product(_zero_rows_past(lhs, group_sizes), rhs, group_sizes), group_sizes)
 
-    hidden = jax.nn.relu(guarded(xs, w_gate)) * guarded(xs, w_up)
+    hidden = ACTIVATIONS[activation](guarded(xs, w_gate)) * guarded(xs, w_up)
     return guarded(hidden, w_down).astype(xs.dtype)
 
 
-def moe_grouped_ffn_reference(xs, w_gate, w_up, w_down, group_sizes):
-    return _ffn(jax.lax.ragged_dot, xs, w_gate, w_up, w_down, group_sizes)
+def moe_grouped_ffn_reference(xs, w_gate, w_up, w_down, group_sizes, activation="relu"):
+    return _ffn(jax.lax.ragged_dot, xs, w_gate, w_up, w_down, group_sizes, activation)
 
 
 def _gmm(lhs, rhs, group_sizes, interpret):
@@ -75,13 +79,14 @@ def _gmm(lhs, rhs, group_sizes, interpret):
     )
 
 
-def _grouped_ffn_gmm(xs, w_gate, w_up, w_down, group_sizes, interpret=False):
-    return _ffn(functools.partial(_gmm, interpret=interpret), xs, w_gate, w_up, w_down, group_sizes)
+def _grouped_ffn_gmm(xs, w_gate, w_up, w_down, group_sizes, activation="relu", interpret=False):
+    return _ffn(functools.partial(_gmm, interpret=interpret), xs, w_gate, w_up, w_down, group_sizes, activation)
 
 
-def moe_grouped_ffn_pallas(xs, w_gate, w_up, w_down, group_sizes):
+def moe_grouped_ffn_pallas(xs, w_gate, w_up, w_down, group_sizes, activation="relu"):
     return registry.platform_dispatch(
-        _grouped_ffn_gmm, moe_grouped_ffn_reference, xs, w_gate, w_up, w_down, group_sizes
+        functools.partial(_grouped_ffn_gmm, activation=activation),
+        functools.partial(moe_grouped_ffn_reference, activation=activation), xs, w_gate, w_up, w_down, group_sizes
     )
 
 
@@ -89,9 +94,9 @@ registry.register(
     "moe_grouped_ffn",
     reference=moe_grouped_ffn_reference,
     pallas=moe_grouped_ffn_pallas,
-    doc="ReGLU experts over rows sorted by expert: three grouped matrix products, no dropped row",
+    doc="gated experts (ReGLU or SwiGLU) over rows sorted by expert: three grouped matrix products, no dropped row",
 )
 
 
-def moe_grouped_ffn(xs, w_gate, w_up, w_down, group_sizes):
-    return registry.dispatch("moe_grouped_ffn")(xs, w_gate, w_up, w_down, group_sizes)
+def moe_grouped_ffn(xs, w_gate, w_up, w_down, group_sizes, activation="relu"):
+    return registry.dispatch("moe_grouped_ffn")(xs, w_gate, w_up, w_down, group_sizes, activation)

@@ -89,7 +89,9 @@ LM_BLOCK_REGIONS: Tuple[str, ...] = (
     "lm.embed",
     "lm.attn_global",
     "lm.attn_window",
+    "lm.attn_mla",
     "lm.moe",
+    "lm.ffn_shared",
     "lm.head_loss",
     "ppo.gae",
     "ppo.optim",

@@ -479,6 +479,10 @@ def _kernel_cases(rng):
                               jnp.asarray([0, 700, 90, 10], jnp.int32))
     yield "window_attention", (normal(1, 1024, 14, 128), normal(1, 1024, 2, 128), normal(1, 1024, 2, 128), 0)
     yield "window_attention", (normal(1, 1024, 14, 128), normal(1, 1024, 2, 128), normal(1, 1024, 2, 128), 256)
+    # latent attention's expanded form: a key-value head a query head, 192-wide queries and keys, 128-wide values
+    yield "window_attention", (normal(1, 1024, 4, 192), normal(1, 1024, 4, 192), normal(1, 1024, 4, 128), 0)
+    yield "moe_grouped_ffn", (normal(1536, 256), normal(4, 256, 128), normal(4, 256, 128), normal(4, 128, 256),
+                              jnp.asarray([0, 700, 90, 10], jnp.int32), "silu")
 
 
 def _split_statics(args):

@@ -162,7 +162,7 @@ def test_every_span_is_entered_as_a_trace_annotation(monkeypatch):
 
 def test_span_names_and_regions_are_listed_once():
     assert len(set(profiler.SPANS)) == len(profiler.SPANS) == 10
-    assert len(set(profiler.REGIONS)) == len(profiler.REGIONS) == 22
+    assert len(set(profiler.REGIONS)) == len(profiler.REGIONS) == 24  # 12 of the bursts, 12 of the language-model block (PR 36: +2)
     assert len(profiler.BURST_REGIONS) == 12 and profiler.REGIONS == profiler.BURST_REGIONS + profiler.LM_BLOCK_REGIONS
     assert all(name == name.lower() for name in profiler.SPANS + profiler.REGIONS)
     assert profiler.scope_table("no such program") is None
