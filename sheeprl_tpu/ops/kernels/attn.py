@@ -18,7 +18,11 @@ means none.
   pass holds one query block's scores at a time and never the whole mask's;
 - kernel tier: ``splash_attention`` (ships in jax) with a ``CausalMask`` or a
   ``LocalMask``, one multi-query kernel per key-value head, on bfloat16
-  operands.
+  operands; a sequence longer than one ``SPLASH_BLOCK`` that no
+  ``SPLASH_BLOCK`` divides is padded at its end to the next multiple
+  (:func:`splash_len`) and run on full blocks, and the padded rows are
+  dropped: no real query reaches a key after it, so the output and every
+  gradient are those of the sequence as it came (PERF.md finding 37).
 """
 
 from __future__ import annotations
@@ -31,7 +35,8 @@ import jax.numpy as jnp
 from sheeprl_tpu.ops.attention import block_attention, online_softmax_merge
 from sheeprl_tpu.ops.kernels import registry
 
-__all__ = ["window_attention", "window_attention_reference", "window_attention_pallas"]
+__all__ = ["kernel_tier_positions", "splash_len", "window_attention", "window_attention_reference",
+           "window_attention_pallas"]
 
 BLOCK = 512  # rows of a query block and of a key block, at most, in the lax tier
 SPLASH_BLOCK = 512  # and in the kernel tier, which wants multiples of 128
@@ -41,6 +46,22 @@ def _block_for(seq: int, cap: int) -> int:
     """The largest of ``cap``, ``cap / 2``, ``cap / 4`` that divides ``seq``;
     the whole of a sequence none divides (small ones, in tests)."""
     return next((b for b in (cap, cap // 2, cap // 4) if b and seq % b == 0), seq)
+
+
+def splash_len(seq: int) -> int:
+    """The length the kernel tier runs ``seq`` positions at: the next multiple
+    of ``SPLASH_BLOCK`` for a sequence longer than one block, ``seq`` itself
+    for one block or less (whose blocks ``_block_for`` picks)."""
+    return -(-seq // SPLASH_BLOCK) * SPLASH_BLOCK if seq > SPLASH_BLOCK else seq
+
+
+def kernel_tier_positions(seq: int):
+    """``(padded, handed)``: the positions the kernel tier pads one call of
+    ``seq`` positions by, and the call's own; ``(0, 0)`` where this process
+    runs the lax tier. From the shape and the registry alone, at trace time."""
+    if registry.resolve("window_attention") != "pallas":
+        return 0, 0
+    return splash_len(seq) - seq, seq
 
 
 def _blocking(seq: int, window: int):
@@ -108,13 +129,20 @@ def _splash(q, k, v, window, interpret=False):
     B, T, Hq, D = q.shape
     Hkv = k.shape[2]
     groups = Hq // Hkv
-    kernel = _splash_kernel(T, groups, int(window), bool(interpret))
+    Tp = splash_len(T)
+    kernel = _splash_kernel(Tp, groups, int(window), bool(interpret))
     scale = D**-0.5
-    # (B, Hkv, groups, T, D) queries against (B, Hkv, T, D) keys and values
-    qh = (q * scale).astype(jnp.bfloat16).reshape(B, T, Hkv, groups, D).transpose(0, 2, 3, 1, 4)
-    kh = k.astype(jnp.bfloat16).transpose(0, 2, 1, 3)
-    vh = v.astype(jnp.bfloat16).transpose(0, 2, 1, 3)
+
+    def pad(x):  # in the cast and transpose that copy it anyway
+        return jnp.pad(x, ((0, 0), (0, Tp - T), (0, 0), (0, 0))) if Tp != T else x
+
+    # (B, Hkv, groups, Tp, D) queries against (B, Hkv, Tp, D) keys and values
+    qh = pad((q * scale).astype(jnp.bfloat16)).reshape(B, Tp, Hkv, groups, D).transpose(0, 2, 3, 1, 4)
+    kh = pad(k.astype(jnp.bfloat16)).transpose(0, 2, 1, 3)
+    vh = pad(v.astype(jnp.bfloat16)).transpose(0, 2, 1, 3)
     o = jax.vmap(jax.vmap(kernel))(qh, kh, vh)
+    if Tp != T:  # the padded rows: thrown away, so their cotangent is zero and adds to no gradient
+        o = o[..., :T, :]
     return o.transpose(0, 3, 1, 2, 4).reshape(B, T, Hq, v.shape[-1]).astype(q.dtype)
 
 

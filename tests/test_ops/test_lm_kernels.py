@@ -45,16 +45,45 @@ def test_window_attention_never_builds_a_whole_score_matrix():
     assert "2048x2048" not in text and "512x512" in text
 
 
-@pytest.mark.parametrize("window", [0, 128], ids=["full", "window"])
-def test_window_attention_kernel_tier_in_interpret_mode(window):
-    q, k, v = _qkv(256, D=128, B=1)
+# (T, SPLASH_BLOCK): one whole block; 320 and 448 on blocks of 128, which none divides, run at 384 and 512
+LENGTHS = {"one-block": (256, 512), "320-padded-to-384": (320, 128), "448-padded-to-512": (448, 128)}
+
+
+def _against_the_lax_tier(q, k, v, window):
+    """The kernel tier's forward and the gradients of q, k and v against the lax tier's, bfloat16 operands."""
     got = attn._splash(q, k, v, window, interpret=True)
-    want = _dense_attention(q, k, v, window)
-    assert float(jnp.max(jnp.abs(got - want))) < 3e-2  # bfloat16 operands
+    want = K.window_attention_reference(q, k, v, window)
+    assert got.shape == want.shape and float(jnp.max(jnp.abs(got - want))) < 3e-2
     ours = jax.grad(lambda *a: jnp.sum(attn._splash(*a, window, interpret=True) * want), (0, 1, 2))(q, k, v)
-    dense = jax.grad(lambda *a: jnp.sum(_dense_attention(*a, window) * want), (0, 1, 2))(q, k, v)
-    for a, b in zip(ours, dense):
-        assert float(jnp.max(jnp.abs(a - b))) < 5e-2 * (1 + float(jnp.max(jnp.abs(b))))
+    lax = jax.grad(lambda *a: jnp.sum(K.window_attention_reference(*a, window) * want), (0, 1, 2))(q, k, v)
+    for a, b in zip(ours, lax):
+        assert a.shape == b.shape and float(jnp.max(jnp.abs(a - b))) < 5e-2 * (1 + float(jnp.max(jnp.abs(b))))
+
+
+@pytest.mark.parametrize("length", list(LENGTHS.values()), ids=list(LENGTHS))
+@pytest.mark.parametrize("window", [0, 128], ids=["full", "window"])
+def test_window_attention_kernel_tier_in_interpret_mode(window, length, monkeypatch):
+    T, block = length
+    monkeypatch.setattr(attn, "SPLASH_BLOCK", block)
+    assert attn.splash_len(T) == {256: 256, 320: 384, 448: 512}[T]
+    _against_the_lax_tier(*_qkv(T, D=128, B=1), window)
+
+
+@pytest.mark.parametrize("T, run", [(7936, 8192), (8192, 8192), (300, 300), (512, 512)])
+def test_the_kernel_tiers_length_is_a_function_of_the_shape_alone(T, run):
+    """Prefill's 7 936 runs at 8 192 on 512-row blocks; the update's 8 192 and one block or less as they come, with
+    no pad and no slice in the program (the update's program is the one it was)."""
+    assert attn.splash_len(T) == run
+    if T % 128 == 0:  # the library's blocks are whole 128-lane tiles: a 300 is refused as it was before
+        q, k, v = (jax.ShapeDtypeStruct((1, T, 2, d), jnp.float32) for d in (192, 192, 128))
+        # the program's own steps (jnp.pad is a jit named "_pad"), not what the library's kernel holds inside
+        jaxpr = jax.make_jaxpr(lambda *a: attn._splash(*a, 0))(q, k, v).jaxpr
+        steps = {e.params.get("name", e.primitive.name) for e in jaxpr.eqns}
+        assert ("_pad" in steps) == ("slice" in steps) == (run != T)
+    with K.use_backend("pallas"):
+        assert attn.kernel_tier_positions(T) == (run - T, T)
+    with K.use_backend("lax"):
+        assert attn.kernel_tier_positions(T) == (0, 0)
 
 
 def test_window_attention_dispatch():
@@ -137,16 +166,12 @@ def test_window_attention_lax_tier_with_narrower_values(window, heads, monkeypat
         np.testing.assert_allclose(a, b, atol=5e-6)
 
 
-def test_window_attention_kernel_tier_with_narrower_values_in_interpret_mode():
+@pytest.mark.parametrize("length", list(LENGTHS.values()), ids=list(LENGTHS))
+def test_window_attention_kernel_tier_with_narrower_values_in_interpret_mode(length, monkeypatch):
     """The published head sizes: 192 for queries and keys (not a whole number of 128-lane tiles), 128 for values."""
-    q, k, v = _qkv_narrow_v(256, Hq=2, Hkv=2, D=192, Dv=128, B=1)
-    got = attn._splash(q, k, v, 0, interpret=True)
-    want = _dense_attention(q, k, v, 0)
-    assert got.shape == (1, 256, 2, 128) and float(jnp.max(jnp.abs(got - want))) < 3e-2  # bfloat16 operands
-    ours = jax.grad(lambda *a: jnp.sum(attn._splash(*a, 0, interpret=True) * want), (0, 1, 2))(q, k, v)
-    dense = jax.grad(lambda *a: jnp.sum(_dense_attention(*a, 0) * want), (0, 1, 2))(q, k, v)
-    for a, b in zip(ours, dense):
-        assert a.shape == b.shape and float(jnp.max(jnp.abs(a - b))) < 5e-2 * (1 + float(jnp.max(jnp.abs(b))))
+    T, block = length
+    monkeypatch.setattr(attn, "SPLASH_BLOCK", block)
+    _against_the_lax_tier(*_qkv_narrow_v(T, Hq=2, Hkv=2, D=192, Dv=128, B=1), 0)
 
 
 # -- SiLU-gated experts beside the ReGLU ones --------------------------------------------------------------
