@@ -166,13 +166,14 @@ def check_common(name: str, out: str, devices: int) -> dict:
     check("os.fork() was called" not in out, f"{name}: something forked the process that holds JAX")
     for needle in ("restarting in", "DEGRADED"):
         check(needle not in out, f"{name}: a supervised worker was restarted or degraded ('{needle}' in its output)")
-    m = re.search(r"^compile: programs=(\d+) seconds=([\d.]+) cache_hits=(\d+) cache_writes=(\d+) cache_dir=(.*)$",
-                  out, re.M)
+    m = re.search(r"^compile: programs=(\d+) seconds=([\d.]+) cache_hits=(\d+) cache_writes=(\d+) "
+                  r"trace_seconds=([\d.]+) cache_load_seconds=([\d.]+)\S* cache_dir=(.*)$", out, re.M)
     check(m is not None, f"{name}: no 'compile:' exit line")
     return {
         "kernels": kernels, "hybrid_player": hybrid, "mesh": mesh, "device_kind": kind,
         "compile_programs": int(m.group(1)), "compile_seconds": float(m.group(2)),
-        "cache_hits": int(m.group(3)), "cache_writes": int(m.group(4)), "cache_dir": m.group(5),
+        "cache_hits": int(m.group(3)), "cache_writes": int(m.group(4)), "trace_seconds": float(m.group(5)),
+        "cache_load_seconds": float(m.group(6)), "cache_dir": m.group(7),
     }
 
 

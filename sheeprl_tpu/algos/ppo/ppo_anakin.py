@@ -407,6 +407,11 @@ def main(fabric, cfg: Dict[str, Any]):
             "ppo_anakin ferries block metrics from a single controller; use the host-loop `algo=ppo` "
             "for multi-host runs."
         )
+    from sheeprl_tpu.utils import profiler as recorder
+    from sheeprl_tpu.utils.profiler import TraceProfiler
+
+    # set-up up to the first `iter` (utils/profiler.py): env, agent, optimizer state, block builder
+    setup_span = recorder.span("setup.build", parent=recorder.ROOT).start()
 
     initial_ent_coef = copy.deepcopy(cfg.algo.ent_coef)
     initial_clip_coef = copy.deepcopy(cfg.algo.clip_coef)
@@ -570,10 +575,8 @@ def main(fabric, cfg: Dict[str, Any]):
     clip_coef = float(cfg.algo.clip_coef)
     ent_coef = float(cfg.algo.ent_coef)
 
-    from sheeprl_tpu.utils import profiler as recorder
-    from sheeprl_tpu.utils.profiler import TraceProfiler
-
     profiler = TraceProfiler(cfg.metric.get("profiler"), log_dir)
+    setup_span.stop()
 
     iter_num = start_iter - 1
     while iter_num < total_iters:
@@ -601,8 +604,7 @@ def main(fabric, cfg: Dict[str, Any]):
             metrics = jax.device_get(metrics)
             # what the block counted of itself, for the record's readers (a block without the counter sets none)
             iter_span.set(**{k: int(np.sum(metrics[k])) for k in ("moe_compact_calls", "moe_compactable_calls",
-                                                                    "moe_bias_moved", "moe_bias_movable",
-                                                                    "attn_pad_positions", "attn_kernel_positions")
+                                                                    "moe_bias_moved", "moe_bias_movable")
                              if k in metrics})
             if "rollout_cache_bytes" in metrics:  # a size, the same every iteration of the block
                 iter_span.set(rollout_cache_bytes=int(np.max(metrics["rollout_cache_bytes"])))

@@ -34,11 +34,7 @@ update's forwards, those that did; ``rollout_cache_bytes``: what the rollout's
 cache holds on all devices, from its arrays' sizes; and, for a policy whose
 router has a selection bias, ``moe_bias_moved`` of ``moe_bias_movable``: of
 the assignments made in prefill and the update's forwards, those that the
-unbiased scores would not have kept); ``attn_pad_positions`` of
-``attn_kernel_positions``: of the positions the attention's kernel tier is
-handed, one call a layer in prefill's forward and one in the update's, those
-it pads to a whole number of blocks (:func:`~sheeprl_tpu.ops.kernels.attn.kernel_tier_positions`;
-from the shapes at trace time, zeros on the lax tier).
+unbiased scores would not have kept).
 ``algo.ferry_rollout`` adds what the rollout recorded (tokens,
 log-probabilities, values, rewards) to the metrics, for a check against
 another implementation.
@@ -58,7 +54,6 @@ from jax.sharding import PartitionSpec as P
 from sheeprl_tpu.algos.ppo.loss import entropy_loss, policy_loss, value_loss
 from sheeprl_tpu.models import decoder_lm as lm
 from sheeprl_tpu.ops import gae as gae_op
-from sheeprl_tpu.ops.kernels.attn import kernel_tier_positions
 from sheeprl_tpu.parallel.comm import pmean_grads
 
 __all__ = ["LMPolicy", "build_lm_agent", "make_anakin_lm_local_block", "make_anakin_lm_block", "PROGRAM_NAME"]
@@ -271,10 +266,6 @@ def make_anakin_lm_local_block(policy: LMPolicy, tx, cfg, benv, local_envs: int,
                 metrics[name] = jax.lax.psum(metrics[name] + rollout_counters[:, column].sum(), "dp")
             # what the rollout's cache held, every device's: a size, known when the block is traced (float32: over 2**31)
             metrics["rollout_cache_bytes"] = jnp.float32(cache_bytes * jax.lax.psum(1, "dp"))
-            # what the attention's kernel tier pads and is handed, one call a layer in prefill and in the update: shapes
-            padded, handed = map(sum, zip(kernel_tier_positions(P_), kernel_tier_positions(P_ + R)))
-            metrics["attn_pad_positions"] = jnp.int32(model.layers * padded)
-            metrics["attn_kernel_positions"] = jnp.int32(model.layers * handed)
             ep_return = traj["raw_rewards"].sum(axis=0)
             metrics["reward"] = jax.lax.pmean(ep_return.mean(), "dp")
             if ferry_episodes:
@@ -298,8 +289,7 @@ def make_anakin_lm_local_block(policy: LMPolicy, tx, cfg, benv, local_envs: int,
 def metric_specs(ferry_episodes: bool, guard: bool, ferry_rollout: bool, selection_bias: bool = False) -> Dict[str, Any]:
     specs = {k: P() for k in ("pg", "v", "ent", "pg_steps", "v_steps", "ent_steps", "grad_norm_steps",
                               "moe_local_assignments", "moe_rollout_assignments", "moe_max_expert_load", "moe_dropped",
-                              "moe_compact_calls", "moe_compactable_calls", "rollout_cache_bytes", "attn_pad_positions",
-                              "attn_kernel_positions", "reward")}
+                              "moe_compact_calls", "moe_compactable_calls", "rollout_cache_bytes", "reward")}
     if selection_bias:
         specs.update(moe_bias_moved=P(), moe_bias_movable=P())
     if guard:

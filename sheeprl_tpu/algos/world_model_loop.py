@@ -362,6 +362,8 @@ def run(
     to restore. With ``random_prefill`` a fresh run acts at random until
     ``algo.learning_starts``."""
     _settle_device_resident(cfg, resumed=resume is not None)
+    # set-up up to the first `iter` (utils.profiler.SPANS): envs, agent, optimizer state, ring, burst builders
+    setup_span = recorder.span("setup.build", parent=recorder.ROOT).start()
     rank = fabric.global_rank
 
     log_dir = get_log_dir(cfg, cfg.root_dir, cfg.run_name)
@@ -470,6 +472,7 @@ def run(
 
     # looked up on the module at call time: the on-chip benchmark puts its own in its place
     profiler = recorder.TraceProfiler(cfg.metric.get("profiler"), log_dir)
+    setup_span.stop()
 
     for iter_num in range(start_iter, total_iters + 1):
         profiler.tick(iter_num)

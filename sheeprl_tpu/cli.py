@@ -194,6 +194,18 @@ def _prepare_process(cfg: DotDict) -> None:
 
 def run_algorithm(cfg: DotDict) -> None:
     """(reference: ``cli.py:59-198``)"""
+    from sheeprl_tpu.utils import profiler
+
+    # set-up's first part (utils/profiler.py; a root span: it drops what an aborted run left open on this thread);
+    # the trainer records its own, up to its first `iter`
+    with profiler.span("setup.build", parent=profiler.ROOT):
+        fabric, command, kwargs = _build_run(cfg)
+    fabric.launch(command, cfg, **kwargs)
+
+
+def _build_run(cfg: DotDict):
+    """The fabric, the trainer's entry point and its extra arguments for
+    :func:`run_algorithm`."""
     os.environ.setdefault("OMP_NUM_THREADS", str(cfg.num_threads))
     _prepare_process(cfg)
 
@@ -291,7 +303,7 @@ def run_algorithm(cfg: DotDict) -> None:
 
         return wrapper
 
-    fabric.launch(reproducible(command), cfg, **kwargs)
+    return fabric, reproducible(command), kwargs
 
 
 def eval_algorithm(cfg: DotDict) -> None:

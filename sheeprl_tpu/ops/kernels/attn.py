@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from sheeprl_tpu.ops.attention import block_attention, online_softmax_merge
 from sheeprl_tpu.ops.kernels import registry
 
-__all__ = ["kernel_tier_positions", "splash_len", "window_attention", "window_attention_reference",
+__all__ = ["splash_len", "window_attention", "window_attention_reference",
            "window_attention_pallas"]
 
 BLOCK = 512  # rows of a query block and of a key block, at most, in the lax tier
@@ -53,15 +53,6 @@ def splash_len(seq: int) -> int:
     of ``SPLASH_BLOCK`` for a sequence longer than one block, ``seq`` itself
     for one block or less (whose blocks ``_block_for`` picks)."""
     return -(-seq // SPLASH_BLOCK) * SPLASH_BLOCK if seq > SPLASH_BLOCK else seq
-
-
-def kernel_tier_positions(seq: int):
-    """``(padded, handed)``: the positions the kernel tier pads one call of
-    ``seq`` positions by, and the call's own; ``(0, 0)`` where this process
-    runs the lax tier. From the shape and the registry alone, at trace time."""
-    if registry.resolve("window_attention") != "pallas":
-        return 0, 0
-    return splash_len(seq) - seq, seq
 
 
 def _blocking(seq: int, window: int):

@@ -23,6 +23,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import random
+import time
 from typing import Any, Callable, Dict, Mapping, Optional, Sequence
 
 import jax
@@ -319,24 +320,31 @@ class Fabric:
         host↔device copy for each.
 
         Prints :meth:`describe` on the way in and the compile counters
-        (programs, seconds, persistent-cache hits and writes) on the way out.
+        (programs, seconds, persistent-cache hits and writes) on the way out,
+        with the seconds of tracing and lowering and those the persistent
+        cache served, from the recorder's ``jax.*`` spans (a ``+`` where the
+        ring dropped some of them: the seconds are then short).
         """
+        from sheeprl_tpu.utils import profiler
         from sheeprl_tpu.utils.utils import compile_stats, host_cpu_device
 
         host_cpu_device()  # a missing CPU platform is a start-up error
         cfg = args[0] if args and isinstance(args[0], Mapping) else None
         if self.is_global_zero:
             print(self.describe(cfg), flush=True)
-        before = compile_stats.snapshot()
+        before, t_launch = compile_stats.snapshot(), time.perf_counter()
         try:
             with jax.default_device(self.local_device), self.mesh:
                 return fn(self, *args, **kwargs)
         finally:
             if self.is_global_zero:
                 programs, seconds, hits, writes = (a - b for a, b in zip(compile_stats.snapshot(), before))
+                pipeline = profiler.compile_pipeline(t_launch)
                 print(
                     f"compile: programs={programs} seconds={seconds:.1f} cache_hits={hits} "
-                    f"cache_writes={writes} cache_dir={jax.config.jax_compilation_cache_dir}",
+                    f"cache_writes={writes} trace_seconds={pipeline['trace']:.1f} "
+                    f"cache_load_seconds={pipeline['cache_load']:.1f}{'' if pipeline['complete'] else '+'} "
+                    f"cache_dir={jax.config.jax_compilation_cache_dir}",
                     flush=True,
                 )
 

@@ -28,7 +28,9 @@ Three things live here and nowhere else:
   profiler session runs — so an operator's ``metric.profiler.enabled=True``
   trace shows the same spans on the device trace's clock. After a stall,
   ``snapshot()`` from a debugger or a signal handler says where each thread
-  was.
+  was. :meth:`Recorder.record` enters a span known only after it ended: JAX's
+  own compile-pipeline events (``jax.trace``, ``jax.lower``, ``jax.compile``,
+  entered by ``utils.utils.CompileStats``), which carry no annotation.
 """
 
 from __future__ import annotations
@@ -47,18 +49,21 @@ __all__ = [
     "LM_BLOCK_REGIONS",
     "KERNEL_PREFIX",
     "SPANS",
+    "JAX_SPANS",
     "CAPACITY",
     "ROOT",
     "Span",
     "Recorder",
     "RECORDER",
     "span",
+    "record",
     "snapshot",
     "reset",
     "register_program",
     "programs",
     "program",
     "scope_table",
+    "compile_pipeline",
 ]
 
 #: ``jax.named_scope`` names of the burst program's regions, outermost level.
@@ -114,7 +119,13 @@ SPANS: Tuple[str, ...] = (
     "burst.submit",
     "burst.dispatch",
     "snapshot.refresh",
+    "setup.build",
+    "jax.trace",
+    "jax.lower",
+    "jax.compile",
 )
+#: The spans of JAX's compile pipeline (``CompileStats``, from ``jax.monitoring``).
+JAX_SPANS: Tuple[str, ...] = ("jax.trace", "jax.lower", "jax.compile")
 
 CAPACITY = 65536
 #: ``parent=ROOT`` opens a span with no parent and drops whatever an aborted
@@ -221,6 +232,19 @@ class Recorder:
         :data:`ROOT`)."""
         return Span(self, name, parent, counters)
 
+    def record(self, name: str, t_start: float, t_end: float, parent: Optional[int] = None, **counters: Any) -> None:
+        """Enter a finished span known only after it ended (``perf_counter``
+        seconds). Its parent is the innermost span open on this thread, or
+        ``parent``; it starts no earlier than that span, so that it nests."""
+        span = Span(self, name, parent, counters)
+        if span.parent is None:
+            stack = self._stack()
+            span.parent = stack[-1].id if stack else ROOT
+            if stack:
+                t_start = max(t_start, stack[-1].t_start)
+        span.t_start, span.t_end = t_start, max(t_start, t_end)
+        self._ring.append(span)
+
     def snapshot(self, t0: Optional[float] = None, t1: Optional[float] = None) -> Dict[str, Any]:
         """Finished spans that overlap ``[t0, t1]`` (``perf_counter``
         seconds; ``None`` is open-ended), oldest first, with how many spans
@@ -278,12 +302,40 @@ class Recorder:
 
 RECORDER = Recorder()
 span = RECORDER.span
+record = RECORDER.record
 snapshot = RECORDER.snapshot
 reset = RECORDER.reset
 register_program = RECORDER.register_program
 programs = RECORDER.programs
 program = RECORDER.program
 scope_table = RECORDER.scope_table
+
+
+def _union_seconds(intervals: List[Tuple[float, float]]) -> float:
+    total, reach = 0.0, float("-inf")
+    for t0, t1 in sorted(intervals):
+        if t1 > reach:
+            total += t1 - max(t0, reach)
+            reach = t1
+    return total
+
+
+def compile_pipeline(t0: float) -> Dict[str, Any]:
+    """Seconds since ``t0`` in JAX's compile pipeline, from the recorded
+    ``jax.*`` spans (each a union, so that a jit traced inside another's
+    trace counts once): ``trace`` (tracing and lowering) and ``cache_load``
+    (compiles the persistent cache served); ``complete`` is False where the
+    ring has dropped spans since ``t0``, so that the seconds are short."""
+    snap = snapshot()
+    held = snap["spans"]
+    spans = [s for s in held if s["name"] in JAX_SPANS and s["t_end"] >= t0]
+    c = snap["counters"]
+    dropped = c["held"] >= c["capacity"] and min(s["t_start"] for s in held) > t0
+    return {
+        "trace": _union_seconds([(s["t_start"], s["t_end"]) for s in spans if s["name"] != "jax.compile"]),
+        "cache_load": _union_seconds([(s["t_start"], s["t_end"]) for s in spans if s["counters"].get("cache") == "hit"]),
+        "complete": not dropped,
+    }
 
 
 class TraceProfiler:

@@ -9,12 +9,15 @@ from __future__ import annotations
 
 import os
 import re
+import threading
+import time
 import warnings
 from typing import Any, Dict, Mapping, Optional, Sequence
 
 import numpy as np
 
 from sheeprl_tpu.config import DotDict, dotdict, save_config, to_yaml
+from sheeprl_tpu.utils import profiler
 
 __all__ = [
     "HostCpuUnavailableError",
@@ -97,7 +100,19 @@ def refuse_children_on_tpu(launcher: str, children: str) -> None:
 
 
 class CompileStats:
-    """Process-wide compile counters, fed by ``jax.monitoring``."""
+    """Process-wide compile counters, fed by ``jax.monitoring``; and the
+    spans of JAX's compile pipeline, entered into ``utils.profiler``'s record
+    as they end: ``jax.trace`` (jaxpr trace), ``jax.lower`` (jaxpr to MLIR
+    module), ``jax.compile`` (backend compile or persistent-cache read), each
+    with ``fn`` (JAX's ``fun_name``), ``jax.compile`` also with ``cache``:
+    ``hit``, ``miss`` (the persistent cache was asked and did not serve it)
+    or ``off`` (not asked)."""
+
+    _SPANS = {
+        "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+        "/jax/core/compile/backend_compile_duration": "jax.compile",
+    }
 
     def __init__(self) -> None:
         self._registered = False
@@ -105,6 +120,7 @@ class CompileStats:
         self.seconds = 0.0  # wall time inside them
         self.cache_hits = 0  # executables read from the persistent cache
         self.cache_writes = 0  # executables written to it
+        self._cache = threading.local()  # what the persistent cache said to this thread's open compile
 
     def register(self) -> None:
         if self._registered:
@@ -113,6 +129,7 @@ class CompileStats:
 
         jax.monitoring.register_event_listener(self._on_event)
         jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+        jax.monitoring.register_event_time_span_listener(self._on_span)
         self._registered = True
 
     def snapshot(self) -> tuple:
@@ -121,13 +138,29 @@ class CompileStats:
     def _on_event(self, event: str, **_: Any) -> None:
         if event == "/jax/compilation_cache/cache_hits":
             self.cache_hits += 1
+            self._cache.said = "hit"
         elif event == "/jax/compilation_cache/cache_misses":
             self.cache_writes += 1
+            self._cache.said = "miss"
+        elif event == "/jax/compilation_cache/compile_requests_use_cache":
+            self._cache.said = "miss"  # until a hit says otherwise; a compile under the size or time floor writes nothing
 
     def _on_duration(self, event: str, duration: float, **_: Any) -> None:
         if event == "/jax/core/compile/backend_compile_duration":
             self.programs += 1
             self.seconds += duration
+
+    def _on_span(self, event: str, start: float, end: float, fun_name: str = "", **_: Any) -> None:
+        name = self._SPANS.get(event)
+        if name is None:
+            return
+        # JAX's clock is time.time(); the recorder's is perf_counter (read now: the span ended a moment ago)
+        offset = time.perf_counter() - time.time()
+        counters = {"fn": fun_name}
+        if name == "jax.compile":
+            counters["cache"] = getattr(self._cache, "said", None) or "off"
+            self._cache.said = None
+        profiler.record(name, start + offset, end + offset, **counters)
 
 
 compile_stats = CompileStats()

@@ -2,13 +2,15 @@
 the reader of ``moe_compact_share_pct`` over the recorder's ``iter`` spans, the definitions that decide
 ``correct`` in the first language-model cell (``chipbench/tests/test_ppo_lm.py:check_synthetic``), and, for the
 latent-attention policy's cell (PR 36), its four readers, its family's FLOP and kernel-work functions and every
-chip run on record put through its ``correct`` again (``chipbench/tests/test_ppo_lm_mla.py``'s ``check_*``)."""
+chip run on record put through its ``correct`` again (``chipbench/tests/test_ppo_lm_mla.py``'s ``check_*``); and the
+five ``setup_*`` readers of the entry-and-compiler layer (PR 38) over the recorder's set-up spans."""
 
 import contextlib
 import importlib.util
 import json
 import os
 import sys
+import threading
 
 import pytest
 
@@ -166,7 +168,7 @@ def test_the_benchmark_declares_the_new_cell_its_configuration_and_its_metrics()
         assert metrics[name] == {"name": name, "unit": unit, "better": better, "source": source, "layer": "model blocks",
                                  "moves": moves, "workloads": [NEW_CELL]}
     listed = {name for name, m in metrics.items() if NEW_CELL in m.get("workloads", [])}
-    assert len(listed) == 22 and not listed & {"moe_local_share_pct", "moe_load_max_over_mean", "attn_global_ms", "attn_window_ms"}
+    assert len(listed) == 27 and not listed & {"moe_local_share_pct", "moe_load_max_over_mean", "attn_global_ms", "attn_window_ms"}
 
 
 @pytest.mark.parametrize("run", RECORDED, ids=[r["id"] for r in RECORDED])
@@ -179,3 +181,106 @@ def test_a_chip_run_on_record_is_correct_and_its_controls_are_not(run):
 def test_the_records_cover_the_seeds_the_new_cells_limits_were_set_from():
     # ISSUE 36 asked for four control seeds; the chip budget ended after three (PERF.md, finding 36)
     assert len({r["seed"] for r in RECORDED}) >= 24 and sum(len(r["controls"]) == 7 for r in RECORDED) >= 3
+
+
+# -- the set-up readers (PR 38) -----------------------------------------------------------------------------------
+SETUP_METRICS = ("setup_trace_s", "setup_compile_s", "setup_cache_load_s", "setup_build_s", "setup_iters_s")
+SETUP_RUN = {"window": {"t_open": 100.0, "t_close": 120.0, "seconds": 20.0, "setup_s": 60.0}}  # set-up began at 40
+MAIN, OTHER = threading.main_thread().ident, -1
+SETUP_SPANS = [  # (name, t_start, t_end, thread, counters)
+    ("setup.build", 41.0, 50.0, MAIN, {}), ("setup.build", 51.0, 60.0, MAIN, {}),
+    ("jax.trace", 45.0, 48.0, MAIN, {"fn": "outer"}), ("jax.trace", 46.0, 47.0, MAIN, {"fn": "inner"}),  # nested: once
+    ("jax.lower", 48.0, 49.0, MAIN, {"fn": "jit(outer)"}), ("jax.compile", 49.0, 50.5, MAIN, {"fn": "jit(outer)", "cache": "miss"}),
+    ("iter", 60.0, 70.0, MAIN, {}), ("iter", 70.5, 80.0, MAIN, {}), ("iter", 65.0, 66.0, OTHER, {}),
+    ("jax.trace", 61.0, 62.0, MAIN, {"fn": "burst"}), ("jax.lower", 62.0, 62.5, MAIN, {"fn": "jit(burst)"}),
+    ("jax.compile", 62.5, 64.0, MAIN, {"fn": "jit(burst)", "cache": "hit"}),
+    ("jax.compile", 75.0, 76.0, OTHER, {"fn": "jit(step)", "cache": "off"}),  # another thread's compile holds the loop too
+    ("jax.trace", 30.0, 30.5, MAIN, {"fn": "before"}),  # the harness's, before set-up began: not the program's
+    ("iter", 100.5, 101.0, MAIN, {}), ("jax.compile", 101.0, 102.0, MAIN, {"fn": "jit(reference)", "cache": "miss"}),
+]
+SETUP_EXPECTED = {
+    "setup_trace_s": 4.0 + 1.5,  # [45, 49] and [61, 62.5]
+    "setup_compile_s": 1.5 + 1.0,  # miss [49, 50.5], off [75, 76]
+    "setup_cache_load_s": 1.5,
+    "setup_build_s": 18.0 - 5.0,  # less [45, 50]
+    "setup_iters_s": 19.5 - 3.0 - 1.0,  # the main thread's, less [61, 64] and [75, 76]
+}
+
+
+def setup_recorder(entries, capacity=profiler.CAPACITY, first_id=1):
+    rec = Recorder(capacity=capacity)
+    for i, (name, t0, t1, thread, counters) in enumerate(sorted(entries, key=lambda e: e[2])):  # as they ended
+        span = Span(rec, name, 0, dict(counters))
+        span.id, span.thread, span.t_start, span.t_end = first_id + i, thread, t0, t1
+        rec._ring.append(span)
+    return rec
+
+
+def read_setup(name, rec, monkeypatch):
+    monkeypatch.setattr(profiler, "snapshot", rec.snapshot)
+    with chipbench_on_path():
+        return load(f"layers/{name}.py", f"chipbench_layers_{name}").read(json.loads(json.dumps(SETUP_RUN)))
+
+
+SETUP_CASES = {
+    "read": (SETUP_SPANS, profiler.CAPACITY, 1, SETUP_EXPECTED),
+    # a full ring that still holds set-up's first span has dropped nothing of it
+    "full_ring_holding_setup": (SETUP_SPANS, len(SETUP_SPANS), 1, SETUP_EXPECTED),
+    # the parent: no `setup.build`, no `jax.*`; the loop's `iter` spans alone are no reading
+    "no_setup_spans": ([e for e in SETUP_SPANS if e[0] == "iter"], profiler.CAPACITY, 1, dict.fromkeys(SETUP_METRICS)),
+    # spans were dropped since set-up began: the oldest held starts after it, and more were recorded than held
+    "evicted": (sorted(SETUP_SPANS, key=lambda e: e[2])[2:], len(SETUP_SPANS) - 2, 3, dict.fromkeys(SETUP_METRICS)),
+    # a cold cache: nothing served; a warm one: nothing compiled
+    "nothing_served": ([e for e in SETUP_SPANS if e[4].get("cache") != "hit"], profiler.CAPACITY, 1,
+                       {**SETUP_EXPECTED, "setup_cache_load_s": 0.0, "setup_iters_s": 19.5 - 1.5 - 1.0}),
+}
+
+
+@pytest.mark.parametrize("metric", SETUP_METRICS)
+@pytest.mark.parametrize("case", list(SETUP_CASES))
+def test_the_setup_readers(case, metric, monkeypatch):
+    entries, capacity, first_id, expected = SETUP_CASES[case]
+    got = read_setup(metric, setup_recorder(entries, capacity, first_id), monkeypatch)
+    assert got == (None if expected[metric] is None else pytest.approx(expected[metric]))
+
+
+def test_a_nested_jits_trace_counts_once(monkeypatch):
+    """The real listener: the inner function's trace lies inside the outer's, and `setup_trace_s` reads the union."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from sheeprl_tpu.utils.utils import compile_stats
+
+    compile_stats.register()
+    inner = jax.jit(lambda x: jnp.cos(x) * 2.0)
+
+    def nested_probe(x):
+        return inner(x) + inner(x + 1.0)
+
+    x = jnp.arange(3, dtype=jnp.float32)
+    t_start = time.perf_counter()
+    with profiler.span("setup.build", parent=profiler.ROOT):
+        jax.jit(nested_probe)(x).block_until_ready()
+    t_open = time.perf_counter()
+    found = [s for s in profiler.snapshot(t_start, t_open)["spans"] if s["name"] in ("jax.trace", "jax.lower")]
+    outer = next(s for s in found if s["counters"]["fn"] == "nested_probe")
+    inside = [s for s in found if s is not outer and outer["t_start"] <= s["t_start"] <= s["t_end"] <= outer["t_end"]]
+    assert inside  # the inner jit, traced inside the outer trace
+    run = {"window": {"t_open": t_open, "t_close": t_open + 1.0, "seconds": 1.0, "setup_s": t_open - t_start}}
+    with chipbench_on_path():
+        got = load("layers/setup_trace_s.py", "chipbench_layers_setup_trace_s").read(run)
+    assert got == pytest.approx(sum(s["t_end"] - s["t_start"] for s in found if s not in inside))
+    assert got < sum(s["t_end"] - s["t_start"] for s in found)
+
+
+def test_the_benchmark_declares_the_setup_readers():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cells = [w["name"] for w in bench["workloads"]]
+    assert [m["name"] for m in bench["per_layer"][-5:]] == list(SETUP_METRICS)
+    for m in bench["per_layer"][-5:]:
+        assert m == {"name": m["name"], "unit": "s", "better": "lower", "source": "program_span",
+                     "layer": "entry and compiler", "moves": "setup_s", "workloads": cells}
+        assert os.path.isfile(os.path.join(CHIPBENCH, "layers", m["name"] + ".py"))

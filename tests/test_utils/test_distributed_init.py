@@ -85,12 +85,13 @@ def test_second_call_is_a_noop(_fresh):
 
 def test_cli_entrypoints_pass_the_config_block():
     """Both CLI bodies hand fabric.distributed to maybe_init (train via
-    run_algorithm, serve via serve_algorithm) — source-level wiring check
-    that survives refactors of either function."""
+    run_algorithm's set-up, ``_build_run``; serve via serve_algorithm) —
+    source-level wiring check that survives refactors of either function."""
     import inspect
 
     from sheeprl_tpu import cli
 
-    for fn in (cli.run_algorithm, cli.serve_algorithm):
+    assert "_build_run" in inspect.getsource(cli.run_algorithm)
+    for fn in (cli._build_run, cli.serve_algorithm):
         src = inspect.getsource(fn)
         assert "maybe_init" in src and "distributed" in src, fn.__name__

@@ -1,6 +1,7 @@
 """The flight recorder of ``utils/profiler.py``: spans nest by parent id,
-threads interleave safely, memory is bounded, ``snapshot`` cuts by time, and
-every span is entered as a ``jax.profiler.TraceAnnotation``."""
+threads interleave safely, memory is bounded, ``snapshot`` cuts by time,
+every span is entered as a ``jax.profiler.TraceAnnotation``, and JAX's own
+compile pipeline enters as finished spans (``utils.utils.CompileStats``)."""
 
 import sys
 import threading
@@ -161,7 +162,8 @@ def test_every_span_is_entered_as_a_trace_annotation(monkeypatch):
 
 
 def test_span_names_and_regions_are_listed_once():
-    assert len(set(profiler.SPANS)) == len(profiler.SPANS) == 10
+    assert len(set(profiler.SPANS)) == len(profiler.SPANS) == 14  # PR 38: setup.build and the three jax.* spans
+    assert set(profiler.JAX_SPANS) < set(profiler.SPANS)
     assert len(set(profiler.REGIONS)) == len(profiler.REGIONS) == 24  # 12 of the bursts, 12 of the language-model block (PR 36: +2)
     assert len(profiler.BURST_REGIONS) == 12 and profiler.REGIONS == profiler.BURST_REGIONS + profiler.LM_BLOCK_REGIONS
     assert all(name == name.lower() for name in profiler.SPANS + profiler.REGIONS)
@@ -175,3 +177,83 @@ def test_a_full_ring_keeps_the_newest(capacity):
         with rec.span("iter", i=i):
             pass
     assert [s["counters"]["i"] for s in rec.snapshot()["spans"]] == list(range(5 - capacity, 5))
+
+
+def test_a_span_known_only_after_it_ended_nests_in_the_open_one():
+    rec = Recorder()
+    with rec.span("iter", parent=ROOT) as it:
+        rec.record("jax.trace", it.t_start - 1.0, it.t_start + 0.5, fn="f")  # starts no earlier than its parent
+        rec.record("jax.compile", 5.0, 6.0, parent=ROOT, fn="g", cache="hit")
+    assert rec._stack() == []
+    spans = _by_name(rec.snapshot())
+    trace = spans["jax.trace"]
+    assert trace["parent"] == it.id and trace["t_start"] == it.t_start and trace["t_end"] == it.t_start + 0.5
+    assert trace["counters"] == {"fn": "f"} and trace["thread"] == spans["iter"]["thread"]
+    assert spans["jax.compile"]["parent"] == ROOT and (spans["jax.compile"]["t_start"], spans["jax.compile"]["t_end"]) == (5.0, 6.0)
+    with rec.span("iter", parent=ROOT):  # the next span takes the next id: one counter for both kinds
+        pass
+    assert [s["id"] for s in rec.snapshot()["spans"]] == [2, 3, 1, 4]
+
+
+def _jax_spans_of(parent_id, *fns):
+    return [s for s in profiler.snapshot()["spans"] if s["parent"] == parent_id and s["name"] in profiler.JAX_SPANS
+            and s["counters"]["fn"] in fns]
+
+
+def test_the_first_call_of_a_jit_records_its_trace_lowering_and_compile_inside_the_open_span():
+    import jax
+    import jax.numpy as jnp
+
+    from sheeprl_tpu.utils.utils import compile_stats
+
+    compile_stats.register()
+
+    def first_call_probe(x):
+        return jnp.sin(x) * 3.0 + 1.0
+
+    f = jax.jit(first_call_probe)
+    x = jnp.arange(7, dtype=jnp.float32)
+    with profiler.span("iter", parent=ROOT) as outer:
+        f(x).block_until_ready()
+    mine = _jax_spans_of(outer.id, "first_call_probe", "jit(first_call_probe)")
+    assert [s["name"] for s in mine] == ["jax.trace", "jax.lower", "jax.compile"]  # as they ended
+    assert [s["t_start"] for s in mine] == sorted(s["t_start"] for s in mine)
+    assert all(outer.t_start <= s["t_start"] <= s["t_end"] <= outer.t_end for s in mine)
+    assert set(mine[0]["counters"]) == set(mine[1]["counters"]) == {"fn"}
+    assert mine[2]["counters"]["cache"] in ("hit", "miss", "off")
+    with profiler.span("iter", parent=ROOT) as again:  # a second call traces and compiles nothing
+        f(x).block_until_ready()
+    assert _jax_spans_of(again.id, "first_call_probe", "jit(first_call_probe)") == []
+
+
+@pytest.mark.parametrize("enabled", [True, False], ids=["cache_on", "cache_off"])
+def test_the_compile_span_says_what_the_persistent_cache_did(enabled, tmp_path):
+    """A fresh cache directory, every compile persisted: the first compile misses, the same program after the
+    in-memory caches are cleared is served (`hit`); with the cache turned off it is never asked (`off`)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from sheeprl_tpu.utils.utils import compile_stats
+
+    compile_stats.register()
+    keys = ("jax_enable_compilation_cache", "jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    f = jax.jit(lambda x: x * 2.5 - 1.0)
+    x = jnp.arange(5, dtype=jnp.float32)
+    said = []
+    try:
+        for k, v in zip(keys, (enabled, str(tmp_path), 0.0)):
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+        for _ in range(2):
+            with profiler.span("iter", parent=ROOT) as it:
+                f(x).block_until_ready()
+            (compiled,) = [s for s in _jax_spans_of(it.id, "jit(<lambda>)") if s["name"] == "jax.compile"]
+            said.append(compiled["counters"]["cache"])
+            jax.clear_caches()
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    assert said == (["miss", "hit"] if enabled else ["off", "off"])

@@ -15,7 +15,7 @@ import pytest
 
 import sheeprl_tpu.utils.profiler as profiler_mod
 from sheeprl_tpu.utils import burst as burst_mod
-from sheeprl_tpu.utils.profiler import BURST_REGIONS, SPANS
+from sheeprl_tpu.utils.profiler import BURST_REGIONS, JAX_SPANS, ROOT, SPANS
 
 N_ENVS, TOTAL_STEPS, TRAIN_EVERY = 2, 96, 4
 ARGS = [
@@ -113,6 +113,27 @@ def test_every_public_span_is_recorded_and_nests_as_documented(burst_run):
             p = by_id[s["parent"]]
             if p["thread"] == s["thread"]:
                 assert p["t_start"] <= s["t_start"] and s["t_end"] <= p["t_end"], (s["name"], p["name"])
+
+
+def test_setup_and_the_compile_pipeline_are_recorded(burst_run):
+    """Set-up's two root spans (`cli.run_algorithm` up to the trainer, the loop up to its first `iter`) on the main
+    thread, and JAX's compile pipeline as spans inside whatever was open (PR 38)."""
+    builds, iters = _named(burst_run, "setup.build"), _named(burst_run, "iter")
+    assert len(builds) == 2 and all(b["parent"] == ROOT and b["thread"] == iters[0]["thread"] for b in builds)
+    assert builds[0]["t_end"] <= builds[1]["t_start"] and builds[1]["t_end"] <= iters[0]["t_start"]
+    by_id = {s["id"]: s for s in burst_run["spans"]}
+    pipeline = [s for s in burst_run["spans"] if s["name"] in JAX_SPANS]
+    assert all(set(s["counters"]) == ({"fn", "cache"} if s["name"] == "jax.compile" else {"fn"}) for s in pipeline)
+    compiles = _named(burst_run, "jax.compile")
+    assert {s["counters"]["cache"] for s in compiles} <= {"hit", "miss", "off"}
+    # the agent's initialisers compile in set-up, the burst program at its first dispatch, on the trainer thread
+    # (this module's probe dispatches it once more before that, outside any span)
+    parent = lambda s: by_id[s["parent"]]["name"] if s["parent"] else None  # noqa: E731
+    assert any(parent(s) == "setup.build" for s in compiles)
+    burst = [s for s in compiles if s["counters"]["fn"] == "jit(packed_burst)"]
+    assert any(parent(s) == "burst.dispatch" and s["thread"] != iters[0]["thread"] for s in burst)
+    traced = {s["counters"]["fn"] for s in _named(burst_run, "jax.trace")}
+    assert "packed_burst" in traced and len(_named(burst_run, "jax.lower")) == len(compiles)
 
 
 def test_iter_spans_carry_the_loop_counters(burst_run):
