@@ -81,10 +81,13 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from sheeprl_tpu.ops.kernels.attn import window_attention
+from sheeprl_tpu.ops.kernels.attn import RESIDUALS, window_attention
 from sheeprl_tpu.ops.kernels.moe import ACTIVATIONS, GMM_ROW_TILE, moe_grouped_ffn
 
 __all__ = ["DecoderConfig", "init_params", "forward", "prefill", "decode_step", "heads", "parameter_count"]
+
+# what a layer's rematerialisation keeps: the attention kernel's output and log-sum-exp (``forward``)
+REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(RESIDUALS)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -521,14 +524,18 @@ def forward(cfg: DecoderConfig, params, tokens, return_kv: bool = False):
     """Full-sequence forward of ``tokens`` (B, T): the hidden states before the
     final norm, the routing counters per layer ``(layers, cfg.counters)`` (a
     dense layer's row is zeros) and, if asked, each layer's cache state. With
-    ``cfg.remat`` each layer is rematerialised in the backward pass."""
+    ``cfg.remat`` each layer is rematerialised in the backward pass, all but
+    what the attention kernel names ``RESIDUALS`` (the kernel tier's output
+    and log-sum-exp, which its backward reads): those are kept, so the
+    backward runs no attention forward again. The reference tier names
+    nothing, and there everything is recomputed."""
     with jax.named_scope("lm.embed"):
         x = params["embed"][tokens]
     kvs, counters = [], []
     for i, layer in enumerate(params["layers"]):
         fn = lambda layer, x, _i=i: layer_forward(cfg, _i, layer, x)  # noqa: E731
         if cfg.remat and not return_kv:
-            fn = jax.checkpoint(fn)
+            fn = jax.checkpoint(fn, policy=REMAT_POLICY)
         x, kv, c = fn(layer, x)
         kvs.append(kv)
         counters.append(jnp.stack(c))

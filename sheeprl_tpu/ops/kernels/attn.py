@@ -22,7 +22,11 @@ means none.
   ``SPLASH_BLOCK`` divides is padded at its end to the next multiple
   (:func:`splash_len`) and run on full blocks, and the padded rows are
   dropped: no real query reaches a key after it, so the output and every
-  gradient are those of the sequence as it came (PERF.md finding 37).
+  gradient are those of the sequence as it came (PERF.md finding 37). The
+  kernel's forward names its output and log-sum-exp ``RESIDUALS``
+  (``jax.ad_checkpoint.checkpoint_name``), so that a ``jax.checkpoint``
+  around it whose policy saves that name keeps them and its backward never
+  runs the forward again; the reference tier names nothing.
 """
 
 from __future__ import annotations
@@ -35,11 +39,12 @@ import jax.numpy as jnp
 from sheeprl_tpu.ops.attention import block_attention, online_softmax_merge
 from sheeprl_tpu.ops.kernels import registry
 
-__all__ = ["splash_len", "window_attention", "window_attention_reference",
+__all__ = ["RESIDUALS", "splash_len", "window_attention", "window_attention_reference",
            "window_attention_pallas"]
 
 BLOCK = 512  # rows of a query block and of a key block, at most, in the lax tier
 SPLASH_BLOCK = 512  # and in the kernel tier, which wants multiples of 128
+RESIDUALS = "window_attention.residuals"  # the kernel tier's forward output and log-sum-exp, as a remat policy names them
 
 
 def _block_for(seq: int, cap: int) -> int:
@@ -113,7 +118,7 @@ def _splash_kernel(seq: int, heads_per_kv: int, window: int, interpret: bool):
     sizes = sk.BlockSizes(block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b, block_kv_dkv=b,
                           block_kv_dkv_compute=b, block_q_dq=b, block_kv_dq=b)
     return sk.make_splash_mqa_single_device(sm.MultiHeadMask([one] * heads_per_kv), block_sizes=sizes,
-                                            interpret=interpret)
+                                            residual_checkpoint_name=RESIDUALS, interpret=interpret)
 
 
 def _splash(q, k, v, window, interpret=False):

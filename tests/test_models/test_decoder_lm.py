@@ -1,9 +1,12 @@
 """The decoder language model against the benchmark's plain reference
 (chipbench/reference/ppo_lm_ref.py), at a small size on the CPU: full forward,
 prefill + decode through the two-kind cache, the expert and vocabulary shares,
-rematerialisation, and an adversarial router."""
+rematerialisation (and what it keeps of the attention kernel), and an
+adversarial router."""
 
+import collections
 import dataclasses
+import functools
 import importlib.util
 import os
 
@@ -13,6 +16,8 @@ import numpy as np
 import pytest
 
 from sheeprl_tpu.models import decoder_lm as lm
+from sheeprl_tpu.ops import kernels as K
+from sheeprl_tpu.ops.kernels import attn
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -274,3 +279,81 @@ def test_dispatch_and_combine_backward_are_the_plain_gathers_gradients():
     plain = jax.grad(lambda ys, w: jnp.sum(jnp.sum(w[..., None] * ys[index], axis=0) * g_tokens), argnums=(0, 1))(ys, weights)
     np.testing.assert_allclose(ours[0], plain[0], rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(ours[1], plain[1], rtol=1e-5, atol=1e-5)
+
+
+# -- what the per-layer remat keeps: the attention kernel's output and log-sum-exp ----------------------------------
+# 256 positions on blocks of 128 (monkeypatched, as the kernels' own tests do): two blocks a call, a full layer and a
+# 128-position window layer
+KERNEL_TIER = dataclasses.replace(SMALL, layers=2, window=128, rope_layout=(0, 1), window_layout=(0, 1))
+
+
+def _eqns(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
+
+
+def splash_forwards(jaxpr) -> int:
+    """The splash forward kernels a program holds, at any depth."""
+    return sum(e.primitive.name == "pallas_call" and str(e.params.get("name", "")).startswith("splash_mqa_fwd")
+               for e in _eqns(jaxpr.jaxpr))
+
+
+def primitive_counts(jaxpr) -> collections.Counter:
+    return collections.Counter(e.primitive.name for e in _eqns(jaxpr.jaxpr))
+
+
+def update_loss(cfg, params, tokens):
+    logits, values = lm.heads(cfg, params, lm.forward(cfg, params, tokens)[0])
+    return jnp.mean(jax.nn.log_softmax(logits)[..., 0]) + jnp.mean(values**2)
+
+
+def gradient_program(cfg, params, tokens):
+    """``jax.grad`` of the loss through ``forward``: its jaxpr and its gradients, traced anew (the policy is read
+    at trace time)."""
+    grad = jax.grad(functools.partial(update_loss, cfg))
+    return jax.make_jaxpr(grad)(params, tokens), jax.jit(grad)(params, tokens)
+
+
+def remat_programs(cfg, params, tokens, monkeypatch):
+    """The gradient program under the layer's policy, and under a plain ``jax.checkpoint`` (the policy left out)."""
+    kept = gradient_program(cfg, params, tokens)
+    with monkeypatch.context() as m:
+        m.setattr(lm, "REMAT_POLICY", None)
+        plain = gradient_program(cfg, params, tokens)
+    return kept, plain
+
+
+def assert_the_kernel_tiers_remat_keeps_its_residuals(cfg, params, tokens, monkeypatch):
+    """One splash forward a layer under the policy (two under a plain checkpoint: the backward's recompute), the
+    plain checkpoint's gradients within 1e-6 and those without remat within the remat test's tolerance."""
+    monkeypatch.setattr(attn, "SPLASH_BLOCK", 128)
+    with K.use_backend("lax", window_attention="pallas"):
+        (kept_jaxpr, kept), (plain_jaxpr, plain) = remat_programs(cfg, params, tokens, monkeypatch)
+        unremat = gradient_program(dataclasses.replace(cfg, remat=False), params, tokens)[1]
+    assert splash_forwards(kept_jaxpr) == cfg.layers
+    assert splash_forwards(plain_jaxpr) == 2 * cfg.layers
+    for a, b, c in zip(jax.tree.leaves(kept), jax.tree.leaves(plain), jax.tree.leaves(unremat)):
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
+        np.testing.assert_allclose(a, c, rtol=1e-5, atol=1e-6)
+
+
+def assert_the_reference_tiers_remat_is_a_plain_checkpoint(cfg, params, tokens, monkeypatch):
+    """The reference tier names nothing: under the policy its gradient program holds every primitive as often as
+    under a plain checkpoint, and its gradients are the same bits."""
+    with K.use_backend("lax"):
+        (kept_jaxpr, kept), (plain_jaxpr, plain) = remat_programs(cfg, params, tokens, monkeypatch)
+    assert splash_forwards(kept_jaxpr) == 0
+    assert primitive_counts(kept_jaxpr) == primitive_counts(plain_jaxpr)
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(plain)):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_the_remat_keeps_the_splash_kernels_residuals_a_full_and_a_window_layer(monkeypatch):
+    params = small_params(KERNEL_TIER)
+    assert_the_kernel_tiers_remat_keeps_its_residuals(KERNEL_TIER, params, tokens_of(KERNEL_TIER, 1, 256), monkeypatch)
+
+
+def test_the_reference_tiers_remat_is_the_plain_checkpoints_program(monkeypatch):
+    assert_the_reference_tiers_remat_is_a_plain_checkpoint(SMALL, small_params(), tokens_of(SMALL, 2, 32), monkeypatch)

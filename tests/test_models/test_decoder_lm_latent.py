@@ -16,6 +16,10 @@ import numpy as np
 import pytest
 
 from sheeprl_tpu.models import decoder_lm as lm
+from tests.test_models.test_decoder_lm import (
+    assert_the_kernel_tiers_remat_keeps_its_residuals,
+    assert_the_reference_tiers_remat_is_a_plain_checkpoint,
+)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -139,6 +143,17 @@ def test_loss_and_every_gradient_match_the_reference():
             assert not np.asarray(a).any() and not np.asarray(b).any()  # no gradient reaches the bias, in either
         else:
             assert scale > 0
+
+
+def test_the_remat_keeps_the_splash_kernels_residuals_latent_attention(monkeypatch):
+    """The expanded path's keys of ``nope_dim + rope_dim`` and values of ``v_dim``, a dense and a routed layer, 256
+    positions on blocks of 128."""
+    cfg = dataclasses.replace(SMALL, layers=2, rope_layout=(1, 1), window_layout=(0, 0))
+    assert_the_kernel_tiers_remat_keeps_its_residuals(cfg, small_params(cfg), tokens_of(cfg, 1, 256), monkeypatch)
+
+
+def test_the_reference_tiers_remat_is_the_plain_checkpoints_program_latent_attention(monkeypatch):
+    assert_the_reference_tiers_remat_is_a_plain_checkpoint(SMALL, small_params(), tokens_of(SMALL, 2, P + R), monkeypatch)
 
 
 def test_prefill_then_decode_through_the_latent_cache_equals_the_references_full_forward():

@@ -2,8 +2,9 @@
 the reader of ``moe_compact_share_pct`` over the recorder's ``iter`` spans, the definitions that decide
 ``correct`` in the first language-model cell (``chipbench/tests/test_ppo_lm.py:check_synthetic``), and, for the
 latent-attention policy's cell (PR 36), its four readers, its family's FLOP and kernel-work functions and every
-chip run on record put through its ``correct`` again (``chipbench/tests/test_ppo_lm_mla.py``'s ``check_*``); and the
-five ``setup_*`` readers of the entry-and-compiler layer (PR 38) over the recorder's set-up spans."""
+chip run on record put through its ``correct`` again (``chipbench/tests/test_ppo_lm_mla.py``'s ``check_*``); the
+five ``setup_*`` readers of the entry-and-compiler layer over the recorder's set-up spans; and the reader of
+``attn_fwd_recomputed_ms`` over rows of the scope table joined to a trace."""
 
 import contextlib
 import importlib.util
@@ -168,7 +169,7 @@ def test_the_benchmark_declares_the_new_cell_its_configuration_and_its_metrics()
         assert metrics[name] == {"name": name, "unit": unit, "better": better, "source": source, "layer": "model blocks",
                                  "moves": moves, "workloads": [NEW_CELL]}
     listed = {name for name, m in metrics.items() if NEW_CELL in m.get("workloads", [])}
-    assert len(listed) == 27 and not listed & {"moe_local_share_pct", "moe_load_max_over_mean", "attn_global_ms", "attn_window_ms"}
+    assert len(listed) == 28 and "attn_fwd_recomputed_ms" in listed and not listed & {"moe_local_share_pct", "moe_load_max_over_mean", "attn_global_ms", "attn_window_ms"}
 
 
 @pytest.mark.parametrize("run", RECORDED, ids=[r["id"] for r in RECORDED])
@@ -279,8 +280,48 @@ def test_the_benchmark_declares_the_setup_readers():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = [w["name"] for w in bench["workloads"]]
-    assert [m["name"] for m in bench["per_layer"][-5:]] == list(SETUP_METRICS)
-    for m in bench["per_layer"][-5:]:
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(SETUP_METRICS[0])
+    assert names[first : first + 5] == list(SETUP_METRICS)  # appended together, after every metric that was there
+    for m in bench["per_layer"][first : first + 5]:
         assert m == {"name": m["name"], "unit": "s", "better": "lower", "source": "program_span",
                      "layer": "entry and compiler", "moves": "setup_s", "workloads": cells}
         assert os.path.isfile(os.path.join(CHIPBENCH, "layers", m["name"] + ".py"))
+
+
+# -- the update's recomputed attention forwards -------------------------------------------------------------------
+ATTN = "kernel.window_attention"
+# (instruction, seconds over the traced block, outer region, innermost scope, backward): a layer's forward, its
+# backward's dq and dkv, the megablox product's, an instruction with no scope
+FORWARD = ("splash_mqa_fwd_residuals.4", 0.040, "lm.attn_mla", ATTN, False)
+BACKWARD = [("splash_mqa_dq_no_residuals.2", 0.085, "lm.attn_mla", ATTN, True),
+            ("splash_mqa_dkv_no_residuals.2", 0.096, "lm.attn_mla", ATTN, True)]
+OTHERS = [("gmm.3", 0.02, "lm.moe", "kernel.moe_grouped_ffn", True), ("copy-done.1", 0.01, None, None, False)]
+RECOMPUTED = [("splash_mqa_fwd_residuals.6", 0.036, "lm.attn_mla", ATTN, True),
+              ("splash_mqa_fwd_residuals.7", 0.037, "lm.attn_mla", ATTN, True)]
+RECOMPUTE_CASES = {
+    # the parent: each layer's forward again in the backward, 73 ms over 8 gradient steps
+    "recomputed": ([FORWARD, *RECOMPUTED, *BACKWARD, *OTHERS], 8, 1e3 * 0.073 / 8),
+    # the residuals kept: splash kernels, none of them a forward in the backward
+    "none_recomputed": ([FORWARD, *BACKWARD, *OTHERS], 8, 0.0),
+    # a forward in the backward that is no splash kernel's (the reference tier's blocks) is not read
+    "no_splash_kernel": ([("fusion.12", 0.03, "lm.attn_mla", ATTN, True), *OTHERS], 8, None),
+    "no_trace": (None, 8, None),
+}
+
+
+@pytest.mark.parametrize("case", list(RECOMPUTE_CASES))
+def test_attn_fwd_recomputed_reader(case):
+    rows, grants, expected = RECOMPUTE_CASES[case]
+    run = {"trace": {"grants": grants}, "_attributed": rows} if rows is not None else {"trace": None, "trace_info": {}}
+    with chipbench_on_path():
+        got = load("layers/attn_fwd_recomputed_ms.py", "chipbench_layers_attn_fwd_recomputed_ms").read(run)
+    assert got == (None if expected is None else pytest.approx(expected))
+
+
+def test_the_benchmark_declares_the_recompute_reader():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    assert bench["per_layer"][-1] == {"name": "attn_fwd_recomputed_ms", "unit": "ms", "better": "lower",
+                                      "source": "device_trace", "layer": "model blocks", "moves": "grad_steps_per_s",
+                                      "workloads": ["smallthinker_ep4_longprompt_rl", NEW_CELL]}
