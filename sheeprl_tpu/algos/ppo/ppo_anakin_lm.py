@@ -350,6 +350,16 @@ TOY_OVERRIDES_LATENT = (
     "algo.lm.num_hidden_layers=3", "algo.lm.vocab_size=96", "algo.lm.vocab_held=64", "env.prompt_len=24",
     "algo.rollout_steps=8",
 )
+#: the third policy at toy widths: a dense layer and four routed ones ([window, window, window, full, window]) with a
+#: shared expert, gated grouped-query attention with q/k norms, sandwich norms, a scaled embedding
+TOY_OVERRIDES_AFMOE = (
+    "exp=ppo_anakin_lm_trinity", "algo.lm.hidden_size=64", "algo.lm.num_attention_heads=4",
+    "algo.lm.num_key_value_heads=2", "algo.lm.head_dim=16", "algo.lm.intermediate_size=96",
+    "algo.lm.moe_intermediate_size=32", "algo.lm.num_experts=8", "algo.lm.num_experts_per_tok=2",
+    "algo.lm.experts_held=4", "algo.lm.expert_offset=2", "algo.lm.num_hidden_layers=5", "algo.lm.num_dense_layers=1",
+    "algo.lm.sliding_window=8", "algo.lm.vocab_size=96", "algo.lm.vocab_held=64", "env.prompt_len=24",
+    "algo.rollout_steps=8",
+)
 
 
 def _audit_block(spec: AuditMesh, name: str, overrides):
@@ -403,3 +413,8 @@ def _audit_programs(spec: AuditMesh):
 @register_audit_programs("ppo_anakin_lm.block_latent")
 def _audit_programs_latent(spec: AuditMesh):
     yield _audit_block(spec, "ppo_anakin_lm.block_latent", TOY_OVERRIDES_LATENT)
+
+
+@register_audit_programs("ppo_anakin_lm.block_afmoe")
+def _audit_programs_afmoe(spec: AuditMesh):
+    yield _audit_block(spec, "ppo_anakin_lm.block_afmoe", TOY_OVERRIDES_AFMOE)

@@ -1,8 +1,12 @@
 """Decoder-only language models with routed experts, as plain functions over a
-parameter dict. One module and one :class:`DecoderConfig` hold two published
+parameter dict. One module and one :class:`DecoderConfig` hold three published
 layers; which one a layer is comes from the config's static kinds
 (``attention``, ``router``, ``activation``, ``dense_layers``,
-``shared_width``), never from the parameters.
+``shared_width``, ``attn_gate``, ``qk_norm``, ``post_norms``,
+``embed_scale``), never from the parameters. The kinds are independent of one
+another: the router's kind says where it sits (``"softmax"`` before
+attention, ``"sigmoid_bias"`` after it), and the shared experts and the
+leading dense layers go with either attention.
 
 **Grouped-query layers with the router first** (``attention="gqa"``,
 ``router="softmax"``, ``activation="relu"``; SmallThinker). For input ``x``
@@ -44,6 +48,29 @@ layers; which one a layer is comes from the config's static kinds
   under ``stop_gradient``, so its gradient is exactly zero, Adam's moments of
   it stay zero and the update ``-lr * 0 / (0 + eps)`` leaves it bit-identical
   (asserted in tier-1 and, on the chip, by the cell's ``router_bias_change``).
+
+**Gated grouped-query layers with sandwich norms and the router after
+attention** (``attention="gqa"``, ``router="sigmoid_bias"``,
+``activation="silu"``, ``attn_gate``, ``qk_norm``, ``post_norms``; the
+``afmoe`` layer). For input ``x``, RMSNorm without bias:
+
+- ``h = norm_in(x)``; ``q = norm_q(h Wq)`` and ``k = norm_k(h Wk)`` per head
+  (``qk_norm``: an RMSNorm over each ``head_dim``-wide head, before the
+  rotary encoding, so the cache holds keys already normed and rotated),
+  ``v = h Wv``; rotary encoding and the window where the layouts say, as in
+  the first layer;
+- ``x' = x + norm_attn_out(Wo (o * sigmoid(h Wg)))``: the attention's output
+  ``o`` (heads x head_dim, concatenated) gated elementwise by a projection of
+  the same width (``attn_gate``, under ``lm.attn_gate``), then the output
+  product, then a norm of the sublayer's output before the residual add
+  (``post_norms``);
+- ``u = norm_post(x')``; ``y = x' + norm_ffn_out(F(u))`` with ``F`` the
+  latent layer's: a dense SwiGLU in the first ``dense_layers`` layers, else
+  the routed share (sigmoid scores, the ``top_k`` of ``score + bias``, the
+  kept scores normalised and scaled by ``routed_scale``) plus the shared
+  experts;
+- the embedding's rows are multiplied by ``embed_scale`` (``sqrt(hidden)``
+  where the published config enables muP), in the full forward and in decode.
 
 **The layer is told which experts it holds** (``experts_held`` of them from
 ``expert_offset``): it routes over all ``experts``, computes its own experts'
@@ -121,6 +148,10 @@ class DecoderConfig:
     nope_dim: int = 0
     rope_dim: int = 0
     v_dim: int = 0
+    attn_gate: bool = False  # the attention's output times sigmoid(h Wg) before Wo
+    qk_norm: bool = False  # an RMSNorm over each head of the queries and keys, before the rotary encoding
+    post_norms: bool = False  # an RMSNorm on each sublayer's output before its residual add
+    embed_scale: float = 1.0  # the embedding's rows times this
 
     @property
     def selection_bias(self) -> bool:
@@ -136,9 +167,12 @@ class DecoderConfig:
     def from_config(cls, lm: Any) -> "DecoderConfig":
         """From the ``algo.lm`` group, whose keys are one published
         config.json's: a group with ``kv_lora_rank`` is the latent-attention
-        layer's, any other the grouped-query layer's."""
+        layer's, one of ``model_type`` ``afmoe`` the gated grouped-query
+        layer's, any other the grouped-query layer's with the router first."""
         if "kv_lora_rank" in lm:
             return cls._latent_from_config(lm)
+        if lm.get("model_type") == "afmoe":
+            return cls._afmoe_from_config(lm)
         layers = int(lm.num_hidden_layers)
         experts = int(lm.moe_num_primary_experts)
         held = int(lm.experts_held or experts)
@@ -148,7 +182,7 @@ class DecoderConfig:
         if not (lm.moe_primary_router_apply_softmax and lm.norm_topk_prob):
             raise ValueError("with the router before attention only the softmax over the kept experts is written down "
                              "(moe_primary_router_apply_softmax and norm_topk_prob); the sigmoid router with a "
-                             "selection bias is the latent-attention layer's")
+                             "selection bias is read from the latent-attention and the afmoe groups' keys")
         return cls(
             hidden=int(lm.hidden_size), heads=int(lm.num_attention_heads), kv_heads=int(lm.num_key_value_heads),
             head_dim=int(lm.head_dim), layers=layers, experts=experts, top_k=int(lm.moe_num_active_primary_experts),
@@ -196,23 +230,82 @@ class DecoderConfig:
             v_dim=int(lm.v_head_dim),
         )
 
+    @classmethod
+    def _afmoe_from_config(cls, lm: Any) -> "DecoderConfig":
+        """The ``afmoe`` keys. What the module does not compute raises."""
+        layers = int(lm.num_hidden_layers)
+        kinds = [str(t) for t in lm.layer_types]
+        every = int(lm.global_attn_every_n_layers)
+        unwritten = {
+            "score_func": lm.score_func != "sigmoid", "route_norm": not lm.route_norm,
+            "expert groups": any(int(lm[k]) != 1 for k in ("n_group", "num_expert_groups", "num_limited_groups", "topk_group")),
+            "rope_scaling": lm.rope_scaling is not None, "hidden_act": lm.hidden_act != "silu",
+            "tie_word_embeddings": bool(lm.tie_word_embeddings),
+            "layer_types": bool(set(kinds) - {"sliding_attention", "full_attention"}) or len(kinds) < layers,
+            "global_attn_every_n_layers": any((t == "full_attention") != (i % every == every - 1) for i, t in enumerate(kinds)),
+            "muP beyond the embedding scale": any(k.startswith("mup_") and k != "mup_enabled" for k in lm),
+        }
+        if any(unwritten.values()):
+            raise ValueError(
+                f"not written down for the gated grouped-query layer: {sorted(k for k, v in unwritten.items() if v)} "
+                "(written down: sigmoid scores with a selection bias normalised over the kept, one selection group, no "
+                "rotary scaling, SiLU gates, an untied head, sliding and full layers, muP as the embedding's scale alone)")
+        experts = int(lm.num_experts)
+        held, offset = int(lm.experts_held or experts), int(lm.expert_offset or 0)
+        if offset + held > experts:
+            raise ValueError(f"experts {offset}..{offset + held - 1} held of {experts}")
+        local = tuple(int(t == "sliding_attention") for t in kinds[:layers])  # rotary and the window on local layers alone
+        return cls(
+            hidden=int(lm.hidden_size), heads=int(lm.num_attention_heads), kv_heads=int(lm.num_key_value_heads),
+            head_dim=int(lm.head_dim), layers=layers, experts=experts, top_k=int(lm.num_experts_per_tok),
+            expert_width=int(lm.moe_intermediate_size), experts_held=held, expert_offset=offset,
+            vocab_held=int(lm.vocab_held or lm.vocab_size), window=int(lm.sliding_window), rope_theta=float(lm.rope_theta),
+            eps=float(lm.rms_norm_eps), rope_layout=local, window_layout=local, remat=bool(lm.remat),
+            router="sigmoid_bias", activation="silu", dense_layers=min(int(lm.num_dense_layers), layers),
+            dense_width=int(lm.intermediate_size), shared_width=int(lm.num_shared_experts) * int(lm.moe_intermediate_size),
+            routed_scale=float(lm.route_scale), attn_gate=True, qk_norm=True, post_norms=True,
+            embed_scale=float(lm.hidden_size) ** 0.5 if lm.mup_enabled else 1.0,
+        )
+
 
 def init_params(cfg: DecoderConfig, key: jax.Array, std: float = 0.02) -> Dict[str, Any]:
     def normal(key, *shape):
         return std * jax.random.normal(key, shape, jnp.float32)
 
-    latent = cfg.attention == "mla"
-    keys = iter(jax.random.split(key, 4 + (12 if latent else 8) * cfg.layers))
+    latent, router_first = cfg.attention == "mla", cfg.router == "softmax"
+    keys = iter(jax.random.split(key, 4 + (8 if router_first else 12) * cfg.layers))
     H, Q, KV, F, E = cfg.hidden, cfg.heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim, cfg.expert_width, cfg.experts_held
     ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
 
-    def grouped_query_layer():
-        return {
-            "ln_in": ones(H), "wq": normal(next(keys), H, Q), "wk": normal(next(keys), H, KV),
-            "wv": normal(next(keys), H, KV), "wo": normal(next(keys), Q, H), "router": normal(next(keys), H, cfg.experts),
-            "ln_post": ones(H), "w_gate": normal(next(keys), E, H, F),
+    def feed_forward(index):
+        """The dense MLP in a leading layer, else the router, its selection bias and the routed and shared experts."""
+        if index < cfg.dense_layers:
+            D = cfg.dense_width
+            return {"dense_gate": normal(next(keys), H, D), "dense_up": normal(next(keys), H, D),
+                    "dense_down": normal(next(keys), D, H)}
+        S = cfg.shared_width
+        return {  # a run from scratch starts without a selection bias (a published model ships its own)
+            "router": normal(next(keys), H, cfg.experts), "router_bias": jnp.zeros((cfg.experts,), jnp.float32),
+            "shared_gate": normal(next(keys), H, S), "shared_up": normal(next(keys), H, S),
+            "shared_down": normal(next(keys), S, H), "w_gate": normal(next(keys), E, H, F),
             "w_up": normal(next(keys), E, H, F), "w_down": normal(next(keys), E, F, H),
         }
+
+    def grouped_query_layer(index):
+        layer = {
+            "ln_in": ones(H), "wq": normal(next(keys), H, Q), "wk": normal(next(keys), H, KV),
+            "wv": normal(next(keys), H, KV), "wo": normal(next(keys), Q, H), "ln_post": ones(H),
+        }
+        if router_first:
+            return {**layer, "router": normal(next(keys), H, cfg.experts), "w_gate": normal(next(keys), E, H, F),
+                    "w_up": normal(next(keys), E, H, F), "w_down": normal(next(keys), E, F, H)}
+        if cfg.qk_norm:
+            layer.update(ln_q=ones(cfg.head_dim), ln_k=ones(cfg.head_dim))
+        if cfg.attn_gate:
+            layer["wg"] = normal(next(keys), H, Q)
+        if cfg.post_norms:
+            layer.update(ln_attn_out=ones(H), ln_ffn_out=ones(H))
+        return {**layer, **feed_forward(index)}
 
     def latent_layer(index):
         layer = {
@@ -220,19 +313,9 @@ def init_params(cfg: DecoderConfig, key: jax.Array, std: float = 0.02) -> Dict[s
             "ln_kv": ones(cfg.latent), "wkvb": normal(next(keys), cfg.latent, cfg.heads * (cfg.nope_dim + cfg.v_dim)),
             "wo": normal(next(keys), cfg.heads * cfg.v_dim, H), "ln_post": ones(H),
         }
-        if index < cfg.dense_layers:
-            D = cfg.dense_width
-            return {**layer, "dense_gate": normal(next(keys), H, D), "dense_up": normal(next(keys), H, D),
-                    "dense_down": normal(next(keys), D, H)}
-        S = cfg.shared_width
-        return {  # a run from scratch starts without a selection bias (a published model ships its own)
-            **layer, "router": normal(next(keys), H, cfg.experts), "router_bias": jnp.zeros((cfg.experts,), jnp.float32),
-            "shared_gate": normal(next(keys), H, S), "shared_up": normal(next(keys), H, S),
-            "shared_down": normal(next(keys), S, H), "w_gate": normal(next(keys), E, H, F),
-            "w_up": normal(next(keys), E, H, F), "w_down": normal(next(keys), E, F, H),
-        }
+        return {**layer, **feed_forward(index)}
 
-    layers = [latent_layer(i) if latent else grouped_query_layer() for i in range(cfg.layers)]
+    layers = [(latent_layer if latent else grouped_query_layer)(i) for i in range(cfg.layers)]
     return {
         "embed": normal(next(keys), cfg.vocab_held, H), "layers": layers, "ln_f": jnp.ones((H,), jnp.float32),
         "head": normal(next(keys), H, cfg.vocab_held), "value_w": normal(next(keys), H, 1),
@@ -434,9 +517,27 @@ def _qkv(cfg: DecoderConfig, layer, h, positions, use_rope):
     q = jnp.dot(h, layer["wq"]).reshape(*lead, cfg.heads, cfg.head_dim)
     k = jnp.dot(h, layer["wk"]).reshape(*lead, cfg.kv_heads, cfg.head_dim)
     v = jnp.dot(h, layer["wv"]).reshape(*lead, cfg.kv_heads, cfg.head_dim)
+    if cfg.qk_norm:
+        q, k = rms_norm(q, layer["ln_q"], cfg.eps), rms_norm(k, layer["ln_k"], cfg.eps)
     if use_rope:
         q, k = rope(q, positions, cfg.rope_theta), rope(k, positions, cfg.rope_theta)
     return q, k, v
+
+
+def _attention_output(cfg: DecoderConfig, layer, h, o):
+    """What attention adds to the residual, from its concatenated heads ``o``
+    (..., heads * head_dim) and the normed input ``h`` they came from: the
+    output gate, the output product and the sublayer's output norm, each
+    where the config has it."""
+    if cfg.attn_gate:
+        with jax.named_scope("lm.attn_gate"):
+            o = o * jax.nn.sigmoid(jnp.dot(h, layer["wg"]))
+    out = jnp.dot(o, layer["wo"])
+    return rms_norm(out, layer["ln_attn_out"], cfg.eps) if cfg.post_norms else out
+
+
+def _attention_scope(cfg: DecoderConfig, index: int) -> str:
+    return "lm.attn_window" if cfg.window_layout[index] else "lm.attn_global"
 
 
 def _experts_block(cfg: DecoderConfig, layer, x, weights, experts):
@@ -450,14 +551,21 @@ def _experts_block(cfg: DecoderConfig, layer, x, weights, experts):
 def _grouped_query_layer(cfg: DecoderConfig, index: int, layer, x):
     B, T, H = x.shape
     windowed = bool(cfg.window_layout[index])
-    with jax.named_scope("lm.moe"):
-        h = rms_norm(x, layer["ln_in"], cfg.eps)
-        weights, experts = route(cfg, h.reshape(B * T, H), layer["router"])
-    with jax.named_scope("lm.attn_window" if windowed else "lm.attn_global"):
+    router_first = cfg.router == "softmax"
+    if router_first:
+        with jax.named_scope("lm.moe"):
+            h = rms_norm(x, layer["ln_in"], cfg.eps)
+            weights, experts = route(cfg, h.reshape(B * T, H), layer["router"])
+    with jax.named_scope(_attention_scope(cfg, index)):
+        if not router_first:
+            h = rms_norm(x, layer["ln_in"], cfg.eps)
         q, k, v = _qkv(cfg, layer, h, jnp.arange(T), cfg.rope_layout[index])
         o = window_attention(q, k, v, cfg.window if windowed else 0)
-        x = x + jnp.dot(o.reshape(B, T, -1), layer["wo"])
-    x, counters = _experts_block(cfg, layer, x, weights, experts)
+        x = x + _attention_output(cfg, layer, h, o.reshape(B, T, -1))
+    if router_first:
+        x, counters = _experts_block(cfg, layer, x, weights, experts)
+    else:
+        x, counters = _feed_forward(cfg, index, layer, x)
     return x, (k, v), counters
 
 
@@ -479,16 +587,19 @@ def _gated_mlp(cfg: DecoderConfig, u, w_gate, w_up, w_down):
     return jnp.dot(ACTIVATIONS[cfg.activation](jnp.dot(u, w_gate)) * jnp.dot(u, w_up), w_down)
 
 
-def _latent_feed_forward(cfg: DecoderConfig, index: int, layer, x, count_moved: bool = True):
-    """The feed-forward half of a latent-attention layer for ``x`` ``(B, T,
-    hidden)`` after attention: the dense MLP in a leading layer (its counters
-    are zeros), else the routed share and the shared experts on the same
-    normed input."""
+def _feed_forward(cfg: DecoderConfig, index: int, layer, x, count_moved: bool = True):
+    """The feed-forward half of a layer whose router sits after attention, for
+    ``x`` ``(B, T, hidden)`` after attention: the dense MLP in a leading layer
+    (its counters are zeros), else the routed share and the shared experts on
+    the same normed input; with ``post_norms`` the sum is normed before the
+    residual add."""
     B, T, H = x.shape
     if index < cfg.dense_layers:
         with jax.named_scope("lm.ffn_shared"):
             u = rms_norm(x, layer["ln_post"], cfg.eps)
             y = _gated_mlp(cfg, u, layer["dense_gate"], layer["dense_up"], layer["dense_down"])
+            if cfg.post_norms:
+                y = rms_norm(y, layer["ln_ffn_out"], cfg.eps)
         return x + y, (jnp.int32(0),) * cfg.counters
     with jax.named_scope("lm.moe"):
         u = rms_norm(x, layer["ln_post"], cfg.eps).reshape(B * T, H)
@@ -496,7 +607,11 @@ def _latent_feed_forward(cfg: DecoderConfig, index: int, layer, x, count_moved: 
         routed, counters = moe_share(cfg, layer, u, weights, experts)
     with jax.named_scope("lm.ffn_shared"):
         shared = _gated_mlp(cfg, u, layer["shared_gate"], layer["shared_up"], layer["shared_down"])
-    return x + (routed + shared).reshape(B, T, H), (*counters, moved if count_moved else jnp.int32(0))
+    y = (routed + shared).reshape(B, T, H)
+    if cfg.post_norms:
+        with jax.named_scope("lm.moe"):
+            y = rms_norm(y, layer["ln_ffn_out"], cfg.eps)
+    return x + y, (*counters, moved if count_moved else jnp.int32(0))
 
 
 def _latent_layer(cfg: DecoderConfig, index: int, layer, x):
@@ -509,7 +624,7 @@ def _latent_layer(cfg: DecoderConfig, index: int, layer, x):
         k = jnp.concatenate([kv[..., : cfg.nope_dim], jnp.broadcast_to(r[:, :, None], (B, T, cfg.heads, cfg.rope_dim))], axis=-1)
         o = window_attention(jnp.concatenate([q_n, q_r], axis=-1), k, kv[..., cfg.nope_dim :], 0)
         x = x + jnp.dot(o.reshape(B, T, -1), layer["wo"])
-    x, counters = _latent_feed_forward(cfg, index, layer, x)
+    x, counters = _feed_forward(cfg, index, layer, x)
     return x, (c, r), counters
 
 
@@ -518,6 +633,12 @@ def layer_forward(cfg: DecoderConfig, index: int, layer, x):
     of it (the rotated keys and the values, or the normed latent and the
     rotated shared key) and the routing counters."""
     return (_latent_layer if cfg.attention == "mla" else _grouped_query_layer)(cfg, index, layer, x)
+
+
+def _embed(cfg: DecoderConfig, params, tokens):
+    with jax.named_scope("lm.embed"):
+        x = params["embed"][tokens]
+        return x * cfg.embed_scale if cfg.embed_scale != 1.0 else x
 
 
 def forward(cfg: DecoderConfig, params, tokens, return_kv: bool = False):
@@ -529,8 +650,7 @@ def forward(cfg: DecoderConfig, params, tokens, return_kv: bool = False):
     and log-sum-exp, which its backward reads): those are kept, so the
     backward runs no attention forward again. The reference tier names
     nothing, and there everything is recomputed."""
-    with jax.named_scope("lm.embed"):
-        x = params["embed"][tokens]
+    x = _embed(cfg, params, tokens)
     kvs, counters = [], []
     for i, layer in enumerate(params["layers"]):
         fn = lambda layer, x, _i=i: layer_forward(cfg, _i, layer, x)  # noqa: E731
@@ -583,12 +703,15 @@ def _grouped_query_decode(cfg: DecoderConfig, index: int, layer, state, x, posit
     ck, cv = state
     B = x.shape[0]
     groups = cfg.heads // cfg.kv_heads
-    windowed = bool(cfg.window_layout[index])
     slots = ck.shape[1]
-    with jax.named_scope("lm.moe"):
-        h = rms_norm(x, layer["ln_in"], cfg.eps)
-        weights, experts = route(cfg, h, layer["router"])
-    with jax.named_scope("lm.attn_window" if windowed else "lm.attn_global"):
+    router_first = cfg.router == "softmax"
+    if router_first:
+        with jax.named_scope("lm.moe"):
+            h = rms_norm(x, layer["ln_in"], cfg.eps)
+            weights, experts = route(cfg, h, layer["router"])
+    with jax.named_scope(_attention_scope(cfg, index)):
+        if not router_first:
+            h = rms_norm(x, layer["ln_in"], cfg.eps)
         q, k, v = _qkv(cfg, layer, h[:, None], position[None], cfg.rope_layout[index])
         slot = position % slots
         ck = jax.lax.dynamic_update_slice_in_dim(ck, k, slot, axis=1)
@@ -599,8 +722,11 @@ def _grouped_query_decode(cfg: DecoderConfig, index: int, layer, state, x, posit
         s = jnp.einsum("bhgd,bshd->bhgs", qg, ck) * cfg.head_dim**-0.5
         s = jnp.where((held >= 0)[None, None, None, :], s, -jnp.inf)
         o = jnp.einsum("bhgs,bshd->bhgd", jax.nn.softmax(s, axis=-1), cv)
-        x = x + jnp.dot(o.reshape(B, -1), layer["wo"])
-    y, counters = _experts_block(cfg, layer, x[:, None], weights, experts)
+        x = x + _attention_output(cfg, layer, h, o.reshape(B, -1))
+    if router_first:
+        y, counters = _experts_block(cfg, layer, x[:, None], weights, experts)
+    else:
+        y, counters = _feed_forward(cfg, index, layer, x[:, None], count_moved=False)
     return y[:, 0], (ck, cv), counters
 
 
@@ -623,7 +749,7 @@ def _latent_decode(cfg: DecoderConfig, index: int, layer, state, x, position):
         mixed = jnp.einsum("bhs,bsc->bhc", jax.nn.softmax(s, axis=-1), latents)
         o = jnp.einsum("bhc,chd->bhd", mixed, up[..., cfg.nope_dim :])
         x = x + jnp.dot(o.reshape(B, -1), layer["wo"])
-    y, counters = _latent_feed_forward(cfg, index, layer, x[:, None], count_moved=False)
+    y, counters = _feed_forward(cfg, index, layer, x[:, None], count_moved=False)
     return y[:, 0], (latents, shared), counters
 
 
@@ -633,8 +759,7 @@ def decode_step(cfg: DecoderConfig, params, cache, token, position):
     cache. Plain ``jax.numpy`` over the cache: the work is reading the weights
     and the cached state once. Returns the hidden state (B, hidden), the new
     cache and the counters."""
-    with jax.named_scope("lm.embed"):
-        x = params["embed"][token]  # (B, H)
+    x = _embed(cfg, params, token)  # (B, H)
     step = _latent_decode if cfg.attention == "mla" else _grouped_query_decode
     new_cache, counters = [], []
     for i, (layer, state) in enumerate(zip(params["layers"], cache)):

@@ -37,6 +37,10 @@ ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
 GMM_TILING = (512, 1024, 1024)
 #: a caller that hands the product fewer rows than it has assignments cuts them to a multiple of this
 GMM_ROW_TILE = GMM_TILING[0]
+# contraction x columns of one tile at most this many: the backward's transposed product holds its float32
+# accumulator and two bfloat16 output buffers of that area beside its float32 gradient rows, and 1024 x 1024 is
+# over the kernel's 16 MiB of scoped VMEM (experts of 1024 on a hidden of 2048)
+GMM_TILE_AREA = 1024 * 768
 
 
 def _tile(dim: int, cap: int) -> int:
@@ -69,11 +73,19 @@ def moe_grouped_ffn_reference(xs, w_gate, w_up, w_down, group_sizes, activation=
     return _ffn(jax.lax.ragged_dot, xs, w_gate, w_up, w_down, group_sizes, activation)
 
 
+def gmm_tiling(m: int, k: int, n: int):
+    """The grouped product's ``(rows, contraction, columns)`` tiles for ``(m,
+    k) x (k, n)``: each as large as ``GMM_TILING`` allows and divides its
+    dimension, the columns' narrowed until a tile's area is at most
+    ``GMM_TILE_AREA``."""
+    tk = _tile(k, GMM_TILING[1])
+    return min(GMM_TILING[0], m), tk, _tile(n, min(GMM_TILING[2], max(128, GMM_TILE_AREA // tk)))
+
+
 def _gmm(lhs, rhs, group_sizes, interpret):
     from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
-    m, k, n = lhs.shape[0], lhs.shape[1], rhs.shape[2]
-    tiling = (min(GMM_TILING[0], m), _tile(k, GMM_TILING[1]), _tile(n, GMM_TILING[2]))
+    tiling = gmm_tiling(lhs.shape[0], lhs.shape[1], rhs.shape[2])
     return megablox.gmm(
         lhs.astype(jnp.bfloat16), rhs.astype(jnp.bfloat16), group_sizes, jnp.float32, tiling, interpret=interpret
     )

@@ -87,7 +87,8 @@ BURST_REGIONS: Tuple[str, ...] = (
 #: The same for the on-device PPO block with a language-model policy
 #: (``algos/ppo/ppo_anakin_lm.py``): the two rollout phases are outermost
 #: there, so the model's own scopes inside them count to the phase; the rest
-#: lies in the update.
+#: lies in the update. ``lm.attn_gate`` lies inside ``lm.attn_window`` /
+#: ``lm.attn_global`` and counts to them; it is an innermost scope of its own.
 LM_BLOCK_REGIONS: Tuple[str, ...] = (
     "rollout.prefill",
     "rollout.decode",
@@ -101,6 +102,7 @@ LM_BLOCK_REGIONS: Tuple[str, ...] = (
     "ppo.gae",
     "ppo.optim",
     "env.token",
+    "lm.attn_gate",
 )
 REGIONS: Tuple[str, ...] = BURST_REGIONS + LM_BLOCK_REGIONS
 #: ``ops.kernels.registry.dispatch(name)`` runs its kernel, whichever tier,

@@ -204,7 +204,7 @@ def test_the_routed_shares_and_the_shared_experts_once_add_up_to_the_uncut_layer
         cfg = dataclasses.replace(SMALL, experts_held=held, expert_offset=s * held, shared_width=0)
         part = {k: (v[s * held : (s + 1) * held] if v.ndim == 3 else v) for k, v in layer.items()}
         # this share's layer output less its input and less the shared experts: its routed part alone
-        with_shared, counters = lm._latent_feed_forward(dataclasses.replace(cfg, shared_width=64), 1, part, x)
+        with_shared, counters = lm._feed_forward(dataclasses.replace(cfg, shared_width=64), 1, part, x)
         u = lm.rms_norm(x, layer["ln_post"], SMALL.eps)
         shared = lm._gated_mlp(SMALL, u, layer["shared_gate"], layer["shared_up"], layer["shared_down"])
         routed_sum = routed_sum + (with_shared - x - shared)

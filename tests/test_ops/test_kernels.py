@@ -618,3 +618,28 @@ def test_gru_gates_block_that_cannot_fit_is_named():
     assert gru._block_rows(16, 512, 4) == 16  # a batch under one block is one block
     with pytest.raises(ValueError, match="H=1048576"):
         gru._block_rows(1024, 1 << 20, 4)
+
+
+def test_the_grouped_products_tiles_narrow_only_where_a_tile_would_not_fit():
+    """(rows, contraction, columns) tiles of the experts' products, forward ``(rows, hidden) x (hidden, width)`` and
+    ``(rows, width) x (width, hidden)``: the first two language-model cells keep the tiles they had; experts of
+    1024 on a hidden of 2048 get columns of 512 (1024 x 1024 is over the transposed product's VMEM)."""
+    from sheeprl_tpu.ops.kernels.moe import gmm_tiling
+
+    assert (gmm_tiling(24576, 2560, 768), gmm_tiling(24576, 768, 2560)) == ((512, 640, 768), (512, 768, 640))
+    assert (gmm_tiling(12288, 2048, 768), gmm_tiling(12288, 768, 2048)) == ((512, 1024, 768), (512, 768, 1024))
+    assert (gmm_tiling(16384, 2048, 1024), gmm_tiling(16384, 1024, 2048)) == ((512, 1024, 512), (512, 1024, 512))
+    assert gmm_tiling(48, 256, 128) == (48, 256, 128)  # a decode call, the tests' widths
+
+
+@pytest.mark.parametrize("hidden,width", [(2560, 768), (2048, 768), (2048, 1024)], ids=["smallthinker", "kanana2", "trinity"])
+def test_moe_grouped_ffn_backward_fits_scoped_vmem_at_every_configured_width(pretend_tpu, tpu_topology, hidden, width):
+    """The experts' products and their gradients as dispatched, 16 held experts over 16 384 sorted rows (the rows
+    an 8 192-token call moves at 16 of 128 experts held, top 8), through the TPU compiler: the backward's transposed
+    product is the one that holds a tile's float32 accumulator (at 1024 x 1024 tiles it is refused here)."""
+    S, f32 = jax.ShapeDtypeStruct, jnp.float32
+    arrays = (S((16384, hidden), f32), S((16, hidden, width), f32), S((16, hidden, width), f32), S((16, width, hidden), f32),
+              S((16,), jnp.int32))
+    ffn = K.get("moe_grouped_ffn").pallas
+    step = jax.grad(lambda xs, wg, wu, wd, gs: ffn(xs, wg, wu, wd, gs, "silu").sum(), argnums=(0, 1, 2, 3))
+    assert "tpu_custom_call" in _compile_for_tpu(tpu_topology, step, arrays).as_text()

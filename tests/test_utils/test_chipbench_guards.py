@@ -1,9 +1,10 @@
 """What every PR's test run guards of the benchmark under ``chipbench/``, loaded by path as the harness loads it:
 the reader of ``moe_compact_share_pct`` over the recorder's ``iter`` spans, the definitions that decide
 ``correct`` in the first language-model cell (``chipbench/tests/test_ppo_lm.py:check_synthetic``), and, for the
-latent-attention policy's cell (PR 36), its four readers, its family's FLOP and kernel-work functions and every
-chip run on record put through its ``correct`` again (``chipbench/tests/test_ppo_lm_mla.py``'s ``check_*``); the
-five ``setup_*`` readers of the entry-and-compiler layer over the recorder's set-up spans; and the reader of
+latent-attention policy's cell and the gated grouped-query policy's, their readers, their
+families' FLOP and kernel-work functions and every chip run on record put through their ``correct`` again
+(``chipbench/tests/test_ppo_lm_mla.py``'s and ``test_ppo_lm_afmoe.py``'s ``check_*``); the five ``setup_*``
+readers of the entry-and-compiler layer over the recorder's set-up spans; and the reader of
 ``attn_fwd_recomputed_ms`` over rows of the scope table joined to a trace."""
 
 import contextlib
@@ -88,7 +89,7 @@ def test_the_benchmark_declares_the_reader():
         entry = next(m for m in json.load(f)["per_layer"] if m["name"] == "moe_compact_share_pct")
     assert entry == {"name": "moe_compact_share_pct", "unit": "%", "better": "higher", "source": "program_counter",
                      "layer": "model blocks", "moves": "grad_steps_per_s",
-                     "workloads": ["smallthinker_ep4_longprompt_rl", "kanana2_ep8_longprompt_rl"]}
+                     "workloads": ["smallthinker_ep4_longprompt_rl", "kanana2_ep8_longprompt_rl", "trinity_mini_ep8_longprompt_rl"]}
 
 
 def test_the_language_model_cells_correct_holds_on_a_case_made_by_hand():
@@ -103,6 +104,7 @@ def test_the_language_model_cells_correct_holds_on_a_case_made_by_hand():
 
 # -- the latent-attention policy's cell (PR 36) ------------------------------------------------------------------
 NEW_CELL, NEW_CONFIG = "kanana2_ep8_longprompt_rl", "kanana2_30b_a3b_ep8"
+THIRD_CELL, THIRD_CONFIG = "trinity_mini_ep8_longprompt_rl", "trinity_mini_26b_a3b_ep8"
 NEW_METRICS = {"attn_mla_ms": ("ms", "lower", "device_trace", "grad_steps_per_s"),
                "ffn_shared_ms": ("ms", "lower", "device_trace", "grad_steps_per_s"),
                "moe_bias_moved_pct": ("%", "higher", "program_counter", "grad_steps_per_s"),
@@ -147,10 +149,11 @@ def test_the_new_familys_flops_and_kernel_work_against_a_hand_count():
 def test_the_benchmark_declares_the_new_cell_its_configuration_and_its_metrics():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert [w["name"] for w in bench["workloads"]][-1] == NEW_CELL and all(w["chips"] == 1 for w in bench["workloads"])
-    cell = bench["workloads"][-1]
+    names = [w["name"] for w in bench["workloads"]]
+    assert names[-2:] == [NEW_CELL, THIRD_CELL] and all(w["chips"] == 1 for w in bench["workloads"])
+    cell = bench["workloads"][-2]
     assert (cell["config"], cell["traffic"]) == (NEW_CONFIG, "longprompt_rl") and len(cell["why"]) <= 200
-    entry = bench["configs"][-1]
+    entry = bench["configs"][-2]
     conf = new_config()
     assert entry["name"] == conf["name"] == NEW_CONFIG and entry["source"] == conf["source"] and len(entry["why"]) <= 200
     assert entry["reduced"] == conf["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
@@ -167,7 +170,7 @@ def test_the_benchmark_declares_the_new_cell_its_configuration_and_its_metrics()
     metrics = {m["name"]: m for m in bench["per_layer"]}
     for name, (unit, better, source, moves) in NEW_METRICS.items():
         assert metrics[name] == {"name": name, "unit": unit, "better": better, "source": source, "layer": "model blocks",
-                                 "moves": moves, "workloads": [NEW_CELL]}
+                                 "moves": moves, "workloads": [NEW_CELL] + ([THIRD_CELL] if name != "attn_mla_ms" else [])}
     listed = {name for name, m in metrics.items() if NEW_CELL in m.get("workloads", [])}
     assert len(listed) == 28 and "attn_fwd_recomputed_ms" in listed and not listed & {"moe_local_share_pct", "moe_load_max_over_mean", "attn_global_ms", "attn_window_ms"}
 
@@ -322,6 +325,84 @@ def test_attn_fwd_recomputed_reader(case):
 def test_the_benchmark_declares_the_recompute_reader():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert bench["per_layer"][-1] == {"name": "attn_fwd_recomputed_ms", "unit": "ms", "better": "lower",
+    assert bench["per_layer"][-2] == {"name": "attn_fwd_recomputed_ms", "unit": "ms", "better": "lower",
                                       "source": "device_trace", "layer": "model blocks", "moves": "grad_steps_per_s",
-                                      "workloads": ["smallthinker_ep4_longprompt_rl", NEW_CELL]}
+                                      "workloads": ["smallthinker_ep4_longprompt_rl", NEW_CELL, THIRD_CELL]}
+
+
+# -- the gated grouped-query policy's cell ----------------------------------------------------------------------
+def third():
+    """``chipbench/tests/test_ppo_lm_afmoe.py``, whose ``check_*`` functions hold the cases (call inside ``chipbench_on_path``)."""
+    return load("tests/test_ppo_lm_afmoe.py", "chipbench_tests_test_ppo_lm_afmoe")
+
+
+def third_config():
+    with open(os.path.join(CHIPBENCH, "configs", THIRD_CONFIG + ".json")) as f:
+        return json.load(f)
+
+
+with chipbench_on_path():
+    _THIRD = third()
+    THIRD_COUNTER_CASES, THIRD_REGION_CASES = list(_THIRD.COUNTER_CASES), sorted(_THIRD.REGION_EXPECTED)
+    THIRD_ENTRIES, THIRD_RECORDED = _THIRD.recorded_runs()
+
+
+@pytest.mark.parametrize("case", THIRD_COUNTER_CASES)
+def test_the_third_cells_counter_readers(case, monkeypatch):
+    with chipbench_on_path():
+        third().check_counter_reader(case, monkeypatch)
+
+
+@pytest.mark.parametrize("name", THIRD_REGION_CASES)
+def test_the_third_cells_region_readers(name, monkeypatch):
+    with chipbench_on_path():
+        third().check_region_reader(name, monkeypatch)
+
+
+def test_the_third_familys_flops_and_kernel_work_against_a_hand_count():
+    with open(os.path.join(CHIPBENCH, "peaks.json")) as f:
+        peaks = json.load(f)["devices"]["TPU v5 lite"]
+    with chipbench_on_path():
+        third().check_flops(load("flops/ppo_lm_afmoe.py", "chipbench_flops_ppo_lm_afmoe"), third_config(), peaks)
+
+
+def test_the_benchmark_declares_the_third_cell_its_configuration_and_its_metric():
+    """One configuration, one cell and one per-layer metric appended; the cell in every language-model metric's
+    list whose reader reads it, and in no other."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cell, entry, conf = bench["workloads"][-1], bench["configs"][-1], third_config()
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (THIRD_CELL, THIRD_CONFIG, "longprompt_rl", 1)
+    assert len(cell["why"]) <= 200 and len(entry["why"]) <= 200
+    assert entry["name"] == conf["name"] == THIRD_CONFIG and entry["source"] == conf["source"]
+    assert entry["file"] == f"chipbench/configs/{THIRD_CONFIG}.json"
+    assert entry["reduced"] == conf["reduced"] == ["num_hidden_layers", "num_dense_layers", "num_experts", "vocab_size"]
+    assert conf["published"] == {"num_hidden_layers": 32, "num_dense_layers": 2, "num_experts": 128, "vocab_size": 200192}
+    assert conf["family"] == "ppo_lm_afmoe" and conf["parameters"] == 705_476_353
+    for kind, name in (("flops", "ppo_lm_afmoe"), ("reference", "ppo_lm_afmoe_ref"), ("correct", "ppo_lm_afmoe"),
+                       ("layers", "attn_gate_ms")):
+        assert os.path.isfile(os.path.join(CHIPBENCH, kind, name + ".py"))
+    # every published width under its published key, unchanged
+    widths = {"hidden_size": 2048, "intermediate_size": 6144, "moe_intermediate_size": 1024, "head_dim": 128,
+              "num_attention_heads": 32, "num_key_value_heads": 4, "num_experts_per_tok": 8, "num_shared_experts": 1,
+              "sliding_window": 2048, "rope_theta": 10000, "route_scale": 2.826}
+    assert {k: conf[k] for k in widths} == widths and all(conf["as_run"]["algo.lm." + k] == v for k, v in widths.items())
+    assert set(conf["correct_limits_why"]) >= {k for k, v in conf["correct_limits"].items() if v}
+    metrics = {m["name"]: m for m in bench["per_layer"]}
+    assert bench["per_layer"][-1] == {"name": "attn_gate_ms", "unit": "ms", "better": "lower", "source": "device_trace",
+                                      "layer": "model blocks", "moves": "grad_steps_per_s", "workloads": [THIRD_CELL]}
+    listed = {name for name, m in metrics.items() if THIRD_CELL in m.get("workloads", [])}
+    kanana = {name for name, m in metrics.items() if NEW_CELL in m.get("workloads", [])}
+    assert listed == (kanana - {"attn_mla_ms"}) | {"attn_window_ms", "attn_global_ms", "attn_gate_ms"}
+    assert not listed & {"moe_local_share_pct", "moe_load_max_over_mean"}
+
+
+@pytest.mark.parametrize("run", THIRD_RECORDED, ids=[r["id"] for r in THIRD_RECORDED])
+def test_a_chip_run_of_the_third_cell_on_record_is_correct_and_its_controls_are_not(run):
+    with chipbench_on_path():
+        third().check_recorded_run(load("correct/ppo_lm_afmoe.py", "chipbench_correct_ppo_lm_afmoe"),
+                                   third_config()["correct_limits"], THIRD_ENTRIES, run)
+
+
+def test_the_records_cover_the_seeds_the_third_cells_limits_were_set_from():
+    assert len({r["seed"] for r in THIRD_RECORDED}) >= 3 and sum(len(r["controls"]) == 10 for r in THIRD_RECORDED) >= 3
