@@ -107,14 +107,18 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from sheeprl_tpu.ops.kernels.attn import RESIDUALS, window_attention
 from sheeprl_tpu.ops.kernels.moe import ACTIVATIONS, GMM_ROW_TILE, moe_grouped_ffn
 
 __all__ = ["DecoderConfig", "init_params", "forward", "prefill", "decode_step", "heads", "parameter_count"]
 
-# what a layer's rematerialisation keeps: the attention kernel's output and log-sum-exp (``forward``)
-REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(RESIDUALS)
+# a sublayer's output before its output norm (``post_norms``), which that norm's backward reads
+SUBLAYER_OUT = "decoder_lm.sublayer_out"
+# what a layer's rematerialisation keeps: the attention kernel's output and log-sum-exp, and the input of each
+# sublayer output norm (``forward``)
+REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(RESIDUALS, SUBLAYER_OUT)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -533,7 +537,13 @@ def _attention_output(cfg: DecoderConfig, layer, h, o):
         with jax.named_scope("lm.attn_gate"):
             o = o * jax.nn.sigmoid(jnp.dot(h, layer["wg"]))
     out = jnp.dot(o, layer["wo"])
-    return rms_norm(out, layer["ln_attn_out"], cfg.eps) if cfg.post_norms else out
+    return _output_norm(cfg, out, layer["ln_attn_out"]) if cfg.post_norms else out
+
+
+def _output_norm(cfg: DecoderConfig, y, scale):
+    """A sublayer's output norm, its input named ``SUBLAYER_OUT``: the remat
+    keeps it, so the backward does not run the sublayer again to rebuild it."""
+    return rms_norm(checkpoint_name(y, SUBLAYER_OUT), scale, cfg.eps)
 
 
 def _attention_scope(cfg: DecoderConfig, index: int) -> str:
@@ -599,7 +609,7 @@ def _feed_forward(cfg: DecoderConfig, index: int, layer, x, count_moved: bool = 
             u = rms_norm(x, layer["ln_post"], cfg.eps)
             y = _gated_mlp(cfg, u, layer["dense_gate"], layer["dense_up"], layer["dense_down"])
             if cfg.post_norms:
-                y = rms_norm(y, layer["ln_ffn_out"], cfg.eps)
+                y = _output_norm(cfg, y, layer["ln_ffn_out"])
         return x + y, (jnp.int32(0),) * cfg.counters
     with jax.named_scope("lm.moe"):
         u = rms_norm(x, layer["ln_post"], cfg.eps).reshape(B * T, H)
@@ -610,7 +620,7 @@ def _feed_forward(cfg: DecoderConfig, index: int, layer, x, count_moved: bool = 
     y = (routed + shared).reshape(B, T, H)
     if cfg.post_norms:
         with jax.named_scope("lm.moe"):
-            y = rms_norm(y, layer["ln_ffn_out"], cfg.eps)
+            y = _output_norm(cfg, y, layer["ln_ffn_out"])
     return x + y, (*counters, moved if count_moved else jnp.int32(0))
 
 
@@ -647,9 +657,11 @@ def forward(cfg: DecoderConfig, params, tokens, return_kv: bool = False):
     dense layer's row is zeros) and, if asked, each layer's cache state. With
     ``cfg.remat`` each layer is rematerialised in the backward pass, all but
     what the attention kernel names ``RESIDUALS`` (the kernel tier's output
-    and log-sum-exp, which its backward reads): those are kept, so the
-    backward runs no attention forward again. The reference tier names
-    nothing, and there everything is recomputed."""
+    and log-sum-exp, which its backward reads) and, with ``post_norms``, the
+    input of each sublayer output norm (``SUBLAYER_OUT``): those are kept, so
+    the backward runs no attention forward again, nor a sublayer only to
+    rebuild its norm's input. The reference tier's attention names nothing,
+    and there the rest is recomputed."""
     x = _embed(cfg, params, tokens)
     kvs, counters = [], []
     for i, layer in enumerate(params["layers"]):

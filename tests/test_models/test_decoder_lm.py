@@ -316,11 +316,12 @@ def gradient_program(cfg, params, tokens):
     return jax.make_jaxpr(grad)(params, tokens), jax.jit(grad)(params, tokens)
 
 
-def remat_programs(cfg, params, tokens, monkeypatch):
-    """The gradient program under the layer's policy, and under a plain ``jax.checkpoint`` (the policy left out)."""
+def remat_programs(cfg, params, tokens, monkeypatch, plain_policy=None):
+    """The gradient program under the layer's policy, and under ``plain_policy`` (by default a plain
+    ``jax.checkpoint``: the policy left out)."""
     kept = gradient_program(cfg, params, tokens)
     with monkeypatch.context() as m:
-        m.setattr(lm, "REMAT_POLICY", None)
+        m.setattr(lm, "REMAT_POLICY", plain_policy)
         plain = gradient_program(cfg, params, tokens)
     return kept, plain
 
@@ -340,10 +341,12 @@ def assert_the_kernel_tiers_remat_keeps_its_residuals(cfg, params, tokens, monke
 
 
 def assert_the_reference_tiers_remat_is_a_plain_checkpoint(cfg, params, tokens, monkeypatch):
-    """The reference tier names nothing: under the policy its gradient program holds every primitive as often as
-    under a plain checkpoint, and its gradients are the same bits."""
+    """The reference tier's attention names nothing: under the policy its gradient program holds every primitive as
+    often as under a plain checkpoint (one that keeps the sublayer outputs alone, where ``post_norms`` names them),
+    and its gradients are the same bits."""
+    plain_policy = jax.checkpoint_policies.save_only_these_names(lm.SUBLAYER_OUT) if cfg.post_norms else None
     with K.use_backend("lax"):
-        (kept_jaxpr, kept), (plain_jaxpr, plain) = remat_programs(cfg, params, tokens, monkeypatch)
+        (kept_jaxpr, kept), (plain_jaxpr, plain) = remat_programs(cfg, params, tokens, monkeypatch, plain_policy)
     assert splash_forwards(kept_jaxpr) == 0
     assert primitive_counts(kept_jaxpr) == primitive_counts(plain_jaxpr)
     for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(plain)):

@@ -3,7 +3,9 @@
 (chipbench/reference/ppo_lm_afmoe_ref.py), at a small size on the CPU: the full
 forward, the loss and every gradient; prefill and then decode through the
 window ring and the full buffer against the reference's full forward; the
-expert shares with the shared expert and the dense layer counted once; what
+expert shares with the shared expert and the dense layer counted once; that
+the per-layer remat keeps each sublayer output norm's input, so the gradient
+runs the routed share no more often than without remat; what
 ``from_config`` refuses; the cut's parameter count; and that leaving out each
 element this layer adds (the output gate, the q/k norms, the sandwich norms,
 the embedding's scale, the full layer without rotary encoding) fails the
@@ -20,9 +22,14 @@ import numpy as np
 import pytest
 
 from sheeprl_tpu.models import decoder_lm as lm
+from sheeprl_tpu.ops import kernels as K
+from sheeprl_tpu.ops.kernels.attn import RESIDUALS
 from tests.test_models.test_decoder_lm import (
     assert_the_kernel_tiers_remat_keeps_its_residuals,
     assert_the_reference_tiers_remat_is_a_plain_checkpoint,
+    primitive_counts,
+    remat_programs,
+    update_loss,
 )
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -173,6 +180,29 @@ def test_the_remat_keeps_the_splash_kernels_residuals_gated_attention(monkeypatc
 
 def test_the_reference_tiers_remat_is_the_plain_checkpoints_program_gated_attention(monkeypatch):
     assert_the_reference_tiers_remat_is_a_plain_checkpoint(SMALL, small_params(), tokens_of(SMALL, 2, P + R), monkeypatch)
+
+
+def test_the_remat_keeps_each_output_norms_input_so_the_routed_share_runs_forward_as_often_as_without_remat(monkeypatch):
+    """A dense layer and a routed one whose call compacts (2 of 8 experts held, 512 tokens: 512 of 1024 rows
+    moved, so the share is a branch of two), on the reference attention tier. With the input of each sublayer output
+    norm kept, the backward does not run the sublayer again to rebuild it: the gradient program holds as many
+    grouped products as the program without remat (24; 30 under the policy that kept the attention kernel's
+    residuals alone, whose recompute ran the routed share's forward once more), and its gradients are that
+    policy's."""
+    cfg = dataclasses.replace(SMALL, layers=2, rope_layout=(1, 0), window_layout=(1, 0), experts_held=2, expert_offset=2)
+    full = small_params(dataclasses.replace(cfg, experts_held=8, expert_offset=0))
+    params = {**full, "layers": [{k: (v[2:4] if v.ndim == 3 else v) for k, v in layer.items()} for layer in full["layers"]]}
+    tokens = tokens_of(cfg, 2, 256)
+    assert lm.compact_rows(cfg, 512) < 512 * cfg.top_k
+    residuals_alone = jax.checkpoint_policies.save_only_these_names(RESIDUALS)
+    with K.use_backend("lax"):
+        (kept_jaxpr, kept), (parent_jaxpr, parent) = remat_programs(cfg, params, tokens, monkeypatch, residuals_alone)
+        unremat = dataclasses.replace(cfg, remat=False)
+        unremat_jaxpr = jax.make_jaxpr(jax.grad(lambda p, t: update_loss(unremat, p, t)))(params, tokens)
+    products = [primitive_counts(j)["ragged_dot_general"] for j in (kept_jaxpr, unremat_jaxpr, parent_jaxpr)]
+    assert products == [24, 24, 30]
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(kept), jax.tree.leaves(parent)):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7, err_msg=jax.tree_util.keystr(path))
 
 
 def test_prefill_then_decode_through_the_window_ring_and_the_full_buffer_equals_the_references_full_forward():
