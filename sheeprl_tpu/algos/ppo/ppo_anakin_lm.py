@@ -361,6 +361,16 @@ TOY_OVERRIDES_AFMOE = (
     "algo.rollout_steps=8",
 )
 
+#: the fourth policy at toy widths: a dense layer and four routed ones ([conv, conv, full, conv, conv]) without a shared
+#: expert, gated short convolutions beside grouped-query attention with q/k norms, a tied head
+TOY_OVERRIDES_LFM2 = (
+    "exp=ppo_anakin_lm_lfm2", "algo.lm.hidden_size=64", "algo.lm.num_attention_heads=4",
+    "algo.lm.num_key_value_heads=2", "algo.lm.intermediate_size=96", "algo.lm.moe_intermediate_size=32",
+    "algo.lm.num_experts=8", "algo.lm.num_experts_per_tok=2", "algo.lm.experts_held=4", "algo.lm.expert_offset=2",
+    "algo.lm.num_hidden_layers=5", "algo.lm.num_dense_layers=1", "algo.lm.vocab_size=96", "algo.lm.vocab_held=64",
+    "env.prompt_len=24", "algo.rollout_steps=8",
+)
+
 
 def _audit_block(spec: AuditMesh, name: str, overrides):
     from sheeprl_tpu.algos.ppo.ppo import _abstract_like
@@ -418,3 +428,8 @@ def _audit_programs_latent(spec: AuditMesh):
 @register_audit_programs("ppo_anakin_lm.block_afmoe")
 def _audit_programs_afmoe(spec: AuditMesh):
     yield _audit_block(spec, "ppo_anakin_lm.block_afmoe", TOY_OVERRIDES_AFMOE)
+
+
+@register_audit_programs("ppo_anakin_lm.block_lfm2")
+def _audit_programs_lfm2(spec: AuditMesh):
+    yield _audit_block(spec, "ppo_anakin_lm.block_lfm2", TOY_OVERRIDES_LFM2)

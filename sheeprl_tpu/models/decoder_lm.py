@@ -1,12 +1,13 @@
 """Decoder-only language models with routed experts, as plain functions over a
-parameter dict. One module and one :class:`DecoderConfig` hold three published
+parameter dict. One module and one :class:`DecoderConfig` hold four published
 layers; which one a layer is comes from the config's static kinds
 (``attention``, ``router``, ``activation``, ``dense_layers``,
 ``shared_width``, ``attn_gate``, ``qk_norm``, ``post_norms``,
-``embed_scale``), never from the parameters. The kinds are independent of one
-another: the router's kind says where it sits (``"softmax"`` before
-attention, ``"sigmoid_bias"`` after it), and the shared experts and the
-leading dense layers go with either attention.
+``embed_scale``, ``conv_layout``, ``tied_head``), never from the parameters.
+The kinds are independent of one another: the router's kind says where it
+sits (``"softmax"`` before attention, ``"sigmoid_bias"`` after it), and the
+shared experts (none where ``shared_width`` is 0) and the leading dense layers
+go with either attention or the convolution.
 
 **Grouped-query layers with the router first** (``attention="gqa"``,
 ``router="softmax"``, ``activation="relu"``; SmallThinker). For input ``x``
@@ -72,6 +73,27 @@ attention** (``attention="gqa"``, ``router="sigmoid_bias"``,
 - the embedding's rows are multiplied by ``embed_scale`` (``sqrt(hidden)``
   where the published config enables muP), in the full forward and in decode.
 
+**Gated short-convolution layers beside grouped-query ones, the router after
+the mixer** (``conv_layout``, ``router="sigmoid_bias"``, ``activation="silu"``,
+``qk_norm``, ``shared_width`` 0, ``tied_head``; the ``lfm2_moe`` layer). Layer
+``l`` mixes positions by a convolution where ``conv_layout[l]`` is 1 and by
+attention where 0. For input ``x``, RMSNorm without bias:
+
+- ``h = norm_in(x)``; on a convolution layer ``[b | c | v] = h W_in``
+  (``hidden`` each), ``z_t = b_t * v_t``, a causal depthwise convolution of
+  ``conv_kernel`` taps along the positions, ``s_t = sum_i w_i * z_{t - K + 1 +
+  i}`` (``z`` before position 0 is 0), and ``x' = x + (c * s) W_out``; the
+  gates and taps are under ``lm.conv_mix``, inside ``lm.conv``. On an
+  attention layer the afmoe layer's attention without its gate and output
+  norm: q/k norms, rotary encoding on every such layer, full causal;
+- ``y = x' + F(norm_post(x'))`` with ``F`` the dense SwiGLU in the first
+  ``dense_layers`` layers, else the routed share alone (sigmoid scores, the
+  ``top_k`` of ``score + bias``, the kept scores over their sum plus
+  ``route_eps``, times ``routed_scale``);
+- the head is the embedding's own matrix (``tied_head``): ``logits =
+  norm_f(x) embed^T`` over the held rows, and the embedding's gradient comes
+  from both ends.
+
 **The layer is told which experts it holds** (``experts_held`` of them from
 ``expert_offset``): it routes over all ``experts``, computes its own experts'
 part and leaves the rest out, which is one chip's share under expert
@@ -89,14 +111,17 @@ same way.
 
 Two modes: a full-sequence forward (:func:`forward`; prefill and the update)
 and one-token decode through a cache (:func:`prefill`, :func:`decode_step`).
-The cache holds **three kinds of state**, whichever the layers need, side by
+The cache holds **four kinds of state**, whichever the layers need, side by
 side: a ``window``-slot ring of ``(k, v)`` per key-value head for each window
 layer (position ``p`` lives in slot ``p % window``; keys are cached already
 rotated, so the ring's order does not matter), a full-length ``(k, v)`` buffer
-for each global grouped-query layer, and for each latent layer the normed
+for each global grouped-query layer, for each latent layer the normed
 latent ``(B, S, latent)`` with the rotated shared key ``(B, S, rope_dim)``: no
 head axis, 576 numbers a position where the expanded keys and values are
-``heads * (nope_dim + rope_dim + v_dim)``.
+``heads * (nope_dim + rope_dim + v_dim)``, and for each convolution layer the
+last ``conv_kernel`` gated inputs ``z`` ``(B, conv_kernel, hidden)``, oldest
+first: a fixed size whatever the sequence's length, shifted by one a decoded
+token.
 """
 
 from __future__ import annotations
@@ -156,10 +181,17 @@ class DecoderConfig:
     qk_norm: bool = False  # an RMSNorm over each head of the queries and keys, before the rotary encoding
     post_norms: bool = False  # an RMSNorm on each sublayer's output before its residual add
     embed_scale: float = 1.0  # the embedding's rows times this
+    route_eps: float = 1e-20  # the sigmoid router's kept scores are divided by their sum plus this
+    conv_layout: Tuple[int, ...] = ()  # 1: the layer mixes by the gated short convolution; empty: every layer attends
+    conv_kernel: int = 0  # the convolution's taps
+    tied_head: bool = False  # the head is the embedding's matrix: no ``head`` leaf
 
     @property
     def selection_bias(self) -> bool:
         return self.router == "sigmoid_bias"
+
+    def is_conv(self, index: int) -> bool:
+        return bool(self.conv_layout) and bool(self.conv_layout[index])
 
     @property
     def counters(self) -> int:
@@ -172,11 +204,14 @@ class DecoderConfig:
         """From the ``algo.lm`` group, whose keys are one published
         config.json's: a group with ``kv_lora_rank`` is the latent-attention
         layer's, one of ``model_type`` ``afmoe`` the gated grouped-query
-        layer's, any other the grouped-query layer's with the router first."""
+        layer's, one of ``lfm2_moe`` the convolution and grouped-query layers',
+        any other the grouped-query layer's with the router first."""
         if "kv_lora_rank" in lm:
             return cls._latent_from_config(lm)
         if lm.get("model_type") == "afmoe":
             return cls._afmoe_from_config(lm)
+        if lm.get("model_type") == "lfm2_moe":
+            return cls._lfm2_from_config(lm)
         layers = int(lm.num_hidden_layers)
         experts = int(lm.moe_num_primary_experts)
         held = int(lm.experts_held or experts)
@@ -271,6 +306,43 @@ class DecoderConfig:
             embed_scale=float(lm.hidden_size) ** 0.5 if lm.mup_enabled else 1.0,
         )
 
+    @classmethod
+    def _lfm2_from_config(cls, lm: Any) -> "DecoderConfig":
+        """The ``lfm2_moe`` keys. What the module does not compute raises. The
+        row states no tie key: LFM2's configuration ties the head unless
+        ``tie_word_embeddings`` (or ``tie_embedding``) says otherwise."""
+        layers = int(lm.num_hidden_layers)
+        kinds = [str(t) for t in lm.layer_types]
+        rope = lm.rope_parameters
+        tied = lm.get("tie_word_embeddings", lm.get("tie_embedding", True))
+        unwritten = {
+            "conv_bias": bool(lm.conv_bias), "norm_topk_prob": not lm.norm_topk_prob,
+            "use_expert_bias": not lm.use_expert_bias, "rope_parameters.rope_type": rope.get("rope_type", "default") != "default",
+            "layer_types": bool(set(kinds) - {"conv", "full_attention"}) or len(kinds) < layers,
+            "tie_word_embeddings": not tied,
+        }
+        if any(unwritten.values()):
+            raise ValueError(
+                f"not written down for the convolution layer: {sorted(k for k, v in unwritten.items() if v)} "
+                "(written down: the gated short convolution without bias, sigmoid scores with a selection bias "
+                "normalised over the kept, default rotary encoding, conv and full_attention layers, a tied head)")
+        experts = int(lm.num_experts)
+        held, offset = int(lm.experts_held or experts), int(lm.expert_offset or 0)
+        if offset + held > experts:
+            raise ValueError(f"experts {offset}..{offset + held - 1} held of {experts}")
+        heads, hidden = int(lm.num_attention_heads), int(lm.hidden_size)
+        conv = tuple(int(t == "conv") for t in kinds[:layers])
+        return cls(
+            hidden=hidden, heads=heads, kv_heads=int(lm.num_key_value_heads), head_dim=int(lm.get("head_dim") or hidden // heads),
+            layers=layers, experts=experts, top_k=int(lm.num_experts_per_tok), expert_width=int(lm.moe_intermediate_size),
+            experts_held=held, expert_offset=offset, vocab_held=int(lm.vocab_held or lm.vocab_size), window=0,
+            rope_theta=float(rope.rope_theta), eps=float(lm.norm_eps), rope_layout=tuple(1 - c for c in conv),
+            window_layout=(0,) * layers, remat=bool(lm.remat), router="sigmoid_bias", activation="silu",
+            dense_layers=min(int(lm.num_dense_layers), layers), dense_width=int(lm.intermediate_size),
+            routed_scale=float(lm.routed_scaling_factor), qk_norm=True, route_eps=1e-6, conv_layout=conv,
+            conv_kernel=int(lm.conv_L_cache), tied_head=True,
+        )
+
 
 def init_params(cfg: DecoderConfig, key: jax.Array, std: float = 0.02) -> Dict[str, Any]:
     def normal(key, *shape):
@@ -288,12 +360,19 @@ def init_params(cfg: DecoderConfig, key: jax.Array, std: float = 0.02) -> Dict[s
             return {"dense_gate": normal(next(keys), H, D), "dense_up": normal(next(keys), H, D),
                     "dense_down": normal(next(keys), D, H)}
         S = cfg.shared_width
-        return {  # a run from scratch starts without a selection bias (a published model ships its own)
-            "router": normal(next(keys), H, cfg.experts), "router_bias": jnp.zeros((cfg.experts,), jnp.float32),
-            "shared_gate": normal(next(keys), H, S), "shared_up": normal(next(keys), H, S),
-            "shared_down": normal(next(keys), S, H), "w_gate": normal(next(keys), E, H, F),
-            "w_up": normal(next(keys), E, H, F), "w_down": normal(next(keys), E, F, H),
-        }
+        # a run from scratch starts without a selection bias (a published model ships its own)
+        routed = {"router": normal(next(keys), H, cfg.experts), "router_bias": jnp.zeros((cfg.experts,), jnp.float32)}
+        if S:
+            routed.update(shared_gate=normal(next(keys), H, S), shared_up=normal(next(keys), H, S),
+                          shared_down=normal(next(keys), S, H))
+        return {**routed, "w_gate": normal(next(keys), E, H, F), "w_up": normal(next(keys), E, H, F),
+                "w_down": normal(next(keys), E, F, H)}
+
+    def conv_layer(index):
+        layer = {"ln_in": ones(H), "conv_in": normal(next(keys), H, 3 * H),
+                 "conv_taps": normal(next(keys), cfg.conv_kernel, H), "conv_out": normal(next(keys), H, H),
+                 "ln_post": ones(H)}
+        return {**layer, **feed_forward(index)}
 
     def grouped_query_layer(index):
         layer = {
@@ -319,12 +398,12 @@ def init_params(cfg: DecoderConfig, key: jax.Array, std: float = 0.02) -> Dict[s
         }
         return {**layer, **feed_forward(index)}
 
-    layers = [(latent_layer if latent else grouped_query_layer)(i) for i in range(cfg.layers)]
-    return {
-        "embed": normal(next(keys), cfg.vocab_held, H), "layers": layers, "ln_f": jnp.ones((H,), jnp.float32),
-        "head": normal(next(keys), H, cfg.vocab_held), "value_w": normal(next(keys), H, 1),
-        "value_b": jnp.zeros((1,), jnp.float32),
-    }
+    mixer = latent_layer if latent else grouped_query_layer
+    layers = [(conv_layer if cfg.is_conv(i) else mixer)(i) for i in range(cfg.layers)]
+    params = {"embed": normal(next(keys), cfg.vocab_held, H), "layers": layers, "ln_f": jnp.ones((H,), jnp.float32)}
+    if not cfg.tied_head:
+        params["head"] = normal(next(keys), H, cfg.vocab_held)
+    return {**params, "value_w": normal(next(keys), H, 1), "value_b": jnp.zeros((1,), jnp.float32)}
 
 
 def parameter_count(params: Any) -> int:
@@ -464,7 +543,7 @@ def _route(cfg: DecoderConfig, h, router, bias=None):
     scores = jax.nn.sigmoid(logits)
     _, experts = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), cfg.top_k)
     kept = jnp.take_along_axis(scores, experts, axis=-1)
-    weights = cfg.routed_scale * kept / (jnp.sum(kept, axis=-1, keepdims=True) + 1e-20)
+    weights = cfg.routed_scale * kept / (jnp.sum(kept, axis=-1, keepdims=True) + cfg.route_eps)
     above = jnp.sum(scores[:, None, :] > kept[:, :, None], axis=-1)  # (N, top_k): a compare and a count, no second sort
     return weights, experts, jnp.sum(above >= cfg.top_k, dtype=jnp.int32)
 
@@ -601,8 +680,8 @@ def _feed_forward(cfg: DecoderConfig, index: int, layer, x, count_moved: bool = 
     """The feed-forward half of a layer whose router sits after attention, for
     ``x`` ``(B, T, hidden)`` after attention: the dense MLP in a leading layer
     (its counters are zeros), else the routed share and the shared experts on
-    the same normed input; with ``post_norms`` the sum is normed before the
-    residual add."""
+    the same normed input (the routed share alone where ``shared_width`` is
+    0); with ``post_norms`` the sum is normed before the residual add."""
     B, T, H = x.shape
     if index < cfg.dense_layers:
         with jax.named_scope("lm.ffn_shared"):
@@ -614,10 +693,11 @@ def _feed_forward(cfg: DecoderConfig, index: int, layer, x, count_moved: bool = 
     with jax.named_scope("lm.moe"):
         u = rms_norm(x, layer["ln_post"], cfg.eps).reshape(B * T, H)
         weights, experts, moved = _route(cfg, u, layer["router"], layer["router_bias"])
-        routed, counters = moe_share(cfg, layer, u, weights, experts)
-    with jax.named_scope("lm.ffn_shared"):
-        shared = _gated_mlp(cfg, u, layer["shared_gate"], layer["shared_up"], layer["shared_down"])
-    y = (routed + shared).reshape(B, T, H)
+        y, counters = moe_share(cfg, layer, u, weights, experts)
+    if cfg.shared_width:
+        with jax.named_scope("lm.ffn_shared"):
+            y = y + _gated_mlp(cfg, u, layer["shared_gate"], layer["shared_up"], layer["shared_down"])
+    y = y.reshape(B, T, H)
     if cfg.post_norms:
         with jax.named_scope("lm.moe"):
             y = _output_norm(cfg, y, layer["ln_ffn_out"])
@@ -638,10 +718,44 @@ def _latent_layer(cfg: DecoderConfig, index: int, layer, x):
     return x, (c, r), counters
 
 
+def _conv_gates(layer, h):
+    """The convolution layer's input projection of the normed ``h`` (..., hidden)
+    (under ``lm.conv``), split into the output gate ``c`` and the gated input
+    ``z = b * v`` (under ``lm.conv_mix``)."""
+    bcv = jnp.dot(h, layer["conv_in"])
+    with jax.named_scope("lm.conv_mix"):
+        b, c, v = jnp.split(bcv, 3, axis=-1)
+        return c, b * v
+
+
+def _conv_layer(cfg: DecoderConfig, index: int, layer, x):
+    """The gated short convolution over a full sequence: the causal depthwise
+    convolution by shifts along the positions and adds, one a tap. Its cache
+    state is the last ``conv_kernel`` gated inputs, zero on the left of a
+    shorter sequence."""
+    T, K = x.shape[1], cfg.conv_kernel
+    with jax.named_scope("lm.conv"):
+        c, z = _conv_gates(layer, rms_norm(x, layer["ln_in"], cfg.eps))
+        with jax.named_scope("lm.conv_mix"):
+            padded = jnp.pad(z, ((0, 0), (K - 1, 0), (0, 0)))
+            # tap i reads the position K - 1 - i back: ``padded[:, i : i + T]``
+            s = layer["conv_taps"][0] * padded[:, :T]
+            for i in range(1, K):
+                s = s + layer["conv_taps"][i] * padded[:, i : i + T]
+            y = c * s
+        x = x + jnp.dot(y, layer["conv_out"])
+    state = jnp.pad(z, ((0, 0), (max(K - T, 0), 0), (0, 0)))[:, -K:]
+    x, counters = _feed_forward(cfg, index, layer, x)
+    return x, state, counters
+
+
 def layer_forward(cfg: DecoderConfig, index: int, layer, x):
     """``x`` (B, T, hidden) through layer ``index``; also what a cache holds
-    of it (the rotated keys and the values, or the normed latent and the
-    rotated shared key) and the routing counters."""
+    of it (the rotated keys and the values, the normed latent and the rotated
+    shared key, or the convolution's last gated inputs) and the routing
+    counters."""
+    if cfg.is_conv(index):
+        return _conv_layer(cfg, index, layer, x)
     return (_latent_layer if cfg.attention == "mla" else _grouped_query_layer)(cfg, index, layer, x)
 
 
@@ -675,9 +789,14 @@ def forward(cfg: DecoderConfig, params, tokens, return_kv: bool = False):
 
 
 def heads(cfg: DecoderConfig, params, x):
-    """Final norm, then logits over the held vocabulary and the value."""
+    """Final norm, then logits over the held vocabulary (against the
+    embedding's rows where the head is tied) and the value."""
     h = rms_norm(x, params["ln_f"], cfg.eps)
-    logits = jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
+    if cfg.tied_head:
+        logits = jax.lax.dot_general(h, params["embed"], (((h.ndim - 1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+    else:
+        logits = jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
     value = jnp.dot(h, params["value_w"])[..., 0] + params["value_b"][0]
     return logits, value
 
@@ -693,12 +812,18 @@ def prefill(cfg: DecoderConfig, params, tokens, max_len: int):
     of ``(B, slots, ...)`` arrays, ``(k, v)`` with a ``(kv_heads, D)`` tail
     for a grouped-query layer (``slots`` the window for a window layer, a
     ring, and ``max_len`` for a global one), the latent ``(latent,)`` and the
-    shared key ``(rope_dim,)`` for a latent layer (``max_len`` slots). Returns
-    the last position's hidden state, the cache and the counters."""
+    shared key ``(rope_dim,)`` for a latent layer (``max_len`` slots); for a
+    convolution layer one ``(B, conv_kernel, hidden)`` array, the prompt's
+    last gated inputs. Returns the last position's hidden state, the cache and
+    the counters."""
     P = tokens.shape[1]
     x, counters, kvs = forward(cfg, params, tokens, return_kv=True)
     cache = []
-    for i, (k, v) in enumerate(kvs):
+    for i, state in enumerate(kvs):
+        if cfg.is_conv(i):
+            cache.append(state)
+            continue
+        k, v = state
         slots = _cache_len(cfg, i, max_len)
         kept = min(P, slots)  # a ring keeps the prompt's last `slots` positions
         where = jnp.arange(P - kept, P) % slots
@@ -765,6 +890,20 @@ def _latent_decode(cfg: DecoderConfig, index: int, layer, state, x, position):
     return y[:, 0], (latents, shared), counters
 
 
+def _conv_decode(cfg: DecoderConfig, index: int, layer, state, x, position):
+    """The new token's gated input shifted into the state (the oldest out),
+    then the taps against the state: the full sequence's convolution at one
+    position."""
+    with jax.named_scope("lm.conv"):
+        c, z = _conv_gates(layer, rms_norm(x, layer["ln_in"], cfg.eps))
+        with jax.named_scope("lm.conv_mix"):
+            state = jnp.concatenate([state[:, 1:], z[:, None]], axis=1)
+            y = c * jnp.sum(state * layer["conv_taps"], axis=1)
+        x = x + jnp.dot(y, layer["conv_out"])
+    y, counters = _feed_forward(cfg, index, layer, x[:, None], count_moved=False)
+    return y[:, 0], state, counters
+
+
 def decode_step(cfg: DecoderConfig, params, cache, token, position):
     """One token per sequence: ``token`` (B,) at ``position`` (a traced
     scalar, the same for all) through every layer, reading and writing the
@@ -775,7 +914,7 @@ def decode_step(cfg: DecoderConfig, params, cache, token, position):
     step = _latent_decode if cfg.attention == "mla" else _grouped_query_decode
     new_cache, counters = [], []
     for i, (layer, state) in enumerate(zip(params["layers"], cache)):
-        x, state, c = step(cfg, i, layer, state, x, position)
+        x, state, c = (_conv_decode if cfg.is_conv(i) else step)(cfg, i, layer, state, x, position)
         new_cache.append(state)
         counters.append(jnp.stack(c))
     return x, new_cache, jnp.stack(counters)

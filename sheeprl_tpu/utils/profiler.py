@@ -89,6 +89,8 @@ BURST_REGIONS: Tuple[str, ...] = (
 #: there, so the model's own scopes inside them count to the phase; the rest
 #: lies in the update. ``lm.attn_gate`` lies inside ``lm.attn_window`` /
 #: ``lm.attn_global`` and counts to them; it is an innermost scope of its own.
+#: ``lm.conv_mix`` (the convolution's gates and taps) lies inside ``lm.conv``
+#: (the convolution layer's mixer) and counts to it the same way.
 LM_BLOCK_REGIONS: Tuple[str, ...] = (
     "rollout.prefill",
     "rollout.decode",
@@ -103,6 +105,8 @@ LM_BLOCK_REGIONS: Tuple[str, ...] = (
     "ppo.optim",
     "env.token",
     "lm.attn_gate",
+    "lm.conv",
+    "lm.conv_mix",
 )
 REGIONS: Tuple[str, ...] = BURST_REGIONS + LM_BLOCK_REGIONS
 #: ``ops.kernels.registry.dispatch(name)`` runs its kernel, whichever tier,

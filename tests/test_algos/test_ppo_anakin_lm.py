@@ -74,8 +74,10 @@ def test_two_iterations_one_compile_finite_losses(tmp_path, trace_hygiene, devic
     outers = {v["outer"] for v in table.values()}
     # every region of the block but env.token, which lies inside rollout.decode and counts to it, and the
     # other policies' scopes (latent attention, shared experts: tests/test_algos/test_ppo_anakin_lm_latent.py; the
-    # attention's output gate, never outermost: tests/test_algos/test_ppo_anakin_lm_afmoe.py)
-    assert outers == (set(profiler.LM_BLOCK_REGIONS) - {"env.token", "lm.attn_mla", "lm.ffn_shared", "lm.attn_gate"}) | {None}
+    # attention's output gate, never outermost: tests/test_algos/test_ppo_anakin_lm_afmoe.py; the convolution:
+    # tests/test_algos/test_ppo_anakin_lm_lfm2.py)
+    others = {"env.token", "lm.attn_mla", "lm.ffn_shared", "lm.attn_gate", "lm.conv", "lm.conv_mix"}
+    assert outers == (set(profiler.LM_BLOCK_REGIONS) - others) | {None}
     scopes = {v["scope"] for v in table.values()}
     assert {"kernel.moe_grouped_ffn", "kernel.window_attention"} <= scopes
     backward = {v["outer"] for v in table.values() if v["backward"]}

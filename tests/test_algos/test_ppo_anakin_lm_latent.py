@@ -41,7 +41,8 @@ def test_two_iterations_through_the_same_main_with_the_new_regions_and_counters(
         assert c["moe_compactable_calls"] == 0  # under a row tile of assignments: every row is moved anyway
     table = profiler.scope_table(f"{PROGRAM_NAME}/1")
     outers = {v["outer"] for v in table.values()}
-    assert outers == (set(profiler.LM_BLOCK_REGIONS) - {"env.token", "lm.attn_global", "lm.attn_window", "lm.attn_gate"}) | {None}
+    others = {"env.token", "lm.attn_global", "lm.attn_window", "lm.attn_gate", "lm.conv", "lm.conv_mix"}
+    assert outers == (set(profiler.LM_BLOCK_REGIONS) - others) | {None}
     assert {"kernel.moe_grouped_ffn", "kernel.window_attention"} <= {v["scope"] for v in table.values()}
     backward = {v["outer"] for v in table.values() if v["backward"]}
     assert {"lm.embed", "lm.attn_mla", "lm.moe", "lm.ffn_shared", "lm.head_loss"} <= backward

@@ -629,10 +629,12 @@ def test_the_grouped_products_tiles_narrow_only_where_a_tile_would_not_fit():
     assert (gmm_tiling(24576, 2560, 768), gmm_tiling(24576, 768, 2560)) == ((512, 640, 768), (512, 768, 640))
     assert (gmm_tiling(12288, 2048, 768), gmm_tiling(12288, 768, 2048)) == ((512, 1024, 768), (512, 768, 1024))
     assert (gmm_tiling(16384, 2048, 1024), gmm_tiling(16384, 1024, 2048)) == ((512, 1024, 512), (512, 1024, 512))
+    assert (gmm_tiling(8192, 2048, 1536), gmm_tiling(8192, 1536, 2048)) == ((512, 1024, 768), (512, 768, 1024))
     assert gmm_tiling(48, 256, 128) == (48, 256, 128)  # a decode call, the tests' widths
 
 
-@pytest.mark.parametrize("hidden,width", [(2560, 768), (2048, 768), (2048, 1024)], ids=["smallthinker", "kanana2", "trinity"])
+@pytest.mark.parametrize("hidden,width", [(2560, 768), (2048, 768), (2048, 1024), (2048, 1536)],
+                         ids=["smallthinker", "kanana2", "trinity", "lfm2"])
 def test_moe_grouped_ffn_backward_fits_scoped_vmem_at_every_configured_width(pretend_tpu, tpu_topology, hidden, width):
     """The experts' products and their gradients as dispatched, 16 held experts over 16 384 sorted rows (the rows
     an 8 192-token call moves at 16 of 128 experts held, top 8), through the TPU compiler: the backward's transposed
@@ -643,3 +645,15 @@ def test_moe_grouped_ffn_backward_fits_scoped_vmem_at_every_configured_width(pre
     ffn = K.get("moe_grouped_ffn").pallas
     step = jax.grad(lambda xs, wg, wu, wd, gs: ffn(xs, wg, wu, wd, gs, "silu").sum(), argnums=(0, 1, 2, 3))
     assert "tpu_custom_call" in _compile_for_tpu(tpu_topology, step, arrays).as_text()
+
+
+def test_window_attention_forward_and_backward_compile_for_tpu_at_64_wide_heads(pretend_tpu, tpu_topology):
+    """Full causal attention of one sequence of 8 192 positions, 32 query heads over 8 key-value heads of 64 (the
+    convolution policy's attention layer), forward and every gradient as dispatched, through the TPU compiler: the
+    splash kernels take a head half a lane tile wide."""
+    from sheeprl_tpu.ops.kernels.attn import window_attention_pallas
+
+    S, f32 = jax.ShapeDtypeStruct, jnp.float32
+    arrays = (S((1, 8192, 32, 64), f32), S((1, 8192, 8, 64), f32), S((1, 8192, 8, 64), f32))
+    step = jax.grad(lambda q, k, v: window_attention_pallas(q, k, v, 0).sum(), argnums=(0, 1, 2))
+    assert _compile_for_tpu(tpu_topology, step, arrays).as_text().count("tpu_custom_call") >= 3

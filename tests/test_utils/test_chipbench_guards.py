@@ -1,9 +1,10 @@
 """What every PR's test run guards of the benchmark under ``chipbench/``, loaded by path as the harness loads it:
 the reader of ``moe_compact_share_pct`` over the recorder's ``iter`` spans, the definitions that decide
 ``correct`` in the first language-model cell (``chipbench/tests/test_ppo_lm.py:check_synthetic``), and, for the
-latent-attention policy's cell and the gated grouped-query policy's, their readers, their
+latent-attention policy's cell, the gated grouped-query policy's and the convolution policy's, their readers, their
 families' FLOP and kernel-work functions and every chip run on record put through their ``correct`` again
-(``chipbench/tests/test_ppo_lm_mla.py``'s and ``test_ppo_lm_afmoe.py``'s ``check_*``); the five ``setup_*``
+(``chipbench/tests/test_ppo_lm_mla.py``'s, ``test_ppo_lm_afmoe.py``'s and ``test_ppo_lm_lfm2.py``'s ``check_*``); the
+five ``setup_*``
 readers of the entry-and-compiler layer over the recorder's set-up spans; and the reader of
 ``attn_fwd_recomputed_ms`` over rows of the scope table joined to a trace."""
 
@@ -89,7 +90,8 @@ def test_the_benchmark_declares_the_reader():
         entry = next(m for m in json.load(f)["per_layer"] if m["name"] == "moe_compact_share_pct")
     assert entry == {"name": "moe_compact_share_pct", "unit": "%", "better": "higher", "source": "program_counter",
                      "layer": "model blocks", "moves": "grad_steps_per_s",
-                     "workloads": ["smallthinker_ep4_longprompt_rl", "kanana2_ep8_longprompt_rl", "trinity_mini_ep8_longprompt_rl"]}
+                     "workloads": ["smallthinker_ep4_longprompt_rl", "kanana2_ep8_longprompt_rl", "trinity_mini_ep8_longprompt_rl",
+                                   "lfm2_ep8_longprompt_rl"]}
 
 
 def test_the_language_model_cells_correct_holds_on_a_case_made_by_hand():
@@ -105,6 +107,7 @@ def test_the_language_model_cells_correct_holds_on_a_case_made_by_hand():
 # -- the latent-attention policy's cell (PR 36) ------------------------------------------------------------------
 NEW_CELL, NEW_CONFIG = "kanana2_ep8_longprompt_rl", "kanana2_30b_a3b_ep8"
 THIRD_CELL, THIRD_CONFIG = "trinity_mini_ep8_longprompt_rl", "trinity_mini_26b_a3b_ep8"
+FOURTH_CELL, FOURTH_CONFIG = "lfm2_ep8_longprompt_rl", "lfm2_24b_a2b_ep8"
 NEW_METRICS = {"attn_mla_ms": ("ms", "lower", "device_trace", "grad_steps_per_s"),
                "ffn_shared_ms": ("ms", "lower", "device_trace", "grad_steps_per_s"),
                "moe_bias_moved_pct": ("%", "higher", "program_counter", "grad_steps_per_s"),
@@ -150,10 +153,10 @@ def test_the_benchmark_declares_the_new_cell_its_configuration_and_its_metrics()
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     names = [w["name"] for w in bench["workloads"]]
-    assert names[-2:] == [NEW_CELL, THIRD_CELL] and all(w["chips"] == 1 for w in bench["workloads"])
-    cell = bench["workloads"][-2]
+    assert names[-3:] == [NEW_CELL, THIRD_CELL, FOURTH_CELL] and all(w["chips"] == 1 for w in bench["workloads"])
+    cell = bench["workloads"][-3]
     assert (cell["config"], cell["traffic"]) == (NEW_CONFIG, "longprompt_rl") and len(cell["why"]) <= 200
-    entry = bench["configs"][-2]
+    entry = bench["configs"][-3]
     conf = new_config()
     assert entry["name"] == conf["name"] == NEW_CONFIG and entry["source"] == conf["source"] and len(entry["why"]) <= 200
     assert entry["reduced"] == conf["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
@@ -169,8 +172,9 @@ def test_the_benchmark_declares_the_new_cell_its_configuration_and_its_metrics()
     assert set(conf["correct_limits_why"]) >= {k for k, v in conf["correct_limits"].items() if v}
     metrics = {m["name"]: m for m in bench["per_layer"]}
     for name, (unit, better, source, moves) in NEW_METRICS.items():
+        later = [THIRD_CELL, FOURTH_CELL] if name != "attn_mla_ms" else []
         assert metrics[name] == {"name": name, "unit": unit, "better": better, "source": source, "layer": "model blocks",
-                                 "moves": moves, "workloads": [NEW_CELL] + ([THIRD_CELL] if name != "attn_mla_ms" else [])}
+                                 "moves": moves, "workloads": [NEW_CELL] + later}
     listed = {name for name, m in metrics.items() if NEW_CELL in m.get("workloads", [])}
     assert len(listed) == 28 and "attn_fwd_recomputed_ms" in listed and not listed & {"moe_local_share_pct", "moe_load_max_over_mean", "attn_global_ms", "attn_window_ms"}
 
@@ -325,9 +329,9 @@ def test_attn_fwd_recomputed_reader(case):
 def test_the_benchmark_declares_the_recompute_reader():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert bench["per_layer"][-2] == {"name": "attn_fwd_recomputed_ms", "unit": "ms", "better": "lower",
+    assert bench["per_layer"][-4] == {"name": "attn_fwd_recomputed_ms", "unit": "ms", "better": "lower",
                                       "source": "device_trace", "layer": "model blocks", "moves": "grad_steps_per_s",
-                                      "workloads": ["smallthinker_ep4_longprompt_rl", NEW_CELL, THIRD_CELL]}
+                                      "workloads": ["smallthinker_ep4_longprompt_rl", NEW_CELL, THIRD_CELL, FOURTH_CELL]}
 
 
 # -- the gated grouped-query policy's cell ----------------------------------------------------------------------
@@ -371,7 +375,7 @@ def test_the_benchmark_declares_the_third_cell_its_configuration_and_its_metric(
     list whose reader reads it, and in no other."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    cell, entry, conf = bench["workloads"][-1], bench["configs"][-1], third_config()
+    cell, entry, conf = bench["workloads"][-2], bench["configs"][-2], third_config()
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (THIRD_CELL, THIRD_CONFIG, "longprompt_rl", 1)
     assert len(cell["why"]) <= 200 and len(entry["why"]) <= 200
     assert entry["name"] == conf["name"] == THIRD_CONFIG and entry["source"] == conf["source"]
@@ -389,7 +393,7 @@ def test_the_benchmark_declares_the_third_cell_its_configuration_and_its_metric(
     assert {k: conf[k] for k in widths} == widths and all(conf["as_run"]["algo.lm." + k] == v for k, v in widths.items())
     assert set(conf["correct_limits_why"]) >= {k for k, v in conf["correct_limits"].items() if v}
     metrics = {m["name"]: m for m in bench["per_layer"]}
-    assert bench["per_layer"][-1] == {"name": "attn_gate_ms", "unit": "ms", "better": "lower", "source": "device_trace",
+    assert bench["per_layer"][-3] == {"name": "attn_gate_ms", "unit": "ms", "better": "lower", "source": "device_trace",
                                       "layer": "model blocks", "moves": "grad_steps_per_s", "workloads": [THIRD_CELL]}
     listed = {name for name, m in metrics.items() if THIRD_CELL in m.get("workloads", [])}
     kanana = {name for name, m in metrics.items() if NEW_CELL in m.get("workloads", [])}
@@ -406,3 +410,83 @@ def test_a_chip_run_of_the_third_cell_on_record_is_correct_and_its_controls_are_
 
 def test_the_records_cover_the_seeds_the_third_cells_limits_were_set_from():
     assert len({r["seed"] for r in THIRD_RECORDED}) >= 3 and sum(len(r["controls"]) == 10 for r in THIRD_RECORDED) >= 3
+
+
+# -- the convolution policy's cell ------------------------------------------------------------------------------
+def fourth():
+    """``chipbench/tests/test_ppo_lm_lfm2.py``, whose ``check_*`` functions hold the cases (call inside ``chipbench_on_path``)."""
+    return load("tests/test_ppo_lm_lfm2.py", "chipbench_tests_test_ppo_lm_lfm2")
+
+
+def fourth_config():
+    with open(os.path.join(CHIPBENCH, "configs", FOURTH_CONFIG + ".json")) as f:
+        return json.load(f)
+
+
+with chipbench_on_path():
+    _FOURTH = fourth()
+    FOURTH_COUNTER_CASES, FOURTH_REGION_CASES = list(_FOURTH.COUNTER_CASES), sorted(_FOURTH.REGION_EXPECTED)
+    FOURTH_ENTRIES, FOURTH_RECORDED = _FOURTH.recorded_runs()
+
+
+@pytest.mark.parametrize("case", FOURTH_COUNTER_CASES)
+def test_the_fourth_cells_counter_readers(case, monkeypatch):
+    with chipbench_on_path():
+        fourth().check_counter_reader(case, monkeypatch)
+
+
+@pytest.mark.parametrize("name", FOURTH_REGION_CASES)
+def test_the_fourth_cells_region_readers(name, monkeypatch):
+    with chipbench_on_path():
+        fourth().check_region_reader(name, monkeypatch)
+
+
+def test_the_fourth_familys_flops_and_kernel_work_against_a_hand_count():
+    with open(os.path.join(CHIPBENCH, "peaks.json")) as f:
+        peaks = json.load(f)["devices"]["TPU v5 lite"]
+    with chipbench_on_path():
+        fourth().check_flops(load("flops/ppo_lm_lfm2.py", "chipbench_flops_ppo_lm_lfm2"), fourth_config(), peaks)
+
+
+def test_the_benchmark_declares_the_fourth_cell_its_configuration_and_its_metrics():
+    """One configuration, one cell and two per-layer metrics appended; the cell in every language-model metric's
+    list whose reader reads it, and in no other."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cell, entry, conf = bench["workloads"][-1], bench["configs"][-1], fourth_config()
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (FOURTH_CELL, FOURTH_CONFIG, "longprompt_rl", 1)
+    assert len(cell["why"]) <= 200 and len(entry["why"]) <= 200
+    assert entry["name"] == conf["name"] == FOURTH_CONFIG and entry["source"] == conf["source"]
+    assert entry["file"] == f"chipbench/configs/{FOURTH_CONFIG}.json"
+    assert entry["reduced"] == conf["reduced"] == ["num_hidden_layers", "num_dense_layers", "num_experts", "vocab_size"]
+    assert conf["published"] == {"num_hidden_layers": 40, "num_dense_layers": 2, "num_experts": 64, "vocab_size": 65536}
+    assert conf["family"] == "ppo_lm_lfm2" and conf["parameters"] == 469_287_297
+    for kind, name in (("flops", "ppo_lm_lfm2"), ("reference", "ppo_lm_lfm2_ref"), ("correct", "ppo_lm_lfm2"),
+                       ("layers", "conv_ms"), ("layers", "conv_mix_ms")):
+        assert os.path.isfile(os.path.join(CHIPBENCH, kind, name + ".py"))
+    # every published width under its published key, unchanged
+    widths = {"hidden_size": 2048, "intermediate_size": 11776, "moe_intermediate_size": 1536, "num_attention_heads": 32,
+              "num_key_value_heads": 8, "num_experts_per_tok": 4, "conv_L_cache": 3, "routed_scaling_factor": 1,
+              "norm_eps": 1e-5, "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"}}
+    assert {k: conf[k] for k in widths} == widths and all(conf["as_run"]["algo.lm." + k] == v for k, v in widths.items())
+    assert conf["layer_types"][:5] == ["conv", "conv", "full_attention", "conv", "conv"] and len(conf["layer_types"]) == 40
+    assert set(conf["correct_limits_why"]) >= {k for k, v in conf["correct_limits"].items() if v}
+    for i, name in enumerate(("conv_ms", "conv_mix_ms")):
+        assert bench["per_layer"][-2 + i] == {"name": name, "unit": "ms", "better": "lower", "source": "device_trace",
+                                              "layer": "model blocks", "moves": "grad_steps_per_s", "workloads": [FOURTH_CELL]}
+    metrics = {m["name"]: m for m in bench["per_layer"]}
+    listed = {name for name, m in metrics.items() if FOURTH_CELL in m.get("workloads", [])}
+    trinity = {name for name, m in metrics.items() if THIRD_CELL in m.get("workloads", [])}
+    assert listed == (trinity - {"attn_window_ms", "attn_gate_ms"}) | {"conv_ms", "conv_mix_ms"}
+    assert not listed & {"moe_local_share_pct", "moe_load_max_over_mean", "attn_mla_ms"}
+
+
+@pytest.mark.parametrize("run", FOURTH_RECORDED, ids=[r["id"] for r in FOURTH_RECORDED])
+def test_a_chip_run_of_the_fourth_cell_on_record_is_correct_and_its_controls_are_not(run):
+    with chipbench_on_path():
+        fourth().check_recorded_run(load("correct/ppo_lm_lfm2.py", "chipbench_correct_ppo_lm_lfm2"),
+                                    fourth_config()["correct_limits"], FOURTH_ENTRIES, run)
+
+
+def test_the_records_cover_the_seeds_the_fourth_cells_limits_were_set_from():
+    assert len({r["seed"] for r in FOURTH_RECORDED}) >= 7 and sum(len(r["controls"]) == 6 for r in FOURTH_RECORDED) >= 2

@@ -164,7 +164,7 @@ def test_every_span_is_entered_as_a_trace_annotation(monkeypatch):
 def test_span_names_and_regions_are_listed_once():
     assert len(set(profiler.SPANS)) == len(profiler.SPANS) == 14  # PR 38: setup.build and the three jax.* spans
     assert set(profiler.JAX_SPANS) < set(profiler.SPANS)
-    assert len(set(profiler.REGIONS)) == len(profiler.REGIONS) == 25  # 12 of the bursts, 13 of the language-model block
+    assert len(set(profiler.REGIONS)) == len(profiler.REGIONS) == 27  # 12 of the bursts, 15 of the language-model block
     assert len(profiler.BURST_REGIONS) == 12 and profiler.REGIONS == profiler.BURST_REGIONS + profiler.LM_BLOCK_REGIONS
     assert all(name == name.lower() for name in profiler.SPANS + profiler.REGIONS)
     assert profiler.scope_table("no such program") is None
